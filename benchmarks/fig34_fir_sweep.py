@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import argparse
 import csv
+import multiprocessing
 import os
 import pathlib
 import time
@@ -89,7 +90,10 @@ def run(mode: str = "default", verbose: bool = True, jobs: int = 1):
         taps_list, n_div = [55, 75, 95, 127, 155, 191, 255], 100
     grid = [(w, t, n_div) for w in ("hamming", "kaiser") for t in taps_list]
     if jobs > 1:
-        with ProcessPoolExecutor(max_workers=jobs) as pool:
+        # spawn: a forked child must not inherit a TPU-holding parent
+        with ProcessPoolExecutor(
+            max_workers=jobs, mp_context=multiprocessing.get_context("spawn")
+        ) as pool:
             all_rows = list(pool.map(_grid_row, grid, chunksize=1))
         if verbose:
             for r in all_rows:

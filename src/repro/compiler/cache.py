@@ -147,11 +147,8 @@ def cache_stats() -> dict:
     out["specialized"] = {
         "hits": info.hits, "misses": info.misses, "size": info.currsize,
     }
-    try:  # jax.jit exposes only a size; absent on very old jax
-        bank_size = _bf._bank_call._cache_size()
-    except Exception:
-        bank_size = None
-    out["bank_call"] = {"size": bank_size}
+    # jax.jit exposes only a size
+    out["bank_call"] = {"size": _bf._bank_call._cache_size()}
     out["counters"] = dict(COUNTERS)
     return out
 
@@ -160,8 +157,7 @@ def clear_caches() -> None:
     """Empty every compile-pipeline cache and zero the counters.
 
     Test isolation hook; serving processes never need it (the caches are
-    bounded).  The `_bank_call` jit cache is cleared when the running jax
-    exposes `clear_cache`, skipped otherwise.
+    bounded).  The `_bank_call` jit cache is cleared too.
     """
     _bf = importlib.import_module("repro.kernels.blmac_fir")
     _rt = importlib.import_module("repro.kernels.runtime")
@@ -173,8 +169,5 @@ def clear_caches() -> None:
     _opt._CSE_MEMO.clear()
     STATS["cse"].reset()
     _bf.specialized_program.cache_clear()
-    try:
-        _bf._bank_call.clear_cache()
-    except Exception:
-        pass
+    _bf._bank_call.clear_cache()
     COUNTERS.clear()

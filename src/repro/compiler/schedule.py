@@ -40,6 +40,8 @@ MAX_BANK_TILE = 256  # acc VMEM at tile=1024: 256×1024×4 B = 1 MiB
 # * 2**8 <= 2**24, satisfied for folded windows up to ~257 taps-half) —
 # so the compiled lanes run it on the fast f32 GEMM units bit-exactly,
 # the effect the compiled-merge autotuner sweep re-measures per plan.
+# It is also the widest merge the Pallas bank kernel's bf16 MXU
+# contraction takes exactly (`repro.kernels.blmac_fir.bf16_dot_safe`).
 MERGE_DEFAULT = 8
 
 

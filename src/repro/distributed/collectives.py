@@ -18,32 +18,11 @@ overlap-save, but across devices instead of across pushes).  One
 from __future__ import annotations
 
 import functools
-import inspect
 from typing import Any
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-
-
-def get_shard_map():
-    """`shard_map` across jax versions (>=0.5 top level, 0.4.x experimental)."""
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
-    return shard_map
-
-
-def shard_map_no_check_kwargs() -> dict:
-    """The "skip replication check" kwarg for this jax's `shard_map`
-    (renamed check_rep → check_vma); keyed off the actual signature."""
-    params = inspect.signature(get_shard_map()).parameters
-    if "check_vma" in params:
-        return {"check_vma": False}
-    if "check_rep" in params:
-        return {"check_rep": False}
-    return {}
 
 
 def halo_exchange_left(
@@ -113,8 +92,6 @@ def make_compressed_dp_grad_fn(loss_fn, mesh: Mesh, axis: str = "data"):
     Params replicated; batch sharded on `axis`.  Returns a function
     (params, batch) → (loss, grads) with grads reduced in int8.
     """
-    shard_map = get_shard_map()
-    _no_check = shard_map_no_check_kwargs()
 
     def local_grads(params, batch):
         loss, grads = jax.value_and_grad(loss_fn)(params, batch)
@@ -126,11 +103,11 @@ def make_compressed_dp_grad_fn(loss_fn, mesh: Mesh, axis: str = "data"):
     def wrapped(params, batch):
         pspec = jax.tree_util.tree_map(lambda _: P(), params)
         bspec = jax.tree_util.tree_map(lambda _: P(axis), batch)
-        f = shard_map(
+        f = jax.shard_map(
             local_grads, mesh=mesh,
             in_specs=(pspec, bspec),
             out_specs=(P(), jax.tree_util.tree_map(lambda _: P(), params)),
-            **_no_check,
+            check_vma=False,
         )
         return f(params, batch)
 

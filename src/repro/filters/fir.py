@@ -11,6 +11,7 @@ Normalized frequencies follow scipy's convention: Nyquist = 1.0.
 from __future__ import annotations
 
 import functools
+import multiprocessing
 from concurrent.futures import ProcessPoolExecutor
 from typing import Literal, Sequence
 
@@ -83,7 +84,11 @@ def firwin_batch(
         raise ValueError("type-I FIR filters need an odd tap count")
     if workers and workers > 1 and len(bands) >= 4 * workers:
         chunks = np.array_split(np.arange(len(bands)), workers)
-        with ProcessPoolExecutor(max_workers=workers) as pool:
+        # spawn, not fork: a forked child of a process that holds the
+        # TPU would inherit (and contend for) the chip
+        with ProcessPoolExecutor(
+            max_workers=workers, mp_context=multiprocessing.get_context("spawn")
+        ) as pool:
             parts = pool.map(
                 _firwin_chunk,
                 [(numtaps, [bands[i] for i in c], window, scale) for c in chunks],

@@ -58,8 +58,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..distributed.collectives import (get_shard_map, halo_exchange_left,
-                                       shard_map_no_check_kwargs)
+from ..distributed.collectives import halo_exchange_left
 from ..distributed.faultbank import (FaultStats, PendingInvalidated,
                                      ShardCorruption, ShardError, ShardHealth,
                                      ShardLost, ShardTimeout,
@@ -365,18 +364,18 @@ class ShardedFilterBankEngine:
         `BlmacProgram` is lowered through the plain single-device
         `FilterBankEngine` (its autotuned packed/specialized path), and
         the shard list collapses to one host-side closure.  ``device``
-        is the survivor; on the forced-host-platform meshes the tests
-        use, every "device" shares the host, so the plain engine's
-        default placement is the survivor's compute either way."""
+        is the survivor: the engine's operands and every dispatch are
+        placed on it, never on the default device (which may be the
+        one that was lost)."""
         from ..core.costmodel import BankDispatchPlan, ShardedBankPlan
         from .bank import FilterBankEngine
 
-        del device  # simulated-loss placement note above
-        plain = FilterBankEngine(
-            self.program, channels=self.channels, tile=self._tile_arg,
-            merge=self._merge_arg, chunk_hint=self._chunk_hint,
-            interpret=self._interpret_arg, compiled=self._compiled_arg,
-        )
+        with jax.default_device(device):
+            plain = FilterBankEngine(
+                self.program, channels=self.channels, tile=self._tile_arg,
+                merge=self._merge_arg, chunk_hint=self._chunk_hint,
+                interpret=self._interpret_arg, compiled=self._compiled_arg,
+            )
         self._plain = plain
         plan1 = plain.dispatch_plan
         if plan1 is None:
@@ -398,7 +397,8 @@ class ShardedFilterBankEngine:
         self.mesh = None
 
         def run_plain(buf, n):
-            return plain._apply(buf[:, :n])
+            with jax.default_device(device):
+                return plain._apply(buf[:, :n])
 
         self._shards = [(run_plain, 0)]
         self.health = ShardHealth(
@@ -455,8 +455,6 @@ class ShardedFilterBankEngine:
             jax.device_put(jnp.asarray(g.packed.view(np.int32)), repl)
             for g in schedule.groups if g.sel_layers
         )
-        shard_map = get_shard_map()
-        nc = shard_map_no_check_kwargs()
         if self.data_mode == "channels":
             in_specs = (P(DATA_AXIS, None),) + (P(),) * len(ops)
             out_specs = P(None, DATA_AXIS, None)
@@ -479,8 +477,9 @@ class ShardedFilterBankEngine:
             # concatenated outputs are warm-up, trimmed at reassembly
             offset = self._halo
 
-        mapped = shard_map(
-            body, mesh=row_mesh, in_specs=in_specs, out_specs=out_specs, **nc
+        mapped = jax.shard_map(
+            body, mesh=row_mesh, in_specs=in_specs, out_specs=out_specs,
+            check_vma=False,
         )
         jitted = jax.jit(mapped)
         x_sharding = NamedSharding(row_mesh, in_specs[0])

@@ -3,9 +3,11 @@
 TPU adaptation of the paper's machine (DESIGN.md §2): the FPGA executes one
 add per pulse per *sample*; these kernels execute one VPU vector add per
 pulse per *tile of output samples* (lane-parallel, pulse-serial).  The
-symmetric pre-add (Eq. 3) is fused.  All arithmetic is exact int32 — the
-§2.1 bound (16-bit coeffs × 8-bit samples × ≤255 taps fits 32 bits) is
-asserted ONCE at pack time (`core.csd.assert_int32_bound`), not per call.
+symmetric pre-add (Eq. 3) is fused.  All arithmetic is exact: int32
+accumulators — the §2.1 bound (16-bit coeffs × 8-bit samples × ≤255
+taps fits 32 bits) is asserted ONCE at pack time
+(`core.csd.assert_int32_bound`), not per call — and, in the bank
+kernel, bf16 MXU contractions held to `bf16_dot_safe`.
 
 Three modes:
 
@@ -21,16 +23,16 @@ Three modes:
     codes per word, `core.csd.pack_trits` layout: 0b00=0, 0b01=+1,
     0b11=−1, signed CSD end-to-end — ~2× fewer pulses than binary
     layers, paper Tab. 3) and are unpacked in-kernel with shifts and
-    masks.  Each grid step builds the framed `(M, tile)` window matrix
-    ONCE with a single gather and reuses it for every surviving layer
-    and every filter in the bank tile.
+    masks.  Each grid step builds the framed `(K, tile)` window matrix
+    ONCE from static slices of the frame and reuses it for every
+    surviving layer and every filter in the bank tile.
 
     The Horner loop is **schedule-driven**, not fixed-length: at pack
     time `plan_bank_schedule` sorts the filters by layer-occupancy
     signature, partitions them into occupancy-homogeneous bank tiles,
     and emits per-tile-group schedules of *superlayers* — runs of
     ``merge`` adjacent CSD layers contracted in one
-    ``(bank_tile, M) @ (M, tile)`` integer matmul, with one
+    ``(bank_tile, K) @ (K, tile)`` exact bf16 matmul, with one
     ``acc << shift`` per populated superlayer.  Bit layers empty across
     the whole tile cost **zero** kernel work (layer-skip); the schedule
     is static per compiled signature and jit-cached exactly like
@@ -109,22 +111,28 @@ def frame_signal(x: jnp.ndarray, taps: int, tile: int) -> tuple[jnp.ndarray, int
 # specialized single-filter kernel (pulse schedule baked in at trace time)
 # ---------------------------------------------------------------------------
 
+def _window(frame_ref, j: int, tile: int) -> jnp.ndarray:
+    """(1, tile) samples x[t + j] of the frame in ``frame_ref`` (block
+    ``(1, 1, frame_len)``): a static lane-offset slice read from the ref,
+    the form Mosaic lowers (a gather inside the kernel it refuses)."""
+    return frame_ref[0, :, pl.ds(j, tile)]
+
+
+def _fold(frame_ref, j: int, taps: int, tile: int) -> jnp.ndarray:
+    """Row j of the symmetric fold, u_j[t] = x[t+j] + x[t+taps-1-j]
+    (the centre row j = taps // 2 is not folded)."""
+    if j == taps // 2:
+        return _window(frame_ref, j, tile)
+    return _window(frame_ref, j, tile) + _window(frame_ref, taps - 1 - j, tile)
+
+
 def _fir_kernel_specialized(frame_ref, out_ref, *, pulses, taps, tile):
     """One grid step = one output tile.  `pulses` is a static tuple of
     (layer, j, sign) triples, MSB layer first."""
-    fx = frame_ref[0, :].astype(jnp.int32)
-    half = taps // 2
     # symmetric fold, built lazily: only the taps that carry pulses
     needed = sorted({j for (_, j, _) in pulses})
-    u = {}
-    for j in needed:
-        if j == half:
-            u[j] = jax.lax.dynamic_slice(fx, (half,), (tile,))
-        else:
-            a = jax.lax.dynamic_slice(fx, (j,), (tile,))
-            b = jax.lax.dynamic_slice(fx, (taps - 1 - j,), (tile,))
-            u[j] = a + b
-    acc = jnp.zeros((tile,), jnp.int32)
+    u = {j: _fold(frame_ref, j, taps, tile) for j in needed}
+    acc = jnp.zeros((1, tile), jnp.int32)
     layer_of = None
     for layer, j, sign in pulses:  # MSB layer first, grouped by layer
         if layer_of is None:
@@ -135,7 +143,7 @@ def _fir_kernel_specialized(frame_ref, out_ref, *, pulses, taps, tile):
         acc = acc + u[j] if sign > 0 else acc - u[j]
     if layer_of is not None and layer_of > 0:
         acc = acc << layer_of
-    out_ref[0, :] = acc
+    out_ref[...] = acc
 
 
 def pulses_msb_first(qcoeffs: np.ndarray) -> tuple[tuple[int, int, int], ...]:
@@ -166,14 +174,16 @@ def specialized_program(pulses, taps: int, tile: int, interpret: bool):
     def run(x: jnp.ndarray) -> jnp.ndarray:
         frames, n_out = frame_signal(x.astype(jnp.int32), taps, tile)
         n_tiles, frame_len = frames.shape
+        # Mosaic blocks: the last two dims are (8, 128)-multiples or the
+        # array's own, hence the unit middle axis on the frames
         y = pl.pallas_call(
             kern,
             grid=(n_tiles,),
-            in_specs=[pl.BlockSpec((1, frame_len), lambda i: (i, 0))],
-            out_specs=pl.BlockSpec((1, tile), lambda i: (i, 0)),
-            out_shape=jax.ShapeDtypeStruct((n_tiles, tile), jnp.int32),
+            in_specs=[pl.BlockSpec((1, 1, frame_len), lambda i: (i, 0, 0))],
+            out_specs=pl.BlockSpec((1, tile), lambda i: (0, i)),
+            out_shape=jax.ShapeDtypeStruct((1, n_tiles * tile), jnp.int32),
             interpret=interpret,
-        )(frames)
+        )(frames.reshape(n_tiles, 1, frame_len))
         return y.reshape(-1)[:n_out]
 
     return run
@@ -199,7 +209,7 @@ def blmac_fir_specialized(
 
 def _fir_kernel_bank(
     frame_ref, packed_ref, out_ref, *, taps, tile, schedule, tail_shift,
-    bank_tile, n_words
+    bank_tile, n_words, k_pad
 ):
     """One grid step = one (bank tile × signal tile) block of one channel.
 
@@ -214,34 +224,40 @@ def _fir_kernel_bank(
     entry shifts the accumulator left by the layer gap to the previous
     superlayer, sums its ``merge``-adjacent trit layers into one small-
     integer digit matrix, and contracts it against the shared window
-    matrix in ONE ``(bank_tile, M) @ (M, tile)`` integer matmul.  Layers
-    (and whole superlayers) empty across the tile appear nowhere: the
-    emitted program length tracks the occupancy, not the worst case.
-    """
-    fx = frame_ref[0, 0, :].astype(jnp.int32)
-    frame_len = fx.shape[0]
-    half = taps // 2
-    m_pad = n_words * TRITS_PER_WORD
-    # The framed (M, tile) window matrix: one gather, built once per grid
-    # step, shared by every superlayer and every filter in the bank tile.
-    # Row j holds the symmetric fold u_j[t] = x[t+j] + x[t+taps-1-j]
-    # (centre row: no fold); rows past the centre are zero and meet only
-    # zero trits.
-    j = jax.lax.broadcasted_iota(jnp.int32, (m_pad, tile), 0)
-    t = jax.lax.broadcasted_iota(jnp.int32, (m_pad, tile), 1)
-    fwd = fx[jnp.minimum(j + t, frame_len - 1)]
-    rev = fx[jnp.clip(taps - 1 - j + t, 0, frame_len - 1)]
-    u = jnp.where(j < half, fwd + rev, jnp.where(j == half, fwd, 0))
+    matrix in ONE ``(bank_tile, K) @ (K, tile)`` bf16 matmul with f32
+    accumulation — exact under `bf16_dot_safe`, which `_bank_call`
+    asserts for every superlayer.  Layers (and whole superlayers) empty
+    across the tile appear nowhere: the emitted program length tracks
+    the occupancy, not the worst case.
 
-    words = packed_ref[...]  # (bank_tile, n_sel, n_words) int32
-    shifts = 2 * jax.lax.broadcasted_iota(
-        jnp.int32, (n_words, TRITS_PER_WORD), 1
-    )
+    Every step is a form Mosaic lowers: static lane-offset slices of the
+    frame ref (no in-kernel gather), one trit layer read from the ref at
+    a time, a 2-D lane-iota unpack (no reshape), and a bf16 MXU dot (the
+    MXU takes no int32 operands).
+    """
+    half = taps // 2
+    # The (K, tile) window matrix, built once per grid step and shared by
+    # every superlayer and every filter in the bank tile.  Row j holds the
+    # symmetric fold u_j[t] = x[t+j] + x[t+taps-1-j] (centre row: no
+    # fold); rows past the centre are zero and meet only zero trits.
+    rows = [_fold(frame_ref, j, taps, tile) for j in range(half + 1)]
+    if k_pad > half + 1:
+        rows.append(jnp.zeros((k_pad - half - 1, tile), jnp.int32))
+    u = jnp.concatenate(rows, axis=0)
+    u = u.astype(jnp.float32).astype(jnp.bfloat16)  # |u_j| <= 2**8: exact
+
+    # trit m of the folded half-filter sits in word m // 16 at bit 2*(m % 16)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (bank_tile, k_pad), 1)
+    word_of = lane // TRITS_PER_WORD
+    shift = 2 * (lane % TRITS_PER_WORD)
 
     def trit_layer(sel_idx):
-        codes = (words[:, sel_idx, :, None] >> shifts[None]) & 3
-        d = (codes == 1).astype(jnp.int32) - (codes == 3).astype(jnp.int32)
-        return d.reshape(bank_tile, m_pad)
+        words = packed_ref[:, sel_idx, :]  # (bank_tile, n_words) int32
+        w = jnp.zeros((bank_tile, k_pad), jnp.int32)
+        for i in range(n_words):  # lanes past m_pad match no word: zero
+            w = jnp.where(word_of == i, words[:, i:i + 1], w)
+        codes = (w >> shift) & 3
+        return (codes == 1).astype(jnp.int32) - (codes == 3).astype(jnp.int32)
 
     acc = jnp.zeros((bank_tile, tile), jnp.int32)
     for shift_in, parts in schedule:  # MSB → LSB over populated superlayers
@@ -253,12 +269,16 @@ def _fir_kernel_bank(
             if rel:
                 dl = dl << rel
             d = dl if d is None else d + dl
-        # one integer matmul per populated superlayer: every pulse in the
+        # one MXU matmul per populated superlayer: every pulse in the
         # tile is one lane-parallel add inside this contraction
-        acc = acc + jnp.dot(d, u, preferred_element_type=jnp.int32)
+        y = jnp.dot(
+            d.astype(jnp.float32).astype(jnp.bfloat16), u,
+            preferred_element_type=jnp.float32,
+        )
+        acc = acc + y.astype(jnp.int32)
     if tail_shift:
         acc = acc << tail_shift
-    out_ref[...] = acc[:, None, None, :]
+    out_ref[...] = acc
 
 
 @functools.partial(
@@ -277,11 +297,23 @@ def _bank_call(
     bank_tile: int,
     interpret: bool,
 ) -> jnp.ndarray:
-    """Scheduled bank call.  jit's static-argument cache makes this the
-    bank analogue of `specialized_program`: one compile per distinct
-    (schedule, geometry) signature, every later dispatch a cache hit."""
+    """Scheduled bank call → (B_pad, C·n_tiles·tile) int32.  jit's
+    static-argument cache makes this the bank analogue of
+    `specialized_program`: one compile per distinct (schedule, geometry)
+    signature, every later dispatch a cache hit.
+
+    Raises ValueError for a schedule with a superlayer outside
+    `bf16_dot_safe`: the kernel's contraction would not be exact."""
     n_chan, n_tiles, frame_len = frames.shape
     b_pad, n_sel, n_words = packed.shape
+    m_pad = n_words * TRITS_PER_WORD
+    for _, parts in schedule:
+        if not bf16_dot_safe(m_pad, parts):
+            raise ValueError(
+                f"superlayer {parts} is outside the bank kernel's exact "
+                f"bf16 contraction (digits <= {BF16_EXACT_INT}, "
+                f"m_pad={m_pad}): plan it with merge <= {BF16_MERGE_MAX}"
+            )
     kern = functools.partial(
         _fir_kernel_bank,
         taps=taps,
@@ -290,20 +322,28 @@ def _bank_call(
         tail_shift=tail_shift,
         bank_tile=bank_tile,
         n_words=n_words,
+        k_pad=_pad_to(m_pad, LANE),
     )
+    # Mosaic blocks: the last two dims are (8, 128)-multiples or the
+    # array's own — frames get a unit middle axis, the output is one
+    # (B_pad, C·n_tiles·tile) matrix of (bank_tile, tile) blocks
     return pl.pallas_call(
         kern,
         grid=(b_pad // bank_tile, n_chan, n_tiles),
         in_specs=[
-            pl.BlockSpec((1, 1, frame_len), lambda b, c, s: (c, s, 0)),
+            pl.BlockSpec(
+                (1, 1, frame_len), lambda b, c, s: (c * n_tiles + s, 0, 0)
+            ),
             pl.BlockSpec((bank_tile, n_sel, n_words), lambda b, c, s: (b, 0, 0)),
         ],
         out_specs=pl.BlockSpec(
-            (bank_tile, 1, 1, tile), lambda b, c, s: (b, c, s, 0)
+            (bank_tile, tile), lambda b, c, s: (b, c * n_tiles + s)
         ),
-        out_shape=jax.ShapeDtypeStruct((b_pad, n_chan, n_tiles, tile), jnp.int32),
+        out_shape=jax.ShapeDtypeStruct(
+            (b_pad, n_chan * n_tiles * tile), jnp.int32
+        ),
         interpret=interpret,
-    )(frames, packed)
+    )(frames.reshape(n_chan * n_tiles, 1, frame_len), packed)
 
 
 # ---------------------------------------------------------------------------
@@ -312,9 +352,9 @@ def _bank_call(
 #
 # The scheduled kernel above runs on four execution lanes:
 #
-#   "interpret" — the Pallas interpreter (pure python; the historic CI
-#       target every BENCH_fir number was recorded on),
-#   "mosaic"    — pallas_call compiled for TPU,
+#   "interpret" — the Pallas interpreter (the CPU test lane; the historic
+#       CI target every BENCH_fir number was recorded on),
+#   "mosaic"    — pallas_call compiled for TPU: the same kernel body,
 #   "triton"    — pallas_call compiled for GPU,
 #   "xla"       — the SAME superlayer schedule lowered as a plain jitted
 #       XLA program (no Pallas): the always-available compiled CI target,
@@ -361,6 +401,30 @@ def f32_dot_safe(m_pad: int, parts) -> bool:
     """
     bound = sum(1 << rel for _, rel in parts)
     return m_pad * bound * 256 <= F32_EXACT_BOUND
+
+
+# bfloat16 keeps 8 significant bits: every integer of magnitude <= 2**8
+# is exact, so digits and folded 8-bit samples enter the MXU unrounded
+BF16_EXACT_INT = 1 << 8
+# the widest merge whose superlayer digit (<= 2**merge - 1) stays exact
+BF16_MERGE_MAX = 8
+
+
+def bf16_dot_safe(m_pad: int, parts) -> bool:
+    """Whether one superlayer's contraction is EXACT as the Pallas bank
+    kernel runs it: bf16 operands, f32 accumulation on the MXU.
+
+    Under the same 8-bit-sample regime as `f32_dot_safe`, the folded
+    window entries obey ``|u_j| <= 2**8`` (bf16-exact).  The digit needs
+    ``|d_j| <= sum(2**rel) <= 2**8`` (bf16-exact: any merge <=
+    `BF16_MERGE_MAX`), and every partial sum must stay an integer below
+    the f32 mantissa limit — `f32_dot_safe`, which holds up to the
+    largest accepted filter (255 taps → m_pad = 128:
+    128 · 255 · 2**8 < 2**24).  `_bank_call` raises on a schedule
+    outside this bound rather than return a rounded result.
+    """
+    bound = sum(1 << rel for _, rel in parts)
+    return bound <= BF16_EXACT_INT and f32_dot_safe(m_pad, parts)
 
 
 def _lane_interpret(lane: str, interpret: bool) -> bool:
@@ -440,8 +504,12 @@ def _bank_call_xla(
             d = dl if d is None else d + dl
         if f32_dot_safe(m_pad, parts):
             # every partial sum is an integer < 2**24: the f32 dot is
-            # bit-exact, and the f32->s32 convert of exact integers is too
-            y = jnp.dot(d.astype(jnp.float32), u_f32).astype(jnp.int32)
+            # bit-exact, and the f32->s32 convert of exact integers is too.
+            # HIGHEST keeps a TPU from rounding the operands to bf16
+            y = jnp.dot(
+                d.astype(jnp.float32), u_f32,
+                precision=jax.lax.Precision.HIGHEST,
+            ).astype(jnp.int32)
         else:
             y = jnp.dot(d, u, preferred_element_type=jnp.int32)
         acc = acc + y

@@ -26,6 +26,8 @@ planner imports ``blmac_fir`` lazily for the same reason).
 from __future__ import annotations
 
 import collections
+import os
+import pathlib
 
 import jax
 import numpy as np
@@ -37,6 +39,7 @@ __all__ = [
     "resolve_interpret",
     "default_lane",
     "resolve_lane",
+    "use_compilation_cache",
     "autotune_bank_dispatch",
     "autotune_sharded_dispatch",
     "SPECIALIZE_BANK_MAX",
@@ -71,6 +74,28 @@ WIDE_BANK_TILE = 128
 def _default_tile(mode: str, bank_tile: int) -> int:
     return 256 if mode == "scheduled" and bank_tile >= WIDE_BANK_TILE \
         else DEFAULT_TILE
+
+
+CHECKOUT = pathlib.Path(__file__).resolve().parents[3]
+
+
+def use_compilation_cache() -> str:
+    """Turn on JAX's persistent compilation cache for an entry point and
+    return its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is the cache (JAX reads it
+    itself; no other directory is set here).  Otherwise the cache is the
+    fixed ``<checkout>/.jax_cache`` — the path is part of what makes a
+    later process find the entries again.  Every compile is cached: the
+    bank kernels compile in about a second, under JAX's default
+    threshold.  Call it before the first compile.
+    """
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(CHECKOUT / ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return path
 
 
 def default_interpret() -> bool:
@@ -209,6 +234,7 @@ def _autotune(program, channels, tile, chunk_hint, allow_specialized=True,
               lanes=("interpret",)):
     from ..compiler import default_bank_tile
     from ..core.costmodel import BankDispatchPlan, ensure_calibration
+    from .blmac_fir import BF16_MERGE_MAX
 
     n_filters = program.n_filters
 
@@ -230,6 +256,8 @@ def _autotune(program, channels, tile, chunk_hint, allow_specialized=True,
         else:
             cal = ensure_calibration(lane)  # fit-at-first-use, persisted
             merges = COMPILED_MERGE_CANDIDATES
+            if lane != "xla":  # Pallas: only the exact bf16 contraction
+                merges = tuple(m for m in merges if m <= BF16_MERGE_MAX)
         for bt in sorted(bank_tiles):
             for merge in merges:
                 schedule = program.schedule(bt, merge)

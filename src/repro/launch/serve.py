@@ -249,6 +249,9 @@ def main() -> None:
                          "load it to warm-start, write it after compiling")
     args = ap.parse_args()
 
+    from repro.kernels.runtime import use_compilation_cache
+
+    use_compilation_cache()
     if args.fir_bank and args.sessions:
         serve_sessions(args)
         return

@@ -6,10 +6,7 @@ def test_compressed_psum_numerics():
     out = run_py("""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-try:
-    from jax import shard_map
-except ImportError:  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from repro.distributed.collectives import compressed_psum
 mesh = jax.make_mesh((8,), ("data",))
 rng = np.random.default_rng(0)

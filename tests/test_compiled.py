@@ -24,6 +24,7 @@ from repro.kernels.blmac_fir import LANES
 from repro.kernels.runtime import (COMPILED_MERGE_CANDIDATES,
                                    MERGE_CANDIDATES, autotune_sharded_dispatch,
                                    default_lane, resolve_lane)
+from tests._subproc import run_py
 
 
 @pytest.fixture(scope="module", autouse=True)
@@ -201,3 +202,41 @@ def test_sharded_compiled_planning_and_degraded_engine():
     y = eng.push(x)
     expect = fir_bit_layers_batch(x, q)
     assert np.array_equal(np.asarray(y, np.int64), expect)
+
+
+# ---------------------------------------------------------------------------
+# persistent compilation cache placement (entry points)
+# ---------------------------------------------------------------------------
+
+
+def test_compilation_cache_uses_env_dir(tmp_path):
+    """``JAX_COMPILATION_CACHE_DIR`` set: the helper keeps it, sets no
+    other directory, and compiled entries land there."""
+    out = run_py(f"""
+import os
+os.environ["JAX_COMPILATION_CACHE_DIR"] = {str(tmp_path)!r}
+import jax, jax.numpy as jnp
+from repro.kernels.runtime import use_compilation_cache
+assert use_compilation_cache() == {str(tmp_path)!r}
+assert jax.config.jax_compilation_cache_dir == {str(tmp_path)!r}
+jax.jit(lambda x: x * 3 + 1)(jnp.arange(8)).block_until_ready()
+print("ENTRIES", len(os.listdir({str(tmp_path)!r})))
+""", devices=1)
+    assert int(out.split("ENTRIES")[1]) > 0
+
+
+def test_compilation_cache_defaults_to_checkout():
+    """Unset: the cache is the fixed ``<checkout>/.jax_cache``, never a
+    temp, pid- or time-derived path."""
+    out = run_py("""
+import os
+os.environ.pop("JAX_COMPILATION_CACHE_DIR", None)
+import jax
+from repro.kernels.runtime import CHECKOUT, use_compilation_cache
+path = use_compilation_cache()
+assert path == str(CHECKOUT / ".jax_cache") == jax.config.jax_compilation_cache_dir
+assert (CHECKOUT / "chip_smoke.py").exists()
+print("CACHE", path)
+""", devices=1)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert out.split("CACHE")[1].strip() == os.path.join(root, ".jax_cache")

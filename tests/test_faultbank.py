@@ -444,6 +444,35 @@ print("CASCADE_OK", eng2.describe())
     assert "KILL_RECOVER_OK" in out and "CASCADE_OK" in out
 
 
+def test_degraded_engine_runs_on_the_surviving_device():
+    """Losing one of two shards degrades to the plain engine ON THE
+    SURVIVOR: its operands are placed there, not on the default device
+    (which is the one that was lost)."""
+    out = run_py("""
+import jax
+import numpy as np
+from repro.distributed import bank_mesh
+from repro.distributed.faultbank import FaultInjector
+from repro.filters import (ShardedFilterBankEngine, fir_bit_layers_batch,
+                           spread_lowpass_qbank)
+
+q = spread_lowpass_qbank(64, 31)
+x = np.random.default_rng(0).integers(-128, 128, 4 * 300)
+inj = FaultInjector().kill_shard(0, at_chunk=1)
+eng = ShardedFilterBankEngine(q, mesh=bank_mesh(2, 1), n_bank_shards=2,
+                              fault_injector=inj)
+outs = [eng.push(x[k * 300:(k + 1) * 300]) for k in range(4)]
+y = np.concatenate([o for o in outs if o.shape[2]], axis=2)
+assert np.array_equal(y, fir_bit_layers_batch(x, q))
+assert eng.fault_stats()["degraded"] and eng._plain.mode == "packed"
+placed = {d for op in eng._plain._group_ops if op is not None
+          for d in op.devices()}
+assert placed == {jax.devices()[1]}, placed
+print("SURVIVOR_OK")
+""", devices=2)
+    assert "SURVIVOR_OK" in out
+
+
 def test_data_axis_meshes_recover_8_devices():
     out = run_py("""
 import numpy as np

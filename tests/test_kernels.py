@@ -86,3 +86,61 @@ def test_zero_column_group():
     w = np.zeros((64, 8))
     codes, ge = pulse_quantize(w, 2)
     assert np.abs(pulse_dequantize(codes, ge)).max() == 0.0
+
+
+# ---------------------------------------------------------------------------
+# the bank kernel's exact bf16 contraction: worst case and out-of-bound
+# ---------------------------------------------------------------------------
+
+
+def _all_ones_bank(taps: int, n_layers: int) -> np.ndarray:
+    """Packed bank of two filters whose every folded tap carries a trit
+    in each of ``n_layers`` layers: +1 everywhere (digit 2**n - 1 per
+    tap), and −1 everywhere.  Not CSD — the widest superlayer digits any
+    packed operand can put in front of the kernel."""
+    from repro.core.csd import pack_trits
+
+    trits = np.ones((2, n_layers, taps // 2 + 1), np.int8)
+    trits[1] = -1
+    return pack_trits(trits)
+
+
+@pytest.mark.parametrize("samples", ["min", "alternating"])
+def test_bank_kernel_exact_at_bf16_bound(samples):
+    """All +1 (and all −1) trits at merge 8, the longest accepted filter
+    (255 taps → m_pad = 128) and ±128 samples folded to ±256: every
+    operand and partial sum sits at the edge of `bf16_dot_safe`, and the
+    kernel body is still bit-exact against the oracle."""
+    from repro.filters import fir_bit_layers_batch
+    from repro.kernels.blmac_fir import (BF16_MERGE_MAX, bf16_dot_safe,
+                                         blmac_fir_bank, plan_bank_schedule)
+
+    taps, n = 255, 255 + 600
+    packed = _all_ones_bank(taps, BF16_MERGE_MAX)
+    sched = plan_bank_schedule(packed, None, BF16_MERGE_MAX)
+    (shift_in, parts), = sched.groups[0].schedule
+    assert sum(1 << rel for _, rel in parts) == 255
+    assert bf16_dot_safe(128, parts)
+    if samples == "min":
+        x = np.full((1, n), -128)  # every fold −256, centre −128
+    else:
+        x = np.where(np.arange(n) % 2, 127, -128)[None, :]
+    y = blmac_fir_bank(jnp.asarray(x), packed, taps, tile=256,
+                       merge=BF16_MERGE_MAX, fast_path=False, lane="interpret")
+    w = np.full((2, taps), 255, np.int64)
+    w[1] = -255
+    assert np.array_equal(np.asarray(y), fir_bit_layers_batch(x, w))
+
+
+@pytest.mark.parametrize("lane", ["mosaic", "interpret"])
+def test_bank_kernel_raises_beyond_bf16_bound(lane):
+    """A superlayer of nine merged layers (digit 511) is not bf16-exact:
+    the Pallas bank kernel refuses it instead of rounding."""
+    from repro.kernels.blmac_fir import blmac_fir_bank
+
+    taps = 31
+    packed = _all_ones_bank(taps, 9)
+    x = jnp.zeros((1, 200), jnp.int32)
+    with pytest.raises(ValueError, match="bf16"):
+        blmac_fir_bank(x, packed, taps, tile=128, merge=9, fast_path=False,
+                       lane=lane)
