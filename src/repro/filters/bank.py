@@ -41,17 +41,52 @@ and `tests/differential.py`).
 """
 from __future__ import annotations
 
+import dataclasses
+
 import numpy as np
 import jax.numpy as jnp
 
-from ..kernels.runtime import DEFAULT_TILE
+from ..kernels.runtime import DEFAULT_TILE, span
 
 # Legacy crossover (filters below → specialized) — superseded by the
 # autotuner for mode="auto"; kept because external callers used it to
 # pre-decide a forced mode.
 SPECIALIZE_THRESHOLD = 8
 
-__all__ = ["FilterBankEngine", "SPECIALIZE_THRESHOLD", "DEFAULT_TILE"]
+__all__ = ["FilterBankEngine", "PushStats", "SPECIALIZE_THRESHOLD",
+           "DEFAULT_TILE"]
+
+
+@dataclasses.dataclass
+class PushStats:
+    """Cumulative work counters of an engine's pushes (`push_stats()`).
+
+    ``pushes`` counts calls that returned outputs to the caller (`push`
+    and `apply_lanes`); ``outputs_delivered`` the rows × channels ×
+    samples they returned; ``outputs_computed`` the padded rows ×
+    channels × padded columns the device produced for them, summed over
+    tile groups (and shards); ``bytes_read_back`` the bytes copied from
+    device to host.  Plain integers, bumped where the work happens.
+    """
+
+    pushes: int = 0
+    outputs_delivered: int = 0
+    outputs_computed: int = 0
+    bytes_read_back: int = 0
+
+    def as_dict(self) -> dict:
+        return dataclasses.asdict(self)
+
+    def delivered(self, y: np.ndarray) -> None:
+        """Count one call that returned ``y`` to the caller."""
+        self.pushes += 1
+        self.outputs_delivered += y.size
+
+
+def padded_columns(n: int, taps: int, tile: int) -> int:
+    """Output columns the kernels produce for an ``n``-sample buffer
+    framed in ``tile``-sample tiles (`frame_signal_batch`)."""
+    return -(-(n - taps + 1) // tile) * tile
 
 
 class FilterBankEngine:
@@ -222,6 +257,7 @@ class FilterBankEngine:
         self._tail = np.zeros((channels, 0), np.int32)
         self.samples_in = 0
         self.samples_out = 0
+        self.stats = PushStats()
 
     # -- cost model ---------------------------------------------------------
 
@@ -256,16 +292,25 @@ class FilterBankEngine:
             raise ValueError(
                 f"expected {self.channels} channels, got {chunk.shape[0]}"
             )
-        self.samples_in += chunk.shape[1]
-        buf = np.concatenate([self._tail, chunk.astype(np.int32)], axis=1)
-        n = buf.shape[1]
-        if n < self.taps:  # still priming
-            self._tail = buf
-            return np.zeros((self.n_filters, self.channels, 0), np.int32)
-        self._tail = buf[:, n - (self.taps - 1):] if self.taps > 1 else buf[:, :0]
-        y = self._apply(buf)
-        self.samples_out += y.shape[2]
-        return y
+        with span("push", chunk=self.stats.pushes):
+            self.samples_in += chunk.shape[1]
+            with span("stage"):
+                buf = np.concatenate(
+                    [self._tail, chunk.astype(np.int32)], axis=1
+                )
+            n = buf.shape[1]
+            if n < self.taps:  # still priming
+                self._tail = buf
+                y = np.zeros((self.n_filters, self.channels, 0), np.int32)
+            else:
+                self._tail = (
+                    buf[:, n - (self.taps - 1):] if self.taps > 1
+                    else buf[:, :0]
+                )
+                y = self._apply(buf)
+            self.samples_out += y.shape[2]
+            self.stats.delivered(y)
+            return y
 
     def __call__(self, chunk) -> np.ndarray:
         return self.push(chunk)
@@ -275,6 +320,12 @@ class FilterBankEngine:
         self._tail = np.zeros((self.channels, 0), np.int32)
         self.samples_in = 0
         self.samples_out = 0
+
+    def push_stats(self) -> dict:
+        """JSON-able cumulative work counters (see `PushStats`): what the
+        pushes delivered against what the device computed and the host
+        read back — the engine's analogue of a server's ``serve_stats()``."""
+        return self.stats.as_dict()
 
     @property
     def pending(self) -> int:
@@ -343,7 +394,10 @@ class FilterBankEngine:
                 f"lane buffer has {buf.shape[1]} samples, "
                 f"need >= taps ({self.taps})"
             )
-        return self._apply(buf)
+        with span("push", chunk=self.stats.pushes):
+            y = self._apply(buf)
+            self.stats.delivered(y)
+            return y
 
     def _apply(self, buf: np.ndarray) -> np.ndarray:
         from ..kernels.blmac_fir import (bank_schedule_apply, blmac_fir_specialized,
@@ -357,31 +411,48 @@ class FilterBankEngine:
         # entries instead of retracing every push; windows that reach
         # into the padding are dropped below.
         n_pad = -(-n // self.tile) * self.tile
-        if n_pad != n:
-            buf = np.pad(buf, ((0, 0), (0, n_pad - n)))
-        x = jnp.asarray(buf, jnp.int32)
+        cols = padded_columns(n_pad, self.taps, self.tile)
+        with span("stage"):
+            if n_pad != n:
+                buf = np.pad(buf, ((0, 0), (0, n_pad - n)))
+            x = jnp.asarray(buf, jnp.int32)
         if self.mode == "packed":
-            frames, _ = frame_signal_batch(x, self.taps, self.tile)
-            y = bank_schedule_apply(
-                frames,
-                self.bank_schedule,
-                self.taps,
-                self.tile,
-                resolve_interpret(self.interpret),
-                device_groups=self._group_ops,
-                lane=self.lane,
-                combine=self._combine,
-                n_real=self.n_filters if self._combine is not None else None,
-            )  # (B, C, n_tiles * tile), caller order restored + combined
-            return np.asarray(y[:, :, :n_out])
+            with span("dispatch"):
+                frames, _ = frame_signal_batch(x, self.taps, self.tile)
+                y = bank_schedule_apply(
+                    frames,
+                    self.bank_schedule,
+                    self.taps,
+                    self.tile,
+                    resolve_interpret(self.interpret),
+                    device_groups=self._group_ops,
+                    lane=self.lane,
+                    combine=self._combine,
+                    n_real=(self.n_filters if self._combine is not None
+                            else None),
+                )  # (B, C, n_tiles * tile), caller order restored + combined
+                y = y[:, :, :n_out]
+            rows = sum(g.packed.shape[0] for g in self.bank_schedule.groups)
+            self.stats.outputs_computed += rows * self.channels * cols
+            with span("wait"):
+                y.block_until_ready()
+            with span("readback"):
+                out = np.asarray(y)
+            self.stats.bytes_read_back += out.nbytes
+            return out
         out = np.empty((len(self._schedules), self.channels, n_out), np.int32)
-        for b, pulses in enumerate(self._schedules):
-            for c in range(self.channels):
-                out[b, c] = np.asarray(
-                    blmac_fir_specialized(
+        # each filter × channel is read back before the next dispatch,
+        # so every readback sits inside the loop's dispatch span
+        with span("dispatch"):
+            for b, pulses in enumerate(self._schedules):
+                for c in range(self.channels):
+                    yc = blmac_fir_specialized(
                         x[c], pulses, self.taps, self.tile, self.interpret
                     )
-                )[:n_out]
+                    with span("readback"):
+                        out[b, c] = np.asarray(yc)[:n_out]
+                    self.stats.bytes_read_back += yc.nbytes
+        self.stats.outputs_computed += out.shape[0] * self.channels * cols
         if self._combine is not None:
             from ..compiler.lowering import _host_combine_i32
 

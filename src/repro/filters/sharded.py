@@ -65,6 +65,8 @@ from ..distributed.faultbank import (FaultStats, PendingInvalidated,
                                      TransientShardError)
 from ..distributed.sharding import (DATA_AXIS, BankPartition, bank_mesh,
                                     mesh_bank_shape)
+from ..kernels.runtime import span
+from .bank import PushStats, padded_columns
 
 __all__ = ["ShardedFilterBankEngine", "PendingChunk"]
 
@@ -132,10 +134,11 @@ class PendingChunk:
                 "stale and will not be reassembled"
             )
         b, c = self._shape
+        eng = self._engine
         if self.n_out <= 0:
             self._resolved = np.zeros((b, c, 0), np.int32)
+            eng.stats.delivered(self._resolved)
             return self._resolved
-        eng = self._engine
         while True:
             try:
                 out = eng._materialize(self)
@@ -160,7 +163,8 @@ class PendingChunk:
                 raise
             except ShardLost as e:
                 eng._recover(e)  # re-partitions + replays, or re-raises
-        self._resolved = np.ascontiguousarray(out)
+        self._resolved = out
+        eng.stats.delivered(out)
         self._shard_outs = None  # free device references + replay material
         self.snapshot = None
         self.chunk = None
@@ -274,6 +278,7 @@ class ShardedFilterBankEngine:
         self._plain = None  # set when degraded to the unsharded engine
         self._inflight: list[PendingChunk] = []
         self._chunk_idx = 0
+        self.stats = PushStats()
         self._configure(mesh)
         # overlap-save state: the last taps-1 samples of every channel
         self._tail = np.zeros((channels, 0), np.int32)
@@ -345,6 +350,12 @@ class ShardedFilterBankEngine:
         devices = np.asarray(mesh.devices).reshape(n_bank, n_data)
         self._device_rows = [devices[r] for r in range(n_bank)]
         self._shards = []
+        # rows each shard's kernels produce, tile-group padding included
+        self._shard_rows = [
+            len(rows) if sched is None
+            else sum(g.packed.shape[0] for g in sched.groups)
+            for rows, sched in zip(self.partition.assign, schedules)
+        ]
         for s, (rows, plan) in enumerate(
             zip(self.partition.assign, self.plan.shard_plans)
         ):
@@ -401,6 +412,7 @@ class ShardedFilterBankEngine:
                 return plain._apply(buf[:, :n])
 
         self._shards = [(run_plain, 0)]
+        self._shard_rows = [0]  # the plain engine counts its own work
         self.health = ShardHealth(
             1, timeout=self.shard_timeout,
             straggler_factor=self._straggler_factor,
@@ -537,10 +549,11 @@ class ShardedFilterBankEngine:
             )
         idx = self._chunk_idx
         self._chunk_idx += 1
-        snap = self.snapshot_tail()
-        chunk_i = chunk.astype(np.int32)
+        with span("stage", chunk=idx):
+            snap = self.snapshot_tail()
+            chunk_i = chunk.astype(np.int32)
+            buf = np.concatenate([self._tail, chunk_i], axis=1)
         self.samples_in += chunk.shape[1]
-        buf = np.concatenate([self._tail, chunk_i], axis=1)
         n = buf.shape[1]
         if n < self.taps:  # still priming
             self._tail = buf
@@ -571,13 +584,19 @@ class ShardedFilterBankEngine:
         contract."""
         n_pad = -(-n // self._quantum) * self._quantum
         if n_pad != buf.shape[1]:
-            buf = np.pad(buf, ((0, 0), (0, n_pad - buf.shape[1])))
+            with span("stage", chunk=chunk_idx):
+                buf = np.pad(buf, ((0, 0), (0, n_pad - buf.shape[1])))
         outs, offsets = [], []
         for s, (fn, offset) in enumerate(self._shards):
             try:
-                if self.injector is not None:
-                    self.injector.on_dispatch(s, chunk_idx)
-                y = fn(buf, n)
+                with span("shard_dispatch", chunk=chunk_idx, shard=s):
+                    if self.injector is not None:
+                        self.injector.on_dispatch(s, chunk_idx)
+                    y = fn(buf, n)
+                self.stats.outputs_computed += (
+                    self._shard_rows[s] * self.channels
+                    * self._shard_columns(s, n_pad)
+                )
             except ShardError as e:
                 if e.shard is None:
                     e.shard = s
@@ -586,9 +605,18 @@ class ShardedFilterBankEngine:
             offsets.append(offset)
         return outs, offsets
 
+    def _shard_columns(self, s: int, n_pad: int) -> int:
+        """Output columns shard ``s`` produces for an ``n_pad``-sample
+        buffer: time sharding frames each data slice with its halo, which
+        tiles the slice exactly."""
+        if self.data_mode == "time":
+            return n_pad
+        return padded_columns(n_pad, self.taps, self.plan.shard_plans[s].tile)
+
     def push(self, chunk) -> np.ndarray:
         """Synchronous `push_async` → int32 (B, C, n_out)."""
-        return self.push_async(chunk).result()
+        with span("push", chunk=self._chunk_idx):
+            return self.push_async(chunk).result()
 
     def __call__(self, chunk) -> np.ndarray:
         return self.push(chunk)
@@ -634,18 +662,19 @@ class ShardedFilterBankEngine:
         )
         n = buf.shape[1]
         n_out = n - self.taps + 1
-        outs, offsets = self._dispatch_shards(buf, n, idx)
-        p = PendingChunk(
-            self, outs, self.partition.inv, n_out, offsets,
-            self.n_filters, self.channels,
-            snapshot=snap, chunk=buf, chunk_idx=idx,
-        )
-        self._inflight.append(p)
-        try:
-            return p.result()
-        except Exception:
-            p.invalidate()
-            raise
+        with span("push", chunk=idx):
+            outs, offsets = self._dispatch_shards(buf, n, idx)
+            p = PendingChunk(
+                self, outs, self.partition.inv, n_out, offsets,
+                self.n_filters, self.channels,
+                snapshot=snap, chunk=buf, chunk_idx=idx,
+            )
+            self._inflight.append(p)
+            try:
+                return p.result()
+            except Exception:
+                p.invalidate()
+                raise
 
     def reset(self) -> None:
         """Drop all buffered history (start a new stream).  Outstanding
@@ -664,6 +693,18 @@ class ShardedFilterBankEngine:
     def pending(self) -> int:
         """Samples buffered but not yet old enough to finish a window."""
         return self._tail.shape[1]
+
+    def push_stats(self) -> dict:
+        """JSON-able cumulative work counters, as
+        `FilterBankEngine.push_stats`: outputs computed are summed over
+        shards (replays included), and a degraded engine adds its plain
+        engine's device work."""
+        d = self.stats.as_dict()
+        if self._plain is not None:
+            plain = self._plain.stats
+            d["outputs_computed"] += plain.outputs_computed
+            d["bytes_read_back"] += plain.bytes_read_back
+        return d
 
     # -- tail snapshot / restore (content-addressed stream state) -----------
 
@@ -711,7 +752,10 @@ class ShardedFilterBankEngine:
             if isinstance(y, ShardError):
                 raise y
             parts.append(self._materialize_shard(s, p, y, off))
-        return np.concatenate(parts, axis=0)[p._inv]
+        with span("reassemble", chunk=p.chunk_idx):
+            return np.ascontiguousarray(
+                np.concatenate(parts, axis=0)[p._inv]
+            )
 
     def _materialize_shard(self, s, p, y, off):
         inj = self.injector
@@ -725,20 +769,28 @@ class ShardedFilterBankEngine:
                     np.stack([np.asarray(a)[:n_out] for a in chans])
                     for chans in y
                 ]
+                self.stats.bytes_read_back += sum(
+                    a.nbytes for chans in y for a in chans
+                )
                 return np.stack(rows)
-            return np.asarray(y)[:, :, off: off + n_out]
+            host = np.asarray(y)
+            if isinstance(y, jax.Array):  # not the degraded engine's
+                self.stats.bytes_read_back += host.nbytes
+            return host[:, :, off: off + n_out]
 
         t0 = time.perf_counter()
-        if self.health.timeout is not None:
-            part = self._with_timeout(read, s)
-        else:
-            part = read()
+        with span("shard_read", chunk=p.chunk_idx, shard=s):
+            if self.health.timeout is not None:
+                part = self._with_timeout(read, s)
+            else:
+                part = read()
         if self.health.record(s, time.perf_counter() - t0):
             self.fault.stragglers += 1
         if inj is not None:
             part = inj.corrupt(s, p.chunk_idx, part)
         if self.integrity_check:
-            self._verify_part(s, part, p)
+            with span("verify", chunk=p.chunk_idx, shard=s):
+                self._verify_part(s, part, p)
         return part
 
     def _with_timeout(self, fn, s):
@@ -794,6 +846,10 @@ class ShardedFilterBankEngine:
         chosen by modelled cost), rebuild the dispatch closures, and
         replay every in-flight chunk from its tail snapshot.  Raises
         `ShardLost` when no surviving device remains."""
+        with span("recover"):
+            self._recover_from(err)
+
+    def _recover_from(self, err: ShardLost) -> None:
         self.fault.detections += 1
         if isinstance(err, ShardTimeout):
             self.fault.timeouts += 1
@@ -866,10 +922,13 @@ class ShardedFilterBankEngine:
         """Re-dispatch ONE pending chunk from its tail snapshot and
         swap the fresh shard outputs (and the current partition's
         reassembly recipe) into the pending."""
-        buf = np.concatenate(
-            [np.asarray(p.snapshot.tail, np.int32), p.chunk], axis=1
-        )
-        outs, offsets = self._dispatch_shards(buf, buf.shape[1], p.chunk_idx)
+        with span("recover", chunk=p.chunk_idx):
+            buf = np.concatenate(
+                [np.asarray(p.snapshot.tail, np.int32), p.chunk], axis=1
+            )
+            outs, offsets = self._dispatch_shards(
+                buf, buf.shape[1], p.chunk_idx
+            )
         p._rearm(outs, offsets, self.partition.inv)
         self.fault.replayed_chunks += 1
         self.fault.replayed_samples += p.n_out

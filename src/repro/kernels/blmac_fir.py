@@ -69,7 +69,7 @@ from ..compiler.schedule import (  # noqa: F401 — re-exported, moved in PR 5
     BankSchedule, MAX_BANK_TILE, MERGE_DEFAULT, TileGroup, default_bank_tile,
     plan_bank_schedule, superlayer_schedule)
 from ..core.csd import csd_digits, pack_trits, unpack_trits
-from .runtime import resolve_interpret
+from .runtime import resolve_interpret, span
 
 LANE = 128
 TRITS_PER_WORD = 16
@@ -171,6 +171,7 @@ def specialized_program(pulses, taps: int, tile: int, interpret: bool):
     )
 
     @jax.jit
+    @jax.named_scope("blmac/specialized")
     def run(x: jnp.ndarray) -> jnp.ndarray:
         frames, n_out = frame_signal(x.astype(jnp.int32), taps, tile)
         n_tiles, frame_len = frames.shape
@@ -183,6 +184,7 @@ def specialized_program(pulses, taps: int, tile: int, interpret: bool):
             out_specs=pl.BlockSpec((1, tile), lambda i: (0, i)),
             out_shape=jax.ShapeDtypeStruct((1, n_tiles * tile), jnp.int32),
             interpret=interpret,
+            name="blmac_specialized",
         )(frames.reshape(n_tiles, 1, frame_len))
         return y.reshape(-1)[:n_out]
 
@@ -287,6 +289,7 @@ def _fir_kernel_bank(
         "taps", "schedule", "tail_shift", "tile", "bank_tile", "interpret"
     ),
 )
+@jax.named_scope("blmac/bank_kernel")
 def _bank_call(
     frames: jnp.ndarray,  # (C, n_tiles, frame_len) int32
     packed: jnp.ndarray,  # (B_pad, n_sel, n_words) int32, selected layers
@@ -343,6 +346,7 @@ def _bank_call(
             (b_pad, n_chan * n_tiles * tile), jnp.int32
         ),
         interpret=interpret,
+        name="blmac_bank_kernel",
     )(frames.reshape(n_chan * n_tiles, 1, frame_len), packed)
 
 
@@ -441,6 +445,7 @@ def _lane_interpret(lane: str, interpret: bool) -> bool:
     jax.jit,
     static_argnames=("taps", "schedule", "tail_shift", "tile", "n_real"),
 )
+@jax.named_scope("blmac/bank_xla")
 def _bank_call_xla(
     frames: jnp.ndarray,  # (C, n_tiles, frame_len) int32
     packed: jnp.ndarray,  # (B_pad, n_sel, n_words) int32, selected layers
@@ -680,15 +685,16 @@ def bank_schedule_apply(
             if device_groups is not None
             else jnp.asarray(g.packed.view(np.int32))
         )
-        if lane == "xla":
-            y = _bank_call_xla(
-                frames, op, taps, g.schedule, g.tail_shift, tile
-            )
-        else:
-            y = _bank_call(
-                frames, op, taps, g.schedule, g.tail_shift, tile,
-                schedule.tile_size, interpret,
-            )  # (rows, C, n_tiles, tile)
+        with span("group", group=gi):
+            if lane == "xla":
+                y = _bank_call_xla(
+                    frames, op, taps, g.schedule, g.tail_shift, tile
+                )
+            else:
+                y = _bank_call(
+                    frames, op, taps, g.schedule, g.tail_shift, tile,
+                    schedule.tile_size, interpret,
+                )  # (rows, C, n_tiles, tile)
         parts.append(y.reshape(rows, n_chan, -1))
     y = jnp.concatenate(parts, axis=0) if len(parts) > 1 else parts[0]
     y = y[schedule.inv]  # drop pad rows, restore caller's filter order
@@ -698,6 +704,7 @@ def bank_schedule_apply(
 
 
 @functools.partial(jax.jit, static_argnames=("n_real",))
+@jax.named_scope("blmac/combine")
 def _combine_shared(y: jnp.ndarray, combine: jnp.ndarray, n_real: int):
     """Fold shared partial-sum rows (``y[n_real:]``) back into their
     consumers: one (n_real, n_shared) int32 GEMM plus an add.  Exact in

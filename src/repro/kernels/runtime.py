@@ -40,6 +40,7 @@ __all__ = [
     "default_lane",
     "resolve_lane",
     "use_compilation_cache",
+    "span",
     "autotune_bank_dispatch",
     "autotune_sharded_dispatch",
     "SPECIALIZE_BANK_MAX",
@@ -96,6 +97,20 @@ def use_compilation_cache() -> str:
         jax.config.update("jax_compilation_cache_dir", path)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     return path
+
+
+def span(name: str, **ids):
+    """The profiler span ``blmac.<name>`` around one step of the serving
+    path, with small integer ``ids`` (``chunk``, ``group``, ``shard``,
+    ``step``) that tie the spans of one push together.
+
+    A `jax.profiler.TraceAnnotation`: it lands on the profiler's host
+    plane, on the same clock as the device planes, so a trace reader can
+    name what the host was doing while a chip sat idle.  With no
+    profiler running it costs about a microsecond, so the serving path
+    keeps its spans on at all times.
+    """
+    return jax.profiler.TraceAnnotation("blmac." + name, **ids)
 
 
 def default_interpret() -> bool:

@@ -16,6 +16,8 @@ device and double-buffered through `repro.serving.AsyncBankServer`)::
     PYTHONPATH=src python -m repro.launch.serve --fir-bank 256 \
         --taps 63 --channels 1 --chunk 4096 --chunks 32
 
+It ends with the engine's ``push_stats()``: the share of the computed
+outputs that reached the caller and the bytes read back per push.
 Run it under ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` to
 exercise the mesh path on a CPU host.  ``--program-path bank.npz``
 round-trips the compiled `repro.compiler.BlmacProgram` through disk:
@@ -31,7 +33,8 @@ selection, continuously batched into the shared lanes of ONE
 
 Exercises one mid-run `swap_filters` hot-swap and one pause/resume,
 spot-checks a session against the numpy oracle, and prints the
-`serve_stats()` surface (occupancy, queue depth, p50/p99 latency).
+`serve_stats()` surface (occupancy, queue depth, p50/p99 latency, and
+the share of computed filter rows that sessions selected).
 
 ``--journal-path wal/`` makes the session server crash-safe: every
 push/pull/registry change is written ahead to a CRC-framed journal and
@@ -134,6 +137,10 @@ def serve_sessions(args) -> None:
           f"rounds {stats['rounds']}, "
           f"p50 {stats['latency_p50_ms']:.1f}ms / "
           f"p99 {stats['latency_p99_ms']:.1f}ms")
+    if stats["rows_computed"]:
+        print(f"[serve] useful rows: {stats['rows_used']} of "
+              f"{stats['rows_computed']} computed "
+              f"({100 * stats['rows_used'] / stats['rows_computed']:.1f}%)")
     # spot-check one full session stream against the exact numpy oracle
     check = 0
     got = np.concatenate(outs[check], axis=1)
@@ -149,6 +156,18 @@ def serve_sessions(args) -> None:
               f"fsyncs, {j['rotations']} rotations, live segment "
               f"{j['segment_bytes']} bytes at {j['path']}")
     server.close()
+
+
+def push_report(stats: dict) -> str:
+    """One line from an engine's ``push_stats()``: the share of the
+    outputs the device computed that reached the caller, and the bytes
+    read back per push."""
+    pushes, computed = stats["pushes"], stats["outputs_computed"]
+    share = 100 * stats["outputs_delivered"] / computed if computed else 0.0
+    per_push = stats["bytes_read_back"] / pushes if pushes else 0.0
+    return (f"[serve] pushes: {pushes}, {stats['outputs_delivered']} of "
+            f"{computed} computed outputs delivered ({share:.1f}%), "
+            f"{per_push:.0f} bytes read back per push")
 
 
 def serve_fir_bank(args) -> None:
@@ -203,6 +222,7 @@ def serve_fir_bank(args) -> None:
     print(f"[serve] fir-bank: {done} samples/filter/channel in {dt:.2f}s "
           f"({done / dt:.0f} samples/s/filter, "
           f"{done * n * args.channels / dt:.3e} filter-samples/s aggregate)")
+    print(push_report(engine.push_stats()))
     # spot-check the tail chunk against the exact oracle
     if outs and outs[-1].shape[2]:
         t = args.taps

@@ -69,6 +69,7 @@ import zlib
 import numpy as np
 
 from ..core.io import atomic_write, check_format_header, fsync_dir
+from ..kernels.runtime import span
 
 __all__ = ["JOURNAL_FORMAT_VERSION", "JournalFormatError", "SessionJournal"]
 
@@ -239,21 +240,23 @@ class SessionJournal:
             raise RuntimeError(
                 "journal has no live segment — call start_segment() first"
             )
-        blob = _frame(rec)
-        self._f.write(blob)
-        self._size += len(blob)
-        self._dirty = True
-        self.appends += 1
-        if sync:
-            self.sync()
+        with span("journal_append"):
+            blob = _frame(rec)
+            self._f.write(blob)
+            self._size += len(blob)
+            self._dirty = True
+            self.appends += 1
+            if sync:
+                self.sync()
 
     def sync(self) -> None:
         """Group-commit fsync of everything appended since the last sync
         (no-op when clean or when the journal was opened fsync=False)."""
         if self._f is None or not self._dirty:
             return
-        if self.fsync:
-            os.fsync(self._f.fileno())
+        with span("journal_sync"):
+            if self.fsync:
+                os.fsync(self._f.fileno())
         self._dirty = False
         self.syncs += 1
 

@@ -81,6 +81,8 @@ from collections import deque
 
 import numpy as np
 
+from ..kernels.runtime import span
+
 __all__ = ["AdmissionRejected", "BankSession", "BankSessionServer"]
 
 #: per-session latency samples kept for the p50/p99 estimators
@@ -289,6 +291,10 @@ class BankSessionServer:
         self.step_retries = 0  # transient faults absorbed inside step()
         self.session_faults = 0  # dispatch-round faults attributed to tenants
         self._lane_fill = 0  # lanes carrying a session, across all rounds
+        # filter rows × lanes each round computed, and the rows its
+        # sessions selected: the useful-row share of the dispatches
+        self.rows_computed = 0
+        self.rows_used = 0
         self.journal = None
         if journal is not None:
             from .journal import SessionJournal
@@ -843,32 +849,44 @@ class BankSessionServer:
         if not ready:
             return 0
         self.steps += 1
+        with span("step", step=self.steps):
+            return self._step(ready)
+
+    def _step(self, ready: list) -> int:
         taps = self.program.taps
         served = 0
         try:
             for r0 in range(0, len(ready), self.n_slots):
                 batch = ready[r0:r0 + self.n_slots]
-                lane_bufs = []
-                for s in batch:
-                    data = np.concatenate([c for c, _ in s.queue])
-                    lane_bufs.append(
-                        np.concatenate([s.tail[0], data])
-                    )
-                lane_len = max(b.shape[0] for b in lane_bufs)
-                buf = np.zeros((self.n_slots, lane_len), np.int32)
-                for lane, b in enumerate(lane_bufs):
-                    buf[lane, : b.shape[0]] = b
+                with span("lane_pack"):
+                    lane_bufs = []
+                    for s in batch:
+                        data = np.concatenate([c for c, _ in s.queue])
+                        lane_bufs.append(
+                            np.concatenate([s.tail[0], data])
+                        )
+                    lane_len = max(b.shape[0] for b in lane_bufs)
+                    buf = np.zeros((self.n_slots, lane_len), np.int32)
+                    for lane, b in enumerate(lane_bufs):
+                        buf[lane, : b.shape[0]] = b
                 y = self._dispatch_lanes(buf, batch)
                 # y: (B_full, n_slots, lane_len - taps + 1)
                 self.rounds += 1
                 self._lane_fill += len(batch)
+                self.rows_computed += y.shape[0] * self.n_slots
+                self.rows_used += sum(int(s.rows.size) for s in batch)
                 now = time.monotonic()
+                with span("row_slice"):
+                    outs = [
+                        np.ascontiguousarray(
+                            y[s.rows, lane, :b.shape[0] - taps + 1]
+                        )
+                        for lane, (s, b) in enumerate(zip(batch, lane_bufs))
+                    ]
                 for lane, s in enumerate(batch):
                     valid = lane_bufs[lane].shape[0]
                     n_out = valid - taps + 1
-                    s.outbox.append(
-                        np.ascontiguousarray(y[s.rows, lane, :n_out])
-                    )
+                    s.outbox.append(outs[lane])
                     s.tail = lane_bufs[lane][None, valid - (taps - 1):] \
                         if taps > 1 else np.zeros((1, 0), np.int32)
                     s.samples_out += n_out
@@ -935,6 +953,8 @@ class BankSessionServer:
                 self._lane_fill / (self.rounds * self.n_slots)
                 if self.rounds else 0.0
             ),
+            "rows_computed": self.rows_computed,
+            "rows_used": self.rows_used,
             "queue_depth": sum(
                 len(s.queue) for s in self.sessions.values()
             ),

@@ -144,3 +144,43 @@ def test_bank_kernel_raises_beyond_bf16_bound(lane):
     with pytest.raises(ValueError, match="bf16"):
         blmac_fir_bank(x, packed, taps, tile=128, merge=9, fast_path=False,
                        lane=lane)
+
+
+def _lowered_texts():
+    """(scope, kernel name or None, lowered text with locations) of each
+    kernel entry point, lowered on the CPU at a small shape."""
+    from repro.compiler import compile_bank
+    from repro.filters import spread_lowpass_qbank
+    from repro.kernels.blmac_fir import (_bank_call, _bank_call_xla,
+                                         _combine_shared, frame_signal_batch,
+                                         specialized_program)
+
+    prog = compile_bank(spread_lowpass_qbank(8, 31))
+    sched = prog.schedule(8, 4)
+    g = sched.groups[0]
+    frames, _ = frame_signal_batch(jnp.zeros((1, 512), jnp.int32), 31, 128)
+    op = jnp.asarray(g.packed.view(np.int32))
+    bank = _bank_call.lower(frames, op, taps=31, schedule=g.schedule,
+                            tail_shift=g.tail_shift, tile=128, bank_tile=8,
+                            interpret=True)
+    xla = _bank_call_xla.lower(frames, op, taps=31, schedule=g.schedule,
+                               tail_shift=g.tail_shift, tile=128)
+    spec = specialized_program(prog.pulse_schedules()[0], 31, 128,
+                               True).lower(jnp.zeros(512, jnp.int32))
+    comb = _combine_shared.lower(jnp.zeros((6, 1, 128), jnp.int32),
+                                 jnp.ones((4, 2), jnp.int32), n_real=4)
+    return [("blmac/bank_kernel", "blmac_bank_kernel", bank),
+            ("blmac/bank_xla", None, xla),
+            ("blmac/specialized", "blmac_specialized", spec),
+            ("blmac/combine", None, comb)]
+
+
+def test_kernels_carry_stable_names_in_their_hlo():
+    """Each kernel entry point lowers under its own `jax.named_scope`,
+    and each Pallas call under its own ``name``, so a trace reader finds
+    them by name after a refactor."""
+    for scope, name, lowered in _lowered_texts():
+        text = lowered.as_text(debug_info=True)
+        assert scope in text
+        if name is not None:
+            assert name in text

@@ -355,3 +355,57 @@ print("OK", srv.serve_stats()["rounds"])
         devices=8,
     )
     assert "OK" in out
+
+
+# ---------------------------------------------------------------------------
+# observability: the step's spans and the useful-row counters
+# ---------------------------------------------------------------------------
+
+
+def _program_spans(log_dir):
+    """``[name, ids]`` of every ``blmac.`` host event in the trace."""
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+
+    path = glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"),
+                     recursive=True)[0]
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("blmac."):
+                    out.append([ev.name, dict(ev.stats), int(ev.start_ns)])
+    return [s[:2] for s in sorted(out, key=lambda s: s[2])]
+
+
+def test_step_spans_and_useful_row_counters(tmp_path):
+    import jax
+
+    prog = _program(16)
+    srv = BankSessionServer(prog, n_slots=2, interpret=True, auto_step=False,
+                            journal=str(tmp_path / "wal"))
+    sessions = [srv.open_session(r) for r in ([0, 1, 2], [5], [7, 8])]
+    rng = np.random.default_rng(3)
+    with jax.profiler.trace(str(tmp_path / "trace")):
+        for s in sessions:
+            s.push(rng.integers(-128, 128, 100))
+        assert srv.step() == 3
+    st = srv.serve_stats()
+    # two rounds of 2 lanes through all 16 filters; 3 + 1 + 2 rows used
+    assert st["rounds"] == 2
+    assert st["rows_computed"] == 2 * 2 * 16
+    assert st["rows_used"] == 6
+    spans = _program_spans(str(tmp_path / "trace"))
+    names = [n for n, _ in spans]
+    # the pushes' chunk records, then the step and its group commit
+    assert names[:3] == ["blmac.journal_append"] * 3
+    assert spans[3] == ["blmac.step", {"step": 1}]
+    assert names.count("blmac.lane_pack") == 2
+    assert names.count("blmac.row_slice") == 2
+    assert names.count("blmac.push") == 2  # one apply_lanes per round
+    assert names[-1] == "blmac.journal_sync"
+    srv.close()
