@@ -23,9 +23,10 @@ chunk comes back with ``n_bank_shards == 1`` — the engine then degrades
 to the single-device scheduled path bit-for-bit.
 
 Output reassembly is gather-free: per-shard outputs land on their own
-devices, the host reads each shard's block, and ONE precomputed index
-permutation (`BankPartition.inv`) restores the caller's filter order —
-no cross-device collective touches the results.
+devices, the host reads each shard's block, and writes it once into
+its caller rows (`BankPartition.assign`) of one new output — no
+cross-device collective touches the results, and no bank-sized
+intermediate is built on the host.
 
 **Fault tolerance** (see `repro.distributed.faultbank` for the shared
 taxonomy/injector/watchdog): every `push_async` captures a
@@ -73,21 +74,22 @@ __all__ = ["ShardedFilterBankEngine", "PendingChunk"]
 
 class PendingChunk:
     """In-flight outputs of one `push_async`: per-shard device arrays plus
-    the reassembly recipe and the chunk's replay material (tail snapshot
-    + raw samples).  `result()` materializes on the host — each shard's
-    block is read off its own devices and rows are restored to caller
-    order with one index permutation (no device-side gather) — and is
+    the reassembly recipe (the partition's caller rows per shard) and
+    the chunk's replay material (tail snapshot + raw samples).
+    `result()` materializes on the host — each shard's block is read off
+    its own devices, then scattered once into its caller rows of one new
+    output (no device-side gather, no concatenated intermediate) — and is
     where faults are detected and recovered: a lost shard triggers the
     engine's re-partition + replay, a corrupted block is replayed in
     place, a transient error re-arms the chunk and propagates for the
     server's retry loop."""
 
-    def __init__(self, engine, shard_outs, inv, n_out, offsets,
+    def __init__(self, engine, shard_outs, assign, n_out, offsets,
                  n_filters, channels, snapshot=None, chunk=None,
                  chunk_idx=0):
         self._engine = engine
         self._shard_outs = shard_outs
-        self._inv = inv
+        self._assign = assign
         self._offsets = offsets
         self.n_out = int(n_out)
         self._shape = (n_filters, channels)
@@ -98,12 +100,12 @@ class PendingChunk:
         self.chunk_idx = int(chunk_idx)
         self._heals = 0  # corruption replays consumed on this chunk
 
-    def _rearm(self, shard_outs, offsets, inv) -> None:
+    def _rearm(self, shard_outs, offsets, assign) -> None:
         """Swap in a replay's fresh dispatch (possibly from a different
         partition after a recovery re-partition)."""
         self._shard_outs = shard_outs
         self._offsets = offsets
-        self._inv = inv
+        self._assign = assign
 
     def invalidate(self) -> None:
         """Mark the chunk unusable (engine reset / terminal failure):
@@ -558,7 +560,7 @@ class ShardedFilterBankEngine:
         if n < self.taps:  # still priming
             self._tail = buf
             return PendingChunk(
-                self, [], self.partition.inv, 0, [],
+                self, [], self.partition.assign, 0, [],
                 self.n_filters, self.channels,
                 snapshot=snap, chunk=chunk_i, chunk_idx=idx,
             )
@@ -569,7 +571,7 @@ class ShardedFilterBankEngine:
         outs, offsets = self._dispatch_shards(buf, n, idx)
         self.samples_out += n_out
         p = PendingChunk(
-            self, outs, self.partition.inv, n_out, offsets,
+            self, outs, self.partition.assign, n_out, offsets,
             self.n_filters, self.channels,
             snapshot=snap, chunk=chunk_i, chunk_idx=idx,
         )
@@ -665,7 +667,7 @@ class ShardedFilterBankEngine:
         with span("push", chunk=idx):
             outs, offsets = self._dispatch_shards(buf, n, idx)
             p = PendingChunk(
-                self, outs, self.partition.inv, n_out, offsets,
+                self, outs, self.partition.assign, n_out, offsets,
                 self.n_filters, self.channels,
                 snapshot=snap, chunk=buf, chunk_idx=idx,
             )
@@ -746,16 +748,19 @@ class ShardedFilterBankEngine:
     def _materialize(self, p: PendingChunk) -> np.ndarray:
         """Assemble one pending chunk on the host; raises the first
         shard fault it detects (stored dispatch errors, watchdog
-        timeout, integrity-probe corruption)."""
+        timeout, integrity-probe corruption).  Every shard is read and
+        checked before the output is written; each block is then copied
+        once, straight to its caller rows."""
         parts = []
         for s, (y, off) in enumerate(zip(p._shard_outs, p._offsets)):
             if isinstance(y, ShardError):
                 raise y
             parts.append(self._materialize_shard(s, p, y, off))
         with span("reassemble", chunk=p.chunk_idx):
-            return np.ascontiguousarray(
-                np.concatenate(parts, axis=0)[p._inv]
-            )
+            out = np.empty(p._shape + (p.n_out,), np.int32)
+            for rows, part in zip(p._assign, parts):
+                out[rows] = part
+            return out
 
     def _materialize_shard(self, s, p, y, off):
         inj = self.injector
@@ -929,7 +934,7 @@ class ShardedFilterBankEngine:
             outs, offsets = self._dispatch_shards(
                 buf, buf.shape[1], p.chunk_idx
             )
-        p._rearm(outs, offsets, self.partition.inv)
+        p._rearm(outs, offsets, self.partition.assign)
         self.fault.replayed_chunks += 1
         self.fault.replayed_samples += p.n_out
 

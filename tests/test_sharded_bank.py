@@ -2,6 +2,8 @@
 restoration, single-device degradation, mesh-aware autotuning, and the
 multi-device paths (per-shard programs, halo exchange, channel sharding)
 in a forced-8-device subprocess."""
+import json
+
 import numpy as np
 import pytest
 
@@ -224,6 +226,123 @@ assert rep.sharded_mesh[0] >= 1
 print("FIVE_WAY_8DEV_OK", rep.sharded_mesh)
 """, devices=8)
     assert "FIVE_WAY_8DEV_OK" in out
+
+
+# Each case pushes two chunks through a 4-device engine and reports the
+# second push's output: the reassembly under test wraps every
+# `_materialize` call, recording the shard blocks it assembles, the
+# partition rows it assembles them with, and the host memory it
+# allocates after the last shard is read (tracemalloc sees numpy's
+# buffers).
+_REASSEMBLY = """
+import json, tracemalloc
+import numpy as np
+from repro.distributed import bank_mesh
+from repro.distributed.faultbank import FaultInjector
+from repro.filters import (ShardedFilterBankEngine, fir_bit_layers_batch,
+                           spread_lowpass_qbank)
+
+E = ShardedFilterBankEngine
+read_shard, materialize = E._materialize_shard, E._materialize
+calls = []
+
+
+def recorded_shard(self, s, p, y, off):
+    part = read_shard(self, s, p, y, off)
+    call = calls[-1]
+    call["parts"].append(part)
+    if len(call["parts"]) == len(p._shard_outs):
+        call["base"] = tracemalloc.get_traced_memory()[0]
+        tracemalloc.reset_peak()
+    return part
+
+
+def recorded(self, p):
+    calls.append({"parts": [], "assign": p._assign})
+    out = materialize(self, p)
+    calls[-1]["grew"] = tracemalloc.get_traced_memory()[1] - calls[-1]["base"]
+    return out
+
+
+E._materialize_shard, E._materialize = recorded_shard, recorded
+tracemalloc.start()
+q = spread_lowpass_qbank(40, 31)[np.random.default_rng(0).permutation(40)]
+# both pushes pad to one 2,048-sample shape: one compile per shard
+x = np.random.default_rng(1).integers(-128, 128, 4066)
+ref = fir_bit_layers_batch(x, q)
+kill = lambda s, k: FaultInjector().kill_shard(s, at_chunk=k)
+engines = {
+    "bank": lambda: E(q, mesh=bank_mesh(4, 1), n_bank_shards=4),
+    "time": lambda: E(q, mesh=bank_mesh(2, 2), n_bank_shards=2,
+                      data_mode="time"),
+    "degraded": lambda: E(q, mesh=bank_mesh(2, 1), n_bank_shards=2,
+                          fault_injector=kill(0, 0)),
+    "rearmed": lambda: E(q, mesh=bank_mesh(4, 1), n_bank_shards=4,
+                         fault_injector=kill(1, 1)),
+}
+res = {}
+for name, make in engines.items():
+    eng = make()
+    first = eng.push(x[:2048])
+    out = eng.push_async(x[2048:]).result()
+    call = calls[-1]
+    res[name] = {
+        "exact": bool(np.array_equal(np.concatenate([first, out], axis=2),
+                                     ref)),
+        "dtype": str(out.dtype),
+        "c_contiguous": bool(out.flags.c_contiguous),
+        "writeable": bool(out.flags.writeable),
+        "shares": bool(np.shares_memory(out, first) or any(
+            np.shares_memory(out, a) for part in call["parts"]
+            for a in (part, part.base) if isinstance(a, np.ndarray))),
+        "grew": call["grew"] / out.nbytes,
+        "current_assign": call["assign"] is eng.partition.assign,
+        "shards": len(call["assign"]),
+        "parts": len(call["parts"]),
+        "degraded": eng._plain is not None,
+        "data_mode": eng.data_mode,
+        "replayed": eng.fault_stats()["replayed_chunks"],
+    }
+print(json.dumps(res))
+"""
+_REASSEMBLY_CASES = {
+    "bank": dict(shards=4, degraded=False, data_mode="none", replayed=0),
+    "time": dict(shards=2, degraded=False, data_mode="time", replayed=0),
+    "degraded": dict(shards=1, degraded=True, data_mode="none", replayed=1),
+    "rearmed": dict(shards=3, degraded=False, data_mode="none", replayed=1),
+}
+
+
+@pytest.fixture(scope="module")
+def reassembled():
+    out = run_py(_REASSEMBLY, devices=4)
+    return json.loads(out.strip().splitlines()[-1])
+
+
+@pytest.mark.parametrize("case", sorted(_REASSEMBLY_CASES))
+def test_sharded_push_output_is_a_fresh_exact_array(reassembled, case):
+    """The pushed output equals the oracle bit for bit, and is a new
+    C-contiguous, writeable int32 array that shares no memory with any
+    shard block or with the previous push's output.  A chunk re-armed
+    after a re-partition (``rearmed``) or a degradation (``degraded``)
+    is assembled with the partition it was replayed on."""
+    got = reassembled[case]
+    assert got["exact"]
+    assert got["dtype"] == "int32"
+    assert got["c_contiguous"] and got["writeable"]
+    assert not got["shares"]
+    assert got["current_assign"]
+    assert got["parts"] == got["shards"]
+    for key, want in _REASSEMBLY_CASES[case].items():
+        assert got[key] == want, (key, got)
+
+
+@pytest.mark.parametrize("case", sorted(_REASSEMBLY_CASES))
+def test_sharded_reassembly_is_one_pass(reassembled, case):
+    """Once the shards are read, assembling the output allocates the
+    output and nothing of its size besides: no concatenated or permuted
+    bank-sized intermediate (a second pass would grow it to ~2×)."""
+    assert 1.0 <= reassembled[case]["grew"] < 1.25, reassembled[case]
 
 
 def test_async_double_buffered_server():
