@@ -52,6 +52,10 @@ class TailSnapshot:
     self-describing — which tenant a frozen stream belongs to rides
     with the artifact, not in a side table.  Engines ignore it; files
     written before the field existed load with ``session=""``.
+
+    ``sample_bits`` is the stream's sample width (16, 8, or None before
+    the stream's first push), which a restoring engine adopts; files
+    written before the field existed load with None.
     """
 
     program_key: str
@@ -60,6 +64,7 @@ class TailSnapshot:
     samples_out: int
     tail: np.ndarray
     session: str = ""
+    sample_bits: int | None = None
 
     def save(self, path) -> None:
         """Atomic npz write (`repro.core.io.atomic_write`), mirroring
@@ -73,6 +78,7 @@ class TailSnapshot:
             "samples_in": int(self.samples_in),
             "samples_out": int(self.samples_out),
             "session": str(self.session),
+            "sample_bits": self.sample_bits,
         }
         atomic_write(path, lambda f: np.savez(
             f,
@@ -109,4 +115,5 @@ class TailSnapshot:
             samples_out=int(header["samples_out"]),
             tail=tail,
             session=str(header.get("session", "")),
+            sample_bits=header.get("sample_bits"),
         )

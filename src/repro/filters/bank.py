@@ -30,10 +30,15 @@ specialized mode, and the §4 cycle predictions.  Two engines built on
 the same bank share one program (content-addressed), and an engine built
 from a `BlmacProgram.load()`ed file starts without recompiling anything.
 
-Arithmetic contract: int32 throughout.  The §2.1 bound (16-bit coeffs ×
-8-bit samples × ≤255 taps) is asserted ONCE, inside `compile_bank` —
-neither `push` nor the kernels re-check it, and `blmac_fir_dynamic`
-documents the identical guarantee.
+Arithmetic contract: int32 throughout the kernels.  The §2.1 bound
+(16-bit coeffs × 8-bit samples × ≤255 taps) is asserted ONCE, inside
+`compile_bank` — neither `push` nor the kernels re-check it, and
+`blmac_fir_dynamic` documents the identical guarantee.  A stream's
+sample width comes from its data (`stream_bits`): 8-bit streams return
+int32.  A 16-bit stream (int16 input) is split on the host into two
+8-bit planes, ``x = 256 · hi + lo``, run as 2C channels of the same
+kernels, and combined on the device into exact int64 outputs
+(`repro.kernels.blmac_fir.plane_combine`).
 
 Bit-exactness: all modes agree with `repro.filters.fir_bit_layers_batch`
 to the last bit on integer inputs (property-tested in `tests/test_bank.py`
@@ -81,6 +86,40 @@ class PushStats:
         """Count one call that returned ``y`` to the caller."""
         self.pushes += 1
         self.outputs_delivered += y.size
+
+
+INT8_MIN, INT8_MAX = -128, 127
+
+
+def stream_bits(chunk: np.ndarray) -> int:
+    """The sample width a chunk states: 16 for int16 data; 8 for any
+    other integer data whose values fit int8.  Raises ValueError for
+    anything else, so a sample the kernels cannot serve exactly never
+    comes back silently wrong."""
+    if chunk.dtype == np.int16:
+        return 16
+    if chunk.dtype.kind not in "iu":
+        raise ValueError(f"samples must be integers, got {chunk.dtype}")
+    check_8bit(chunk, "an 8-bit stream (int16 data makes a 16-bit one)")
+    return 8
+
+
+def check_8bit(x: np.ndarray, what: str) -> None:
+    """Raise ValueError unless every value of ``x`` fits int8."""
+    if x.size and (x.min() < INT8_MIN or x.max() > INT8_MAX):
+        raise ValueError(
+            f"{what} takes 8-bit samples in [{INT8_MIN}, {INT8_MAX}], "
+            f"got values in [{x.min()}, {x.max()}]"
+        )
+
+
+def split_planes(buf: np.ndarray) -> np.ndarray:
+    """(C, n) 16-bit samples → (2C, n) int32: the low planes
+    ``lo = ((x + 128) & 255) − 128`` in [−128, 127], then the high
+    planes ``hi = (x − lo) >> 8`` in [−128, 128], so ``x = 256 · hi +
+    lo`` and both fold (Eq. 3) within the 8-bit kernels' |u| ≤ 2**8."""
+    lo = ((buf + 128) & 255) - 128
+    return np.concatenate([lo, (buf - lo) >> 8])
 
 
 def padded_columns(n: int, taps: int, tile: int) -> int:
@@ -253,11 +292,14 @@ class FilterBankEngine:
             self.bank_tile = bank_tile
             self._group_ops = None
             self._schedules = program.pulse_schedules()
-        # overlap-save state: the last taps-1 samples of every channel
+        # overlap-save state: the last taps-1 samples of every channel,
+        # and the stream's sample width (None until the first push)
         self._tail = np.zeros((channels, 0), np.int32)
         self.samples_in = 0
         self.samples_out = 0
+        self.sample_bits = None
         self.stats = PushStats()
+        self.wide_pushes = 0
 
     # -- cost model ---------------------------------------------------------
 
@@ -283,8 +325,15 @@ class FilterBankEngine:
 
     def push(self, chunk) -> np.ndarray:
         """Feed (C, n) samples (or (n,) when C == 1); returns the newly
-        computable outputs as int32 (B, C, n_out) — n_out may be 0 while
-        the engine is still priming its taps−1 history."""
+        computable outputs (B, C, n_out) — n_out may be 0 while the
+        engine is still priming its taps−1 history.
+
+        The first push fixes the stream's sample width (`stream_bits`):
+        int16 data makes a 16-bit stream, whose outputs are exact int64;
+        any other integer data whose values fit int8 makes an 8-bit
+        stream, whose outputs are int32.  A later push of another width,
+        or values outside the width, raise ValueError; `reset` forgets
+        the width."""
         chunk = np.asarray(chunk)
         if chunk.ndim == 1:
             chunk = chunk[None, :]
@@ -292,6 +341,13 @@ class FilterBankEngine:
             raise ValueError(
                 f"expected {self.channels} channels, got {chunk.shape[0]}"
             )
+        bits = stream_bits(chunk)
+        if self.sample_bits is not None and bits != self.sample_bits:
+            raise ValueError(
+                f"this stream is {self.sample_bits}-bit, the chunk is "
+                f"{bits}-bit: reset() starts a new stream"
+            )
+        self.sample_bits = bits
         with span("push", chunk=self.stats.pushes):
             self.samples_in += chunk.shape[1]
             with span("stage"):
@@ -301,31 +357,38 @@ class FilterBankEngine:
             n = buf.shape[1]
             if n < self.taps:  # still priming
                 self._tail = buf
-                y = np.zeros((self.n_filters, self.channels, 0), np.int32)
+                y = np.zeros((self.n_filters, self.channels, 0),
+                             np.int64 if bits == 16 else np.int32)
             else:
                 self._tail = (
                     buf[:, n - (self.taps - 1):] if self.taps > 1
                     else buf[:, :0]
                 )
-                y = self._apply(buf)
+                # an 8-bit push calls `_apply` as it always has
+                y = (self._apply(buf, wide=True) if bits == 16
+                     else self._apply(buf))
             self.samples_out += y.shape[2]
             self.stats.delivered(y)
+            self.wide_pushes += bits == 16
             return y
 
     def __call__(self, chunk) -> np.ndarray:
         return self.push(chunk)
 
     def reset(self) -> None:
-        """Drop all buffered history (start a new stream)."""
+        """Drop all buffered history and the sample width (start a new
+        stream)."""
         self._tail = np.zeros((self.channels, 0), np.int32)
         self.samples_in = 0
         self.samples_out = 0
+        self.sample_bits = None
 
     def push_stats(self) -> dict:
         """JSON-able cumulative work counters (see `PushStats`): what the
         pushes delivered against what the device computed and the host
-        read back — the engine's analogue of a server's ``serve_stats()``."""
-        return self.stats.as_dict()
+        read back — the engine's analogue of a server's ``serve_stats()``
+        — plus ``wide_pushes``, the pushes of a 16-bit stream."""
+        return dict(self.stats.as_dict(), wide_pushes=self.wide_pushes)
 
     @property
     def pending(self) -> int:
@@ -348,12 +411,14 @@ class FilterBankEngine:
             program_key=self.program.key, channels=self.channels,
             samples_in=self.samples_in, samples_out=self.samples_out,
             tail=self._tail.copy(), session=str(session),
+            sample_bits=self.sample_bits,
         )
 
     def restore_tail(self, snapshot) -> None:
         """Adopt a `TailSnapshot` captured on THIS program (validated by
         content key — restoring another bank's stream is a loud error,
-        never a silently wrong output)."""
+        never a silently wrong output).  The stream keeps the
+        snapshot's sample width."""
         if snapshot.program_key != self.program.key:
             raise ValueError(
                 f"snapshot belongs to program {snapshot.program_key[:12]}…, "
@@ -367,13 +432,15 @@ class FilterBankEngine:
         self._tail = np.asarray(snapshot.tail, np.int32).copy()
         self.samples_in = int(snapshot.samples_in)
         self.samples_out = int(snapshot.samples_out)
+        self.sample_bits = snapshot.sample_bits
 
     # -- one-shot application ----------------------------------------------
 
     def apply_lanes(self, buf) -> np.ndarray:
         """Stateless one-shot bank application over ``channels`` lanes.
 
-        ``buf`` is (C, n) int samples with ``n >= taps``; returns the full
+        ``buf`` is (C, n) 8-bit samples (values in int8; anything else
+        raises ValueError) with ``n >= taps``; returns the full int32
         (B, C, n − taps + 1) output without touching the engine's
         overlap-save tail or stream counters.  This is the batched
         multi-select dispatch surface the session server builds on: it
@@ -383,7 +450,9 @@ class FilterBankEngine:
         result — bit-exactness per lane follows from `push` and
         `apply_lanes` sharing the same `_apply` path.
         """
-        buf = np.asarray(buf, np.int32)
+        buf = np.asarray(buf)
+        check_8bit(buf, "apply_lanes")
+        buf = buf.astype(np.int32, copy=False)
         if buf.ndim != 2 or buf.shape[0] != self.channels:
             raise ValueError(
                 f"expected ({self.channels}, n) lane buffer, "
@@ -399,9 +468,11 @@ class FilterBankEngine:
             self.stats.delivered(y)
             return y
 
-    def _apply(self, buf: np.ndarray) -> np.ndarray:
+    def _apply(self, buf: np.ndarray, wide: bool = False) -> np.ndarray:
+        """The bank's outputs on the (C, n) int32 buffer: int32, or for
+        ``wide`` (16-bit samples) int64 through the two 8-bit planes."""
         from ..kernels.blmac_fir import (bank_schedule_apply, blmac_fir_specialized,
-                                         frame_signal_batch)
+                                         frame_signal_batch, plane_combine)
         from ..kernels.runtime import resolve_interpret
 
         n = buf.shape[1]
@@ -413,10 +484,15 @@ class FilterBankEngine:
         n_pad = -(-n // self.tile) * self.tile
         cols = padded_columns(n_pad, self.taps, self.tile)
         with span("stage"):
+            if wide:
+                with span("planes"):
+                    buf = split_planes(buf)
             if n_pad != n:
                 buf = np.pad(buf, ((0, 0), (0, n_pad - n)))
             x = jnp.asarray(buf, jnp.int32)
+        lanes = buf.shape[0]  # channels, or the 2C planes of a wide push
         if self.mode == "packed":
+            interpret = resolve_interpret(self.interpret)
             with span("dispatch"):
                 frames, _ = frame_signal_batch(x, self.taps, self.tile)
                 y = bank_schedule_apply(
@@ -424,37 +500,45 @@ class FilterBankEngine:
                     self.bank_schedule,
                     self.taps,
                     self.tile,
-                    resolve_interpret(self.interpret),
+                    interpret,
                     device_groups=self._group_ops,
                     lane=self.lane,
                     combine=self._combine,
                     n_real=(self.n_filters if self._combine is not None
                             else None),
-                )  # (B, C, n_tiles * tile), caller order restored + combined
-                y = y[:, :, :n_out]
+                )  # (B, lanes, n_tiles * tile), caller order restored + combined
+                if wide:
+                    with span("plane_combine"):
+                        y = plane_combine(y, n_out, interpret)
+                else:
+                    y = y[:, :, :n_out]
             rows = sum(g.packed.shape[0] for g in self.bank_schedule.groups)
-            self.stats.outputs_computed += rows * self.channels * cols
+            self.stats.outputs_computed += rows * lanes * cols
             with span("wait"):
                 y.block_until_ready()
             with span("readback"):
                 out = np.asarray(y)
             self.stats.bytes_read_back += out.nbytes
-            return out
-        out = np.empty((len(self._schedules), self.channels, n_out), np.int32)
+            # a wide push's words, less the padding of their last block
+            return out[:, :, :2 * n_out].view(np.int64) if wide else out
+        out = np.empty((len(self._schedules), lanes, n_out), np.int32)
         # each filter × channel is read back before the next dispatch,
         # so every readback sits inside the loop's dispatch span
         with span("dispatch"):
             for b, pulses in enumerate(self._schedules):
-                for c in range(self.channels):
+                for c in range(lanes):
                     yc = blmac_fir_specialized(
                         x[c], pulses, self.taps, self.tile, self.interpret
                     )
                     with span("readback"):
                         out[b, c] = np.asarray(yc)[:n_out]
                     self.stats.bytes_read_back += yc.nbytes
-        self.stats.outputs_computed += out.shape[0] * self.channels * cols
+        self.stats.outputs_computed += out.shape[0] * lanes * cols
         if self._combine is not None:
             from ..compiler.lowering import _host_combine_i32
 
             out = _host_combine_i32(out, self._combine, self.n_filters)
+        if wide:
+            c = self.channels
+            return (out[:, c:].astype(np.int64) << 8) + out[:, :c]
         return out

@@ -67,7 +67,11 @@ from ..distributed.faultbank import (FaultStats, PendingInvalidated,
 from ..distributed.sharding import (DATA_AXIS, BankPartition, bank_mesh,
                                     mesh_bank_shape)
 from ..kernels.runtime import span
-from .bank import PushStats, padded_columns
+from .bank import PushStats, check_8bit, padded_columns
+
+# what a push of wider samples is told (`check_8bit`)
+WIDE_REFUSAL = ("ShardedFilterBankEngine (16-bit streams go through "
+                "FilterBankEngine, as int16 data)")
 
 __all__ = ["ShardedFilterBankEngine", "PendingChunk"]
 
@@ -549,6 +553,7 @@ class ShardedFilterBankEngine:
             raise ValueError(
                 f"expected {self.channels} channels, got {chunk.shape[0]}"
             )
+        check_8bit(chunk, WIDE_REFUSAL)
         idx = self._chunk_idx
         self._chunk_idx += 1
         with span("stage", chunk=idx):
@@ -642,7 +647,9 @@ class ShardedFilterBankEngine:
         the pending so no stale dispatch leaks into ``_inflight``."""
         from ..compiler.state import TailSnapshot
 
-        buf = np.asarray(buf, np.int32)
+        buf = np.asarray(buf)
+        check_8bit(buf, WIDE_REFUSAL)
+        buf = buf.astype(np.int32, copy=False)
         if buf.ndim != 2 or buf.shape[0] != self.channels:
             raise ValueError(
                 f"expected ({self.channels}, n) lane buffer, "
@@ -738,6 +745,9 @@ class ShardedFilterBankEngine:
                 f"snapshot has {snapshot.channels} channels, "
                 f"engine has {self.channels}"
             )
+        if snapshot.sample_bits == 16:
+            raise ValueError(f"a 16-bit stream's snapshot: {WIDE_REFUSAL} "
+                             "takes 8-bit samples")
         self.reset()
         self._tail = np.asarray(snapshot.tail, np.int32).copy()
         self.samples_in = int(snapshot.samples_in)

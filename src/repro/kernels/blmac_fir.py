@@ -43,6 +43,10 @@ Three modes:
     itself fast-paths B≤1 *packed* banks to the specialized program —
     the route that erased the PR-1 B=1 framing regression.
 
+16-bit samples run as two 8-bit planes through the same kernels:
+`plane_combine` (one Pallas kernel, ``blmac_plane_combine``) turns the
+planes' int32 outputs into exact int64 words on the device.
+
 Input layout: the host frames each channel into overlapping tiles
 (n_tiles, tile + taps − 1 padded to a lane multiple); BlockSpec then maps
 one frame per grid step into VMEM.  The ~taps/tile halo duplication
@@ -63,6 +67,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ..compiler.program import compile_packed, pack_bank_trits  # noqa: F401
 from ..compiler.schedule import (  # noqa: F401 — re-exported, moved in PR 5
@@ -701,6 +706,70 @@ def bank_schedule_apply(
     if combine is not None:
         y = _combine_shared(y, combine, n_real)
     return y
+
+
+# ---------------------------------------------------------------------------
+# 16-bit samples: the two 8-bit planes' outputs combined into int64 words
+# ---------------------------------------------------------------------------
+
+COMBINE_ROWS = 128  # filters per combine block: the transposes' lane width
+COMBINE_COLS = 256  # output samples per combine block
+_SIGN = -(1 << 31)
+
+
+def _plane_words(lo, hi):
+    """The little-endian int32 words (low, high) of ``256 · hi + lo``,
+    exactly, for any int32 ``lo`` and ``hi``: ``256 · hi`` is split at
+    bit 24 of ``hi``, and the carry out of the low word is an unsigned
+    compare (signed after flipping the sign bit)."""
+    shifted = (hi & 0xFFFFFF) << 8  # the low 32 bits of 256 · hi
+    low = shifted + lo  # wraps mod 2**32
+    carry = ((low ^ _SIGN) < (shifted ^ _SIGN)).astype(jnp.int32)
+    return low, (hi >> 24) + (lo >> 31) + carry
+
+
+def _plane_combine_kernel(y_ref, out_ref, pairs_ref, *, channels):
+    """One (filters × samples) block: channel c's outputs are the low
+    plane's (row c of ``y_ref``) plus 256 × the high plane's (row
+    channels + c), written as interleaved (low, high) word pairs — the
+    bytes of little-endian int64.  The interleave goes through the
+    transposed scratch: word rows stored at stride 2 along sublanes."""
+    for c in range(channels):
+        low, high = _plane_words(y_ref[:, c, :], y_ref[:, channels + c, :])
+        n = low.shape[1]
+        pairs_ref[pl.ds(0, n, stride=2), :] = low.T
+        pairs_ref[pl.ds(1, n, stride=2), :] = high.T
+        out_ref[:, c, :] = pairs_ref[...].T
+
+
+@functools.partial(jax.jit, static_argnames=("n_out", "interpret"))
+@jax.named_scope("blmac/plane_combine")
+def plane_combine(y: jnp.ndarray, n_out: int, interpret: bool) -> jnp.ndarray:
+    """(B, 2C, cols) int32 outputs of the low planes (channels 0..C-1)
+    and high planes (C..2C-1) of C 16-bit channels → (B, C, 2 · n_pad)
+    int32: the first ``n_out`` outputs of each channel, ``256 · hi +
+    lo``, as int64 words in little-endian order, in rows padded to
+    whole blocks of ``COMBINE_COLS`` outputs.  The host reads the
+    words with ``np.asarray(out)[..., :2 * n_out].view(np.int64)``:
+    (B, C, n_out), exact.  The padding keeps the rows whole 128-lane
+    tiles: for a ragged row a TPU lays the result out column-major, and
+    its host copy cannot be read as int64 words."""
+    b, two_c, _ = y.shape
+    c = two_c // 2
+    n_pad = pl.cdiv(n_out, COMBINE_COLS) * COMBINE_COLS
+    return pl.pallas_call(
+        functools.partial(_plane_combine_kernel, channels=c),
+        grid=(pl.cdiv(b, COMBINE_ROWS), pl.cdiv(n_out, COMBINE_COLS)),
+        in_specs=[pl.BlockSpec((COMBINE_ROWS, two_c, COMBINE_COLS),
+                               lambda i, j: (i, 0, j))],
+        out_specs=pl.BlockSpec((COMBINE_ROWS, c, 2 * COMBINE_COLS),
+                               lambda i, j: (i, 0, j)),
+        out_shape=jax.ShapeDtypeStruct((b, c, 2 * n_pad), jnp.int32),
+        scratch_shapes=[pltpu.VMEM((2 * COMBINE_COLS, COMBINE_ROWS),
+                                   jnp.int32)],
+        interpret=interpret,
+        name="blmac_plane_combine",
+    )(y)
 
 
 @functools.partial(jax.jit, static_argnames=("n_real",))

@@ -81,6 +81,7 @@ from collections import deque
 
 import numpy as np
 
+from ..filters.bank import check_8bit
 from ..kernels.runtime import span
 
 __all__ = ["AdmissionRejected", "BankSession", "BankSessionServer"]
@@ -731,6 +732,8 @@ class BankSessionServer:
             raise ValueError(
                 f"session chunks are 1-D sample vectors, got {chunk.shape}"
             )
+        check_8bit(chunk, "BankSessionServer (16-bit streams go through "
+                          "FilterBankEngine, as int16 data)")
         chunk = chunk.astype(np.int32, copy=False)
         self._seq += 1
         session.last_active = self._seq

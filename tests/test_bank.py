@@ -242,13 +242,19 @@ def test_engine_final_chunk_not_tile_multiple(mode):
 
 
 def test_engine_tail_state_and_output_dtype():
-    """The carried tail must stay int32 whatever integer dtype is pushed,
-    and outputs are int32 — the serving-side contract."""
+    """The carried tail must stay int32 whatever integer dtype is pushed;
+    outputs are int32 on an 8-bit stream (any integer dtype whose values
+    fit int8) and int64 on a 16-bit one (int16 data) — the serving-side
+    contract."""
     q = _qbank(2, 15)
     eng = FilterBankEngine(q, channels=1, tile=128)
-    for dtype in (np.int8, np.int16, np.int32, np.int64):
+    for dtype, out in ((np.int8, np.int32), (np.int32, np.int32),
+                       (np.int64, np.int32), (np.int16, np.int64)):
+        if dtype == np.int16:
+            eng.reset()
+            eng.push(np.ones(14, dtype))
         y = eng.push(np.ones(20, dtype))
-        assert y.dtype == np.int32
+        assert y.dtype == out
         assert eng._tail.dtype == np.int32
         assert eng._tail.shape == (1, 14)
     eng.reset()

@@ -31,7 +31,8 @@ def test_packed_engine_counts_delivered_computed_and_read(program):
     rows = sum(g.packed.shape[0] for g in eng.bank_schedule.groups)
     assert rows > B  # the last tile group is padded
     assert eng.push_stats() == {"pushes": 0, "outputs_delivered": 0,
-                                "outputs_computed": 0, "bytes_read_back": 0}
+                                "outputs_computed": 0, "bytes_read_back": 0,
+                                "wide_pushes": 0}
     want = dict.fromkeys(eng.push_stats(), 0)
     tail = 0
     for x in _chunks(3, 200):
@@ -58,7 +59,8 @@ def test_priming_push_counts_a_push_and_no_work(program):
     eng = FilterBankEngine(program, channels=C, mode="packed", tile=128)
     eng.push(np.zeros((C, TAPS - 5), np.int32))
     assert eng.push_stats() == {"pushes": 1, "outputs_delivered": 0,
-                                "outputs_computed": 0, "bytes_read_back": 0}
+                                "outputs_computed": 0, "bytes_read_back": 0,
+                                "wide_pushes": 0}
 
 
 def test_specialized_engine_counts_per_filter_programs():
@@ -71,7 +73,7 @@ def test_specialized_engine_counts_per_filter_programs():
     assert eng.push_stats() == {
         "pushes": 1, "outputs_delivered": 3 * C * 270,
         "outputs_computed": 3 * C * 384,
-        "bytes_read_back": 3 * C * 354 * 4}
+        "bytes_read_back": 3 * C * 354 * 4, "wide_pushes": 0}
 
 
 def test_sharded_engine_on_one_device_counts_like_the_plain_engine(program):

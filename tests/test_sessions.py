@@ -159,7 +159,7 @@ def test_snapshot_session_field_round_trips_through_disk(tmp_path):
     assert s2.session_id == "tenant-42"
     ref = FilterBankEngine(prog, channels=1, interpret=True)
     ref.push(np.arange(100)[None, :])
-    x = np.arange(100, 160)
+    x = np.arange(100, 160) - 128  # 8-bit samples
     s2.push(x)
     srv.step()
     assert np.array_equal(s2.pull(), ref.push(x[None, :])[[0, 2], 0])

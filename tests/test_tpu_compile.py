@@ -28,7 +28,7 @@ from repro.distributed import halo_exchange_left
 from repro.filters import FilterBankEngine, spread_lowpass_qbank, sweep_bank
 from repro.kernels.blmac_fir import (_bank_call, _bank_call_xla,
                                      bank_schedule_apply, frame_signal_batch,
-                                     specialized_program)
+                                     plane_combine, specialized_program)
 
 TAPS = CONFIG.taps
 CHUNK = 16384
@@ -105,6 +105,19 @@ def test_mosaic_bank_kernel_compiles(bank, one_chip):
         eng = _engine(q, 1)
     texts = _compile_groups(eng, one_chip)
     assert texts and all("tpu_custom_call" in t for t in texts)
+
+
+@pytest.mark.parametrize("n_out", [4096, 3970])
+def test_plane_combine_kernel_compiles(n_out, one_chip):
+    """The 16-bit path's combine kernel at the I/Q sweep's shapes: the
+    9,900-filter bank's two planes of two channels, steady pushes and
+    the first push (a partial last block of columns).  The result is
+    laid out row-major, so its host copy reads as int64 words."""
+    y = jax.ShapeDtypeStruct((9900, 4, 4352), jnp.int32, sharding=one_chip)
+    compiled = plane_combine.lower(y, n_out, False).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "blmac_plane_combine" in text
+    assert compiled.output_formats.layout.major_to_minor == (0, 1, 2)
 
 
 def test_specialized_program_compiles(one_chip):
