@@ -23,10 +23,12 @@ chunk comes back with ``n_bank_shards == 1`` — the engine then degrades
 to the single-device scheduled path bit-for-bit.
 
 Output reassembly is gather-free: per-shard outputs land on their own
-devices, the host reads each shard's block, and writes it once into
-its caller rows (`BankPartition.assign`) of one new output — no
-cross-device collective touches the results, and no bank-sized
-intermediate is built on the host.
+devices, every shard's host copy starts at dispatch, and the host
+reads the shards in turn, handing each block to a worker thread that
+writes it once into its caller rows (`BankPartition.assign`) of one new
+output while the next shard is read — no cross-device collective
+touches the results, and no bank-sized intermediate is built on the
+host.
 
 **Fault tolerance** (see `repro.distributed.faultbank` for the shared
 taxonomy/injector/watchdog): every `push_async` captures a
@@ -53,6 +55,7 @@ Bit-exactness: every mesh shape agrees with
 from __future__ import annotations
 
 import time
+from concurrent.futures import ThreadPoolExecutor, wait
 
 import numpy as np
 import jax
@@ -81,8 +84,9 @@ class PendingChunk:
     the reassembly recipe (the partition's caller rows per shard) and
     the chunk's replay material (tail snapshot + raw samples).
     `result()` materializes on the host — each shard's block is read off
-    its own devices, then scattered once into its caller rows of one new
-    output (no device-side gather, no concatenated intermediate) — and is
+    its own devices and scattered once, on a worker thread, into its
+    caller rows of one new output while the next shard is read (no
+    device-side gather, no concatenated intermediate) — and is
     where faults are detected and recovered: a lost shard triggers the
     engine's re-partition + replay, a corrupted block is replayed in
     place, a transient error re-arms the chunk and propagates for the
@@ -285,6 +289,8 @@ class ShardedFilterBankEngine:
         self._inflight: list[PendingChunk] = []
         self._chunk_idx = 0
         self.stats = PushStats()
+        self._reads = {"prefetched_reads": 0, "overlapped_scatters": 0}
+        self._scatter_pool = None  # created at the first multi-shard read
         self._configure(mesh)
         # overlap-save state: the last taps-1 samples of every channel
         self._tail = np.zeros((channels, 0), np.int32)
@@ -600,6 +606,8 @@ class ShardedFilterBankEngine:
                     if self.injector is not None:
                         self.injector.on_dispatch(s, chunk_idx)
                     y = fn(buf, n)
+                    if isinstance(y, jax.Array):  # start the host copy now
+                        y.copy_to_host_async()
                 self.stats.outputs_computed += (
                     self._shard_rows[s] * self.channels
                     * self._shard_columns(s, n_pad)
@@ -715,6 +723,13 @@ class ShardedFilterBankEngine:
             d["bytes_read_back"] += plain.bytes_read_back
         return d
 
+    def read_stats(self) -> dict:
+        """JSON-able cumulative counters of the overlapped readback:
+        ``prefetched_reads``, shard reads whose host copy was started at
+        dispatch, and ``overlapped_scatters``, shard scatters handed to
+        a worker while a later shard of the chunk was still unread."""
+        return dict(self._reads)
+
     # -- tail snapshot / restore (content-addressed stream state) -----------
 
     def snapshot_tail(self):
@@ -758,19 +773,39 @@ class ShardedFilterBankEngine:
     def _materialize(self, p: PendingChunk) -> np.ndarray:
         """Assemble one pending chunk on the host; raises the first
         shard fault it detects (stored dispatch errors, watchdog
-        timeout, integrity-probe corruption).  Every shard is read and
-        checked before the output is written; each block is then copied
-        once, straight to its caller rows."""
-        parts = []
-        for s, (y, off) in enumerate(zip(p._shard_outs, p._offsets)):
-            if isinstance(y, ShardError):
-                raise y
-            parts.append(self._materialize_shard(s, p, y, off))
+        timeout, integrity-probe corruption).  Shards are read in turn
+        on this thread; each checked block is then copied once, straight
+        to its caller rows, on a worker while the next shard is read.  A
+        fault waits for the copies already handed out, so no output
+        escapes half written; a worker's own error is raised in its
+        place, with the fault as its context."""
+        out = np.empty(p._shape + (p.n_out,), np.int32)
+        last = len(p._shard_outs) - 1
+        if last > 0 and self._scatter_pool is None:
+            self._scatter_pool = ThreadPoolExecutor(
+                max_workers=len(self._shards),
+                thread_name_prefix="blmac-scatter")
+        scatters = []
+        try:
+            for s, (rows, y, off) in enumerate(
+                zip(p._assign, p._shard_outs, p._offsets)
+            ):
+                if isinstance(y, ShardError):
+                    raise y
+                part = self._materialize_shard(s, p, y, off)
+                if last == 0:
+                    _scatter(out, rows, part)
+                    continue
+                scatters.append(
+                    self._scatter_pool.submit(_scatter, out, rows, part))
+                if s < last:
+                    self._reads["overlapped_scatters"] += 1
+        except BaseException:
+            _finish(scatters)
+            raise
         with span("reassemble", chunk=p.chunk_idx):
-            out = np.empty(p._shape + (p.n_out,), np.int32)
-            for rows, part in zip(p._assign, parts):
-                out[rows] = part
-            return out
+            _finish(scatters)
+        return out
 
     def _materialize_shard(self, s, p, y, off):
         inj = self.injector
@@ -791,6 +826,7 @@ class ShardedFilterBankEngine:
             host = np.asarray(y)
             if isinstance(y, jax.Array):  # not the degraded engine's
                 self.stats.bytes_read_back += host.nbytes
+                self._reads["prefetched_reads"] += 1  # started at dispatch
             return host[:, :, off: off + n_out]
 
         t0 = time.perf_counter()
@@ -813,7 +849,6 @@ class ShardedFilterBankEngine:
         deadline; expiry escalates to `ShardTimeout` (→ loss).  The
         worker thread is abandoned, not joined — a wedged device read
         must not wedge the recovery path too."""
-        from concurrent.futures import ThreadPoolExecutor
         from concurrent.futures import TimeoutError as FuturesTimeout
 
         ex = ThreadPoolExecutor(max_workers=1)
@@ -1012,3 +1047,15 @@ class ShardedFilterBankEngine:
             f"imbalance={self.partition.imbalance:.2f} "
             f"predicted={self.plan.predicted_us:.0f}us"
         )
+
+
+def _scatter(out, rows, part) -> None:
+    """Write one shard's block to its caller rows of the output."""
+    out[rows] = part
+
+
+def _finish(futures) -> None:
+    """Wait for every scatter, then raise the first one's error."""
+    wait(futures)
+    for f in futures:
+        f.result()

@@ -231,9 +231,10 @@ print("FIVE_WAY_8DEV_OK", rep.sharded_mesh)
 # Each case pushes two chunks through a 4-device engine and reports the
 # second push's output: the reassembly under test wraps every
 # `_materialize` call, recording the shard blocks it assembles, the
-# partition rows it assembles them with, and the host memory it
-# allocates after the last shard is read (tracemalloc sees numpy's
-# buffers).
+# partition rows it assembles them with, and the host memory the call
+# allocates besides what the shard reads keep (tracemalloc sees numpy's
+# buffers).  The output is allocated before the first read, so that
+# each shard is scattered while the next one is read.
 _REASSEMBLY = """
 import json, tracemalloc
 import numpy as np
@@ -248,23 +249,36 @@ calls = []
 
 
 def recorded_shard(self, s, p, y, off):
+    before = tracemalloc.get_traced_memory()[0]
     part = read_shard(self, s, p, y, off)
     call = calls[-1]
     call["parts"].append(part)
-    if len(call["parts"]) == len(p._shard_outs):
-        call["base"] = tracemalloc.get_traced_memory()[0]
-        tracemalloc.reset_peak()
+    call["read"] += tracemalloc.get_traced_memory()[0] - before
     return part
 
 
 def recorded(self, p):
-    calls.append({"parts": [], "assign": p._assign})
+    calls.append({"parts": [], "assign": p._assign, "read": 0})
+    base = tracemalloc.get_traced_memory()[0]
+    tracemalloc.reset_peak()
     out = materialize(self, p)
-    calls[-1]["grew"] = tracemalloc.get_traced_memory()[1] - calls[-1]["base"]
+    call = calls[-1]
+    call["grew"] = tracemalloc.get_traced_memory()[1] - base - call["read"]
     return out
 
 
 E._materialize_shard, E._materialize = recorded_shard, recorded
+# count the device-to-host copies started (at dispatch, ahead of the reads)
+from jax._src.array import ArrayImpl
+start_copy, copies = ArrayImpl.copy_to_host_async, [0]
+
+
+def counted_copy(self):
+    copies[0] += 1
+    return start_copy(self)
+
+
+ArrayImpl.copy_to_host_async = counted_copy
 tracemalloc.start()
 q = spread_lowpass_qbank(40, 31)[np.random.default_rng(0).permutation(40)]
 # both pushes pad to one 2,048-sample shape: one compile per shard
@@ -284,7 +298,11 @@ res = {}
 for name, make in engines.items():
     eng = make()
     first = eng.push(x[:2048])
-    out = eng.push_async(x[2048:]).result()
+    reads, copies[0] = eng.read_stats(), 0
+    pending = eng.push_async(x[2048:])
+    started = copies[0]
+    out = pending.result()
+    reads = {k: v - reads[k] for k, v in eng.read_stats().items()}
     call = calls[-1]
     res[name] = {
         "exact": bool(np.array_equal(np.concatenate([first, out], axis=2),
@@ -302,6 +320,8 @@ for name, make in engines.items():
         "degraded": eng._plain is not None,
         "data_mode": eng.data_mode,
         "replayed": eng.fault_stats()["replayed_chunks"],
+        "reads": reads,
+        "copies": [started, copies[0]],
     }
 print(json.dumps(res))
 """
@@ -339,10 +359,191 @@ def test_sharded_push_output_is_a_fresh_exact_array(reassembled, case):
 
 @pytest.mark.parametrize("case", sorted(_REASSEMBLY_CASES))
 def test_sharded_reassembly_is_one_pass(reassembled, case):
-    """Once the shards are read, assembling the output allocates the
+    """Besides the shard reads, assembling the output allocates the
     output and nothing of its size besides: no concatenated or permuted
     bank-sized intermediate (a second pass would grow it to ~2×)."""
     assert 1.0 <= reassembled[case]["grew"] < 1.25, reassembled[case]
+
+
+# read_stats() over the second push, and the host copies started by its
+# dispatch and by the whole push: every device block's copy starts at
+# dispatch, and every shard but the last is scattered while a later one
+# is unread.  ``rearmed`` counts its aborted attempt (shards 0, 2 and 3
+# dispatched, shard 0 read and scattered before shard 1's loss) and the
+# 3-shard replay; the degraded engine's one block is numpy, read and
+# written inline.
+_READS_PER_PUSH = {
+    "bank": ({"prefetched_reads": 4, "overlapped_scatters": 3}, [4, 4]),
+    "time": ({"prefetched_reads": 2, "overlapped_scatters": 1}, [2, 2]),
+    "degraded": ({"prefetched_reads": 0, "overlapped_scatters": 0}, [0, 0]),
+    "rearmed": ({"prefetched_reads": 1 + 3, "overlapped_scatters": 1 + 2},
+                [3, 3 + 3]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_READS_PER_PUSH))
+def test_sharded_read_stats_per_push(reassembled, case):
+    reads, copies = _READS_PER_PUSH[case]
+    assert reassembled[case]["reads"] == reads
+    assert reassembled[case]["copies"] == copies
+
+
+# Faults while scatters are in flight, on a 4-device engine: the second
+# push's shard-0 scatter is held for 0.3 s (and in the ``worker_*``
+# cases then raises) while shard 2 faults.  Every scatter is logged
+# with the output array it wrote, so the test can tell the aborted
+# attempt's output from the one returned.
+_FAULTS = """
+import json, time
+import numpy as np
+from repro.distributed import bank_mesh
+from repro.distributed.faultbank import FaultInjector
+from repro.filters import (ShardedFilterBankEngine, fir_bit_layers_batch,
+                           spread_lowpass_qbank)
+from repro.filters import sharded
+
+E = ShardedFilterBankEngine
+q = spread_lowpass_qbank(40, 31)[np.random.default_rng(0).permutation(40)]
+x = np.random.default_rng(1).integers(-128, 128, 4066)
+ref = fir_bit_layers_batch(x, q)
+scatter, replay_one = sharded._scatter, E._replay_one
+held = {}      # rows -> what the held scatter does when it wakes
+outs, writes, replays = [], [], []
+
+
+def logged_scatter(out, rows, part):
+    t0 = time.monotonic()
+    action = held.pop(id(rows), None)
+    try:
+        if action is not None:
+            time.sleep(0.3)
+            if action == "raise":
+                raise RuntimeError("scatter failed")
+        scatter(out, rows, part)
+    finally:
+        if not any(o is out for o in outs):
+            outs.append(out)
+        k = next(i for i, o in enumerate(outs) if o is out)
+        writes.append({"out": k, "held": action is not None,
+                       "t0": t0, "t1": time.monotonic()})
+
+
+def logged_replay(self, p):
+    replays.append(time.monotonic())
+    return replay_one(self, p)
+
+
+sharded._scatter, E._replay_one = logged_scatter, logged_replay
+cases = {
+    "corrupt": (FaultInjector().corrupt_output(2, at_chunk=1), None),
+    "lost": (FaultInjector().kill_shard(2, at_chunk=1), None),
+    "worker_and_corrupt": (FaultInjector().corrupt_output(2, at_chunk=1),
+                           "raise"),
+    "worker_alone": (None, "raise"),
+}
+res = {}
+for name, (inj, action) in cases.items():
+    eng = E(q, mesh=bank_mesh(4, 1), n_bank_shards=4, integrity_check=True,
+            fault_injector=inj)
+    first = eng.push(x[:2048])
+    del outs[:], writes[:], replays[:]
+    held[id(eng.partition.assign[0])] = action or "wait"
+    got = {"raised": None, "context": None}
+    try:
+        out = eng.push(x[2048:])
+    except RuntimeError as e:
+        out = None
+        got.update(raised=type(e).__name__,
+                   context=type(e.__context__).__name__
+                   if e.__context__ is not None else None)
+    held_w = [w for w in writes if w["held"]]
+    got.update(
+        exact=out is not None and bool(
+            np.array_equal(np.concatenate([first, out], axis=2), ref)),
+        returned=next((i for i, o in enumerate(outs) if o is out), None),
+        writes=[sum(w["out"] == k for w in writes) for k in range(len(outs))],
+        held=len(held_w),
+        held_done_before_replay=bool(held_w) and bool(replays)
+        and held_w[0]["t1"] <= replays[0],
+        replays=len(replays),
+        replayed_chunks=eng.fault_stats()["replayed_chunks"],
+    )
+    res[name] = got
+print(json.dumps(res))
+"""
+
+
+@pytest.fixture(scope="module")
+def faulted():
+    out = run_py(_FAULTS, devices=4)
+    return json.loads(out.strip().splitlines()[-1])
+
+
+@pytest.mark.parametrize("case,shards_after", [("corrupt", 4), ("lost", 3)])
+def test_shard_fault_during_scatter_is_replayed_exactly(faulted, case,
+                                                        shards_after):
+    """Shard 2 faults while shard 0's scatter is still running: the
+    materialize waits for the scatters it handed out (shards 0 and 1)
+    before the fault reaches the replay, and the caller gets the
+    replay's output, exact, never the aborted attempt's."""
+    got = faulted[case]
+    assert got["raised"] is None
+    assert got["exact"]
+    assert got["held"] == 1 and got["held_done_before_replay"]
+    assert got["replayed_chunks"] == 1
+    # the aborted output took shards 0 and 1; the returned one, every
+    # shard of the partition the chunk was replayed on
+    assert got["writes"] == [2, shards_after]
+    assert got["returned"] == 1
+
+
+@pytest.mark.parametrize("case,context", [
+    ("worker_alone", None), ("worker_and_corrupt", "ShardCorruption")])
+def test_scatter_worker_error_is_raised(faulted, case, context):
+    """A scatter that raises on its worker reaches the caller, also when
+    a shard fault is raised meanwhile (the fault is its context), and
+    no output is returned."""
+    got = faulted[case]
+    assert got["raised"] == "RuntimeError"
+    assert got["context"] == context
+    assert got["returned"] is None
+    assert got["replays"] == 0
+
+
+def test_dropped_engines_leave_no_scatter_threads():
+    """Each engine's scatter pool ends with the engine: building,
+    pushing through and dropping 50 four-shard engines leaves the
+    process's thread count where it started."""
+    out = run_py("""
+import gc, threading, time
+import numpy as np
+from repro.distributed import bank_mesh
+from repro.filters import ShardedFilterBankEngine as E, spread_lowpass_qbank
+
+# one jitted program per shard schedule, shared by every engine, so the
+# loop compiles once
+make, shared = E._make_scheduled_fn, {}
+E._make_scheduled_fn = lambda self, sched, tile, lane=None: shared.setdefault(
+    (id(sched), tile, lane), make(self, sched, tile, lane))
+q = spread_lowpass_qbank(16, 15)
+x = np.random.default_rng(0).integers(-128, 128, 300)
+before, most, overlapped = threading.active_count(), 0, 0
+for _ in range(50):
+    eng = E(q, mesh=bank_mesh(4, 1), n_bank_shards=4, tile=128)
+    eng.push(x)
+    most = max(most, threading.active_count())
+    overlapped += eng.read_stats()["overlapped_scatters"]
+    del eng
+gc.collect()
+deadline = time.monotonic() + 10
+while threading.active_count() > before and time.monotonic() < deadline:
+    time.sleep(0.05)
+print(before, most, threading.active_count(), overlapped)
+""", devices=4)
+    before, most, after, overlapped = map(int, out.split()[-4:])
+    assert overlapped == 50 * 3  # every engine scattered on its pool
+    assert most > before
+    assert after <= before
 
 
 def test_async_double_buffered_server():
