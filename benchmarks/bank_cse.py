@@ -1,39 +1,19 @@
-"""Cross-filter CSE pass: adds-per-filter reduction AND B=256 throughput.
+"""Cross-filter CSE pass: the adds-per-filter reduction on the sweep grid.
 
-Two arms, one committed artifact (``BENCH_cse.json``):
+One arm, one committed artifact (``BENCH_cse.json``): the paper's
+§3.3/Table-4 accounting metric on the full 9,900-filter 127-tap Hamming
+sweep grid (`table4_machine`'s bank).  The grid is compiled once,
+`repro.compiler.cse_pass`-optimized, and the §3.3 adds-per-filter and
+§4 machine-cycle predictions of parent vs optimized program are
+compared.  Pure accounting — no timing — so the reduction is exact,
+deterministic and machine-independent (the optimized cycle column
+amortizes each shared virtual row once per bank and charges one cycle
+per combine use; it is priced at the widened ``coeff_bits = n_layers +
+1`` spec the augmented rows need).
 
-* **sweep** — the paper's §3.3/Table-4 accounting metric on the full
-  9,900-filter 127-tap Hamming sweep grid (`table4_machine`'s bank):
-  the grid is compiled once, `repro.compiler.cse_pass`-optimized, and
-  the §3.3 adds-per-filter and §4 machine-cycle predictions of parent
-  vs optimized program are compared.  Pure accounting — no timing — so
-  the reduction is exact, deterministic and machine-independent (the
-  optimized cycle column amortizes each shared virtual row once per
-  bank and charges one cycle per combine use; it is priced at the
-  widened ``coeff_bits = n_layers + 1`` spec the augmented rows need).
-
-* **throughput** — the compiled-lane no-regression gate on the B=256
-  reference bank (63 taps, spread lowpass cutoffs, the
-  `bank_compiled` geometry).  Three interleaved arms, every one
-  verified bit-exact against `fir_bit_layers_batch` before timing:
-
-    - ``baseline``    — the parent's autotuned compiled dispatch,
-    - ``cse-auto``    — the autotuned dispatch for the OPTIMIZED
-      program: `autotune_bank_dispatch` prices the combine stage
-      (`predict_combine_us`) against the parent's own plan and may
-      *decline* the shared-row layout (``plan.cse == "declined"``) —
-      the honest mechanism behind the no-regression guarantee, since
-      a dense superlayer GEMM's cost scales with ROWS and the
-      augmented bank has more of them,
-    - ``cse-forced``  — the shared-row layout forced onto the compiled
-      lane (informational: what declining saved).
-
-The CI gate (``--check``) enforces the acceptance floors: mean
+The CI gate (``--check``) enforces the acceptance floor: mean
 adds-per-filter reduction ``>= --floor-adds`` (default 10%) on the
-sweep grid, same-run ``baseline/cse-auto`` throughput ratio
-``>= --floor-throughput`` (default 0.90 — no regression beyond runner
-noise; when the autotuner declines, both arms run the identical parent
-plan), plus a tolerance band against the committed reduction.
+sweep grid, plus a tolerance band against the committed reduction.
 
 Usage:
   python benchmarks/bank_cse.py                  # full run, writes JSON
@@ -53,8 +33,6 @@ import numpy as np
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-BANK_SIZE = 256
-TAPS = 63
 SWEEP_TAPS = 127
 OUT_PATH = os.path.join(os.path.dirname(__file__), "..", "BENCH_cse.json")
 BREAKDOWN_PATH = os.path.join(
@@ -134,139 +112,13 @@ def derive_sweep(adds_parent, adds_opt, n_filters, pulses_parent,
     }
 
 
-def _interleaved_times(arms: dict, repeats: int) -> dict:
-    """Fastest wall time per arm, arms interleaved with rotating start."""
-    for fn in arms.values():
-        fn()  # warm-up: compile + stage operands
-    names = list(arms)
-    best = {name: float("inf") for name in names}
-    for r in range(repeats):
-        for name in names[r % len(names):] + names[: r % len(names)]:
-            t0 = time.perf_counter()
-            arms[name]()
-            best[name] = min(best[name], time.perf_counter() - t0)
-    return best
-
-
-def run_throughput(n_samples: int = 16384, repeats: int = 3,
-                   verbose: bool = True, n_filters: int = BANK_SIZE,
-                   taps: int = TAPS) -> dict:
-    import jax.numpy as jnp
-
-    from repro.compiler import compile_bank, cse_pass
-    from repro.filters import fir_bit_layers_batch, spread_lowpass_qbank
-    from repro.kernels.blmac_fir import blmac_fir_bank
-    from repro.kernels.runtime import autotune_bank_dispatch, resolve_lane
-
-    qbank = spread_lowpass_qbank(n_filters, taps)
-    parent = compile_bank(qbank)
-    opt = cse_pass(parent)
-    rng = np.random.default_rng(42)
-    x = rng.integers(-128, 128, n_samples).astype(np.int32)
-    xj = jnp.asarray(x)
-    n_out = n_samples - taps + 1
-    ref = fir_bit_layers_batch(x, qbank)[:, 0, :]
-
-    lane = resolve_lane(True)  # this host's compiled lane
-    plan_b, sched_b = autotune_bank_dispatch(
-        parent, chunk_hint=n_samples, compiled=lane
-    )
-    plan_a, sched_a = autotune_bank_dispatch(
-        opt, chunk_hint=n_samples, compiled=lane
-    )
-
-    def make_arm(prog, schedule, plan, combine, n_real):
-        def f():
-            blmac_fir_bank(
-                xj, prog.packed, taps, tile=plan.tile, schedule=schedule,
-                fast_path=False, lane=plan.lane, combine=combine,
-                n_real=n_real,
-            ).block_until_ready()
-        return f
-
-    def verify(prog, schedule, plan, combine, n_real, name):
-        y = np.asarray(blmac_fir_bank(
-            xj, prog.packed, taps, tile=plan.tile, schedule=schedule,
-            fast_path=False, lane=plan.lane, combine=combine, n_real=n_real,
-        ))[..., :n_out]
-        if not np.array_equal(y, ref):
-            raise AssertionError(f"arm {name} is not bit-exact")
-
-    arms, rows = {}, []
-
-    def add_arm(name, prog, schedule, plan, combine=None, n_real=None,
-                **extra):
-        verify(prog, schedule, plan, combine, n_real, name)
-        arms[name] = make_arm(prog, schedule, plan, combine, n_real)
-        rows.append({
-            "arm": name, "lane": plan.lane, "merge": plan.merge,
-            "bank_tile": plan.bank_tile, "tile": plan.tile,
-            "rows_executed": prog.n_filters, **extra,
-        })
-
-    add_arm("baseline", parent, sched_b, plan_b)
-    if opt is parent or plan_a.cse == "declined":
-        # the autotuner rejected the shared-row layout: the auto arm
-        # executes the PARENT plan (what an engine would actually do)
-        add_arm("cse-auto", parent, sched_a, plan_a, cse=plan_a.cse)
-    else:
-        add_arm("cse-auto", opt, sched_a, plan_a, combine=opt.combine,
-                n_real=opt.n_real, cse=plan_a.cse)
-    if opt is not parent:
-        # the shared-row layout forced onto the compiled lane at the
-        # baseline's autotuned merge (informational)
-        sched_f = opt.schedule(None, plan_b.merge)
-        add_arm("cse-forced", opt, sched_f, plan_b,
-                combine=opt.combine, n_real=opt.n_real, cse="forced")
-
-    times = _interleaved_times(arms, repeats)
-    t_base = times["baseline"]
-    for row in rows:
-        t = times[row["arm"]]
-        row["seconds"] = t
-        row["samples_per_s_per_filter"] = n_out / t
-        row["ratio_vs_baseline"] = t_base / t
-        if verbose:
-            print(f"{row['arm']:12s} {t * 1e3:9.2f} ms  "
-                  f"{row['samples_per_s_per_filter']:12.0f} "
-                  f"samples/s/filter  "
-                  f"({row['ratio_vs_baseline']:.2f}x baseline)"
-                  + (f"  [{row['cse']}]" if "cse" in row else ""))
-
-    out = {
-        "bank_size": n_filters,
-        "taps": taps,
-        "n_samples": n_samples,
-        "lane": lane,
-        "auto_cse": plan_a.cse if opt is not parent else "",
-        "n_shared": 0 if opt is parent else opt.n_shared,
-        "rows": rows,
-        **derive_throughput(rows),
-    }
-    return out
-
-
-def derive_throughput(rows) -> dict:
-    """Derived ratio columns from the per-arm seconds (shared with
-    `benchmarks.reanalyze.reanalyze_cse`)."""
-    t = {row["arm"]: row["seconds"] for row in rows}
-    out = {"throughput_ratio": t["baseline"] / t["cse-auto"]}
-    if "cse-forced" in t:
-        out["forced_ratio"] = t["baseline"] / t["cse-forced"]
-    return out
-
-
-def run(n_div: int = 100, n_samples: int = 16384, repeats: int = 3,
-        verbose: bool = True) -> dict:
+def run(n_div: int = 100, verbose: bool = True) -> dict:
     import jax
 
-    sweep = run_sweep(n_div, verbose=verbose)
-    throughput = run_throughput(n_samples, repeats, verbose=verbose)
     return {
         "benchmark": "bank_cse",
         "backend": jax.default_backend(),
-        "sweep": sweep,
-        "throughput": throughput,
+        "sweep": run_sweep(n_div, verbose=verbose),
     }
 
 
@@ -278,10 +130,10 @@ def write_breakdown(result: dict, path: str = BREAKDOWN_PATH) -> None:
 
 
 def check(result: dict, committed_path: str, tolerance: float,
-          floor_adds: float, floor_throughput: float) -> int:
+          floor_adds: float) -> int:
     """Fail (non-zero) unless the adds-per-filter reduction clears the
     acceptance floor and stays within ``tolerance`` of the committed
-    value, AND the same-run autotuned throughput does not regress."""
+    value."""
     with open(committed_path) as f:
         committed = json.load(f)
     status = 0
@@ -296,14 +148,6 @@ def check(result: dict, committed_path: str, tolerance: float,
     flag = "OK" if red >= old - tolerance else "REGRESSION"
     print(f"check adds reduction vs committed: {100 * red:.1f}% vs "
           f"{100 * old:.1f}% (tolerance {100 * tolerance:.1f}pt)  {flag}")
-    if flag != "OK":
-        status = 1
-
-    ratio = result["throughput"]["throughput_ratio"]
-    flag = "OK" if ratio >= floor_throughput else "REGRESSION"
-    print(f"check B={result['throughput']['bank_size']} throughput: "
-          f"cse-auto at {ratio:.2f}x baseline >= {floor_throughput:.2f}x "
-          f"required  {flag}")
     if flag != "OK":
         status = 1
     return status
@@ -321,30 +165,14 @@ def main() -> int:
     ap.add_argument("--floor-adds", type=float, default=0.10,
                     help="minimum mean adds-per-filter reduction on the "
                          "sweep grid (the PR acceptance bar)")
-    ap.add_argument("--floor-throughput", type=float, default=0.90,
-                    help="minimum same-run cse-auto/baseline throughput "
-                         "ratio at B=256.  When the autotuner declines "
-                         "(the common verdict on dense GEMM lanes) both "
-                         "arms run the IDENTICAL parent plan, so the true "
-                         "ratio is 1.0 and the band only absorbs runner "
-                         "noise; a real regression — the autotuner "
-                         "wrongly forcing the shared-row layout, or the "
-                         "combine epilogue slowing the winning plan — "
-                         "lands far below it")
     ap.add_argument("--out", default=OUT_PATH)
     args = ap.parse_args()
     if args.check and not os.path.exists(args.check):
         ap.error(f"baseline not found: {args.check}")
-    n_div = 20 if args.fast else 100
-    n_samples = 8192 if args.fast else 16384
-    # arms are ms-scale: generous repeats cost little and keep the
-    # near-1.0 declined-arm ratio out of the noise floor
-    repeats = 6 if args.fast else 8
-    result = run(n_div=n_div, n_samples=n_samples, repeats=repeats)
+    result = run(n_div=20 if args.fast else 100)
     write_breakdown(result)
     if args.check:
-        return check(result, args.check, args.tolerance,
-                     args.floor_adds, args.floor_throughput)
+        return check(result, args.check, args.tolerance, args.floor_adds)
     if not args.fast:
         with open(args.out, "w") as f:
             json.dump(result, f, indent=2)

@@ -22,7 +22,8 @@ assertion failure, not a slow row.
 
 The committed ``BENCH_fault.json`` is the smoke baseline CI gates
 against.  Wall-clock recovery latency is host-speed dependent (it
-re-runs the mesh autotuner), so the gate is deliberately loose: every
+re-plans the mesh and recompiles shard kernels), so the gate is
+deliberately loose: every
 row must (a) recover bit-exactly with the expected counters, (b) keep
 ``recovery_s`` under the absolute smoke ceiling, and (c) stay within
 ``--tolerance`` (a multiple, default 4x) of the committed latency.
@@ -148,11 +149,11 @@ def run(grid=GRID, n_chunks: int = 8, chunk: int = 4096,
         "recovery_ceiling_s": RECOVERY_CEILING_S,
         "rows": rows,
         "note": (
-            "recovery_s is detection -> recovered mesh (re-partition via the "
-            "cost model, rebuilt dispatch closures, bit-exact replay of every "
+            "recovery_s is detection -> recovered mesh (re-partition over the "
+            "survivors, rebuilt dispatch closures, bit-exact replay of every "
             "in-flight chunk from its tail snapshot); every row is verified "
             "bit-exact against the numpy oracle before it is reported; "
-            "latency re-runs the mesh autotuner so the CI gate is a loose "
+            "latency re-plans the mesh so the CI gate is a loose "
             "smoke bound (absolute ceiling + a generous multiple of the "
             "committed row), not a tight regression ratio"
         ),

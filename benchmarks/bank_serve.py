@@ -95,7 +95,7 @@ def _run_row(n_sessions: int, n_slots: int, n_chunks: int,
         for i, s in enumerate(sessions):
             shared_out[i].append(s.pull())
 
-    shared_round(0)  # warm the jit/autotune caches off the clock
+    shared_round(0)  # warm the jit caches off the clock
     warm_samples = srv.samples_out
     t0 = time.perf_counter()
     for k in range(1, n_chunks + 1):

@@ -176,7 +176,7 @@ def check(result: dict, committed_path: str, tolerance: float) -> int:
     """Gate: the max-device arm must (a) clear the absolute >= 3x scaling
     floor and (b) not regress > tolerance vs the committed same-run
     scaling ratio.  Ratios are measured within one run, so the gate
-    transfers across runner hardware like BENCH_fir.json's speedup gate."""
+    transfers across runner hardware."""
     with open(committed_path) as f:
         committed = json.load(f)
     rows = {r["devices"]: r for r in result["rows"]}
@@ -222,7 +222,7 @@ def main() -> int:
     n_samples = 4096 if args.fast else 8192
     if args.check:
         # scaling ratios are only comparable at the committed signal
-        # length (the autotuner picks different tiles per chunk size)
+        # length
         with open(args.check) as f:
             n_samples = json.load(f)["n_samples"]
     repeats = 5 if args.fast else 7

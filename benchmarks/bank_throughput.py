@@ -1,12 +1,11 @@
-"""Filter-bank throughput: autotuned BLMAC dispatch vs per-filter loop.
+"""Filter-bank throughput: the engines' BLMAC bank plan vs per-filter loop.
 
 For each bank size B the benchmark designs B lowpass filters with spread
 cutoffs, quantizes them to 16 bits, and measures samples/s/filter for
 
-  * ``batched``    — the autotuned dispatch path: `autotune_bank_dispatch`
-    picks (mode, tile, bank_tile, merge) per bank — the pulse-specialized
-    loop for narrow banks, occupancy-grouped scheduled bank tiles for
-    wide ones.  The winning configuration is recorded in the row.
+  * ``batched``    — the plan every engine runs (`repro.compiler.bank_plan`:
+    tile 256, bank tile `default_bank_tile(B)`, merge 8) on the
+    scheduled bank kernel.  The plan is recorded in the row.
   * ``dense``      — the scheduled kernel forced to merge=1 and one
     default bank tile: one matmul per bit layer, i.e. the PR-1 kernel —
     kept so the schedule's contribution is measurable on its own.
@@ -16,24 +15,15 @@ cutoffs, quantizes them to 16 bits, and measures samples/s/filter for
     dispatched/framed B times).
 
 All arms are cross-checked bit-exactly against
-`repro.filters.fir_bit_layers_batch` before timing.  Results land in
-``BENCH_fir.json`` at the repo root — the committed copy is the perf
-baseline CI regresses against — and the per-mode breakdown in
-``benchmarks/out/bank_throughput_breakdown.json`` (uploaded as a CI
-artifact).
-
-Methodology note (committed-floor rule): the committed rows are the
-CONSERVATIVE FLOOR — lowest speedup over repeated serial runs on the
-reference machine — so the CI gate tolerance absorbs timing noise, not a
-lucky fast run.  Since the sparsity-scheduled kernel the floor also
-covers the B=1 row: the small-bank fast path must keep ``speedup >= 1.0``
-there (the PR-1 kernel committed 0.70× — a framing-overhead regression
-this file now gates against).
+`repro.filters.fir_bit_layers_batch` before timing.  A full run writes
+``BENCH_fir.json`` at the repo root, and every run writes the per-arm
+breakdown to ``benchmarks/out/bank_throughput_breakdown.json``.  The
+numbers are wall times of the backend the run is on (on a CPU, the
+Pallas interpreter): a record, not a gate.
 
 Usage:
   python benchmarks/bank_throughput.py                 # full: B ∈ {1,16,256}
-  python benchmarks/bank_throughput.py --quick         # CI: short signal
-  python benchmarks/bank_throughput.py --check BENCH_fir.json --tolerance 0.2
+  python benchmarks/bank_throughput.py --quick         # short signal, no JSON
 """
 from __future__ import annotations
 
@@ -49,7 +39,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 BANK_SIZES = (1, 16, 256)
 TAPS = 63
-TILE = 512  # per-filter baseline tile; the batched arm's tile is autotuned
+TILE = 512  # tile of the dense and per-filter arms
 OUT_PATH = os.path.join(os.path.dirname(__file__), "..", "BENCH_fir.json")
 BREAKDOWN_PATH = os.path.join(
     os.path.dirname(__file__), "out", "bank_throughput_breakdown.json"
@@ -83,11 +73,9 @@ def bench_bank(
 ) -> dict:
     import jax.numpy as jnp
 
-    from repro.compiler import compile_bank
+    from repro.compiler import bank_plan, compile_bank
     from repro.filters import fir_bit_layers_batch
-    from repro.kernels.blmac_fir import (blmac_fir_bank, plan_bank_schedule,
-                                         blmac_fir_specialized)
-    from repro.kernels.runtime import autotune_bank_dispatch
+    from repro.kernels.blmac_fir import blmac_fir_bank, plan_bank_schedule
 
     qbank = _design_qbank(n_filters, taps)
     rng = np.random.default_rng(42)
@@ -97,13 +85,10 @@ def bench_bank(
 
     # every arm reads the ONE compiled program — trit encoding, packing
     # AND schedule planning are compile-time work hoisted out of the
-    # timed region, like reloading the FPGA weight memory; the autotuner
-    # shares the program's schedule memo instead of re-planning
+    # timed region, like reloading the FPGA weight memory
     program = compile_bank(qbank)
     packed = program.packed
-    plan, schedule = autotune_bank_dispatch(
-        program, channels=1, chunk_hint=n_samples
-    )
+    plan, schedule = bank_plan(program)
     dense_schedule = program.schedule(bank_tile=None, merge=1)
     singles = [
         (packed[b : b + 1], plan_bank_schedule(packed[b : b + 1], 1, merge=1))
@@ -112,29 +97,14 @@ def bench_bank(
 
     ref = fir_bit_layers_batch(x, qbank)[:, 0, :]
 
-    if plan.mode == "specialized":
-        pulses = program.pulse_schedules()
+    def run_batched():
+        blmac_fir_bank(
+            xj, packed, taps, tile=plan.tile, schedule=schedule
+        ).block_until_ready()
 
-        def run_batched():
-            ys = [
-                blmac_fir_specialized(xj, p, taps, plan.tile) for p in pulses
-            ]
-            ys[-1].block_until_ready()
-
-        y_tuned = np.stack(
-            [np.asarray(blmac_fir_specialized(xj, p, taps, plan.tile))[:n_out]
-             for p in pulses]
-        )
-    else:
-
-        def run_batched():
-            blmac_fir_bank(
-                xj, packed, taps, tile=plan.tile, schedule=schedule
-            ).block_until_ready()
-
-        y_tuned = np.asarray(
-            blmac_fir_bank(xj, packed, taps, tile=plan.tile, schedule=schedule)
-        )
+    y_plan = np.asarray(
+        blmac_fir_bank(xj, packed, taps, tile=plan.tile, schedule=schedule)
+    )
 
     def run_dense():
         blmac_fir_bank(
@@ -142,8 +112,8 @@ def bench_bank(
         ).block_until_ready()
 
     # bit-exact check of every arm before any timing
-    if not np.array_equal(y_tuned, ref):
-        raise AssertionError(f"autotuned arm mismatch at B={n_filters}")
+    if not np.array_equal(y_plan, ref):
+        raise AssertionError(f"batched arm mismatch at B={n_filters}")
     y_dense = np.asarray(
         blmac_fir_bank(xj, packed, taps, tile=tile, schedule=dense_schedule)
     )
@@ -167,8 +137,6 @@ def bench_bank(
         "dense_samples_per_s_per_filter": n_out / t_dense,
         "speedup_vs_dense": t_dense / t_batched,
     }
-    if n_filters == 1:
-        baseline = True  # the B=1 floor gate always needs the speedup ratio
     if baseline:
 
         def run_per_filter():
@@ -246,83 +214,16 @@ def write_breakdown(result: dict, path: str = BREAKDOWN_PATH) -> None:
         f.write("\n")
 
 
-def check(result: dict, committed_path: str, tolerance: float,
-          min_bank: int = 16, gate: str = "throughput") -> int:
-    """Fail (non-zero) if the gated metric regressed > tolerance versus
-    the committed baseline.
-
-    ``gate="throughput"`` compares absolute batched samples/s/filter —
-    only meaningful on hardware comparable to where the baseline was
-    recorded.  ``gate="speedup"`` compares the batched-vs-per-filter
-    ratio measured within the same run, which transfers across machines
-    (this is what CI uses).  Banks below ``min_bank`` are exempt from
-    the *ratio* gate (their wall time is a few ms of dispatch overhead,
-    too noisy for a relative threshold) — EXCEPT the B=1 row, which is
-    gated on the absolute floor ``speedup >= 1.0``: the small-bank fast
-    path must never be slower than the per-filter loop it replaces."""
-    key = ("batched_samples_per_s_per_filter" if gate == "throughput"
-           else "speedup")
-    with open(committed_path) as f:
-        committed = json.load(f)
-    base = {r["bank_size"]: r for r in committed["rows"]}
-    status = 0
-    for row in result["rows"]:
-        b = row["bank_size"]
-        if b == 1 and "speedup" in row:
-            flag = "OK" if row["speedup"] >= 1.0 else "REGRESSION"
-            print(f"check B={b:4d} fast-path floor: speedup "
-                  f"{row['speedup']:.2f}x >= 1.00x required  {flag}")
-            if flag != "OK":
-                status = 1
-        if b not in base:
-            continue
-        if b < min_bank and b != 1:
-            print(f"check B={b:4d}: skipped (below --min-bank={min_bank})")
-            continue
-        if b < min_bank:
-            continue  # B=1 already gated on the absolute floor above
-        old = base[b][key]
-        new = row[key]
-        ratio = new / old
-        flag = "OK" if ratio >= 1.0 - tolerance else "REGRESSION"
-        print(f"check B={b:4d} {gate}: {new:.0f} vs committed {old:.0f} "
-              f"({ratio:.2f}x) {flag}")
-        if flag != "OK":
-            status = 1
-    return status
-
-
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--quick", action="store_true",
-                    help="short signal for CI (no JSON rewrite)")
-    ap.add_argument("--check", metavar="JSON",
-                    help="compare against a committed BENCH_fir.json")
-    ap.add_argument("--tolerance", type=float, default=0.2)
-    ap.add_argument("--min-bank", type=int, default=16,
-                    help="smallest bank size the relative regression gate "
-                         "applies to (B=1 is always gated on speedup>=1)")
-    ap.add_argument("--gate", choices=("throughput", "speedup"),
-                    default="throughput",
-                    help="metric to gate on: absolute samples/s/filter "
-                         "(same-machine runs) or the machine-normalized "
-                         "batched-vs-per-filter speedup (CI)")
+                    help="short signal, one repeat (no JSON rewrite)")
     ap.add_argument("--out", default=OUT_PATH)
     args = ap.parse_args()
-    if args.check and not os.path.exists(args.check):
-        ap.error(f"baseline not found: {args.check}")  # before minutes of timing
     n_samples = 2048 if args.quick else 8192
     repeats = 1 if args.quick else 3
-    # --check must measure the same signal length as the committed
-    # baseline to be comparable; the throughput gate doesn't need the
-    # per-filter arm, the speedup gate (and the B=1 floor) does
-    result = run(n_samples=8192 if args.check else n_samples,
-                 repeats=repeats,
-                 baseline=not args.check or args.gate == "speedup")
+    result = run(n_samples=n_samples, repeats=repeats)
     write_breakdown(result)
-    if args.check:
-        return check(result, args.check, args.tolerance, args.min_bank,
-                     args.gate)
     if not args.quick:
         with open(args.out, "w") as f:
             json.dump(result, f, indent=2)

@@ -5,16 +5,10 @@ Two sections:
 * **dryrun** — re-derive the §Roofline JSONs from the saved (gzipped)
   HLO — lets analyzer improvements update the table without recompiling
   68 cells.
-* **compiled** — re-derive `BENCH_compiled.json`'s roofline columns
-  (`roofline_us`, `roofline_utilization`, `compiled_speedup`,
-  `best_arm`) from the stored raw values — HLO FLOP/byte counts and
-  measured peaks — so a formula change does not require re-timing the
-  arms on the reference box.
-* **cse** — re-derive `BENCH_cse.json`'s reduction and ratio columns
+* **cse** — re-derive `BENCH_cse.json`'s reduction columns
   (`adds_per_filter_*`, `adds_reduction`, `pulse_reduction`,
-  `cycle_reduction`, `throughput_ratio`, `forced_ratio`) from the
-  stored raw totals and per-arm seconds (same formulas as
-  `bank_cse.derive_sweep` / `derive_throughput`).
+  `cycle_reduction`) from the stored raw totals (same formulas as
+  `bank_cse.derive_sweep`).
 """
 import glob
 import gzip
@@ -27,9 +21,6 @@ from repro.roofline.hlo_analysis import analyze_hlo  # noqa: E402
 
 PEAK, HBM_BW, ICI_BW = 197e12, 819e9, 50e9
 OUT = os.path.join(os.path.dirname(__file__), "out", "dryrun")
-BENCH_COMPILED = os.path.join(
-    os.path.dirname(__file__), "..", "BENCH_compiled.json"
-)
 BENCH_CSE = os.path.join(os.path.dirname(__file__), "..", "BENCH_cse.json")
 
 
@@ -62,48 +53,12 @@ def reanalyze_dryrun() -> None:
               f"x={r['collective_term_s']:.3f}")
 
 
-def reanalyze_compiled(path: str = BENCH_COMPILED) -> None:
-    """Recompute BENCH_compiled.json's derived roofline columns from its
-    stored raw measurements (same formula as bank_compiled.run)."""
-    if not os.path.exists(path):
-        return
-    r = json.load(open(path))
-    t_interp = next(
-        row["seconds"] for row in r["rows"] if row["lane"] == "interpret"
-    )
-    for row in r["rows"]:
-        row["speedup_vs_interpret"] = t_interp / row["seconds"]
-        if row.get("hlo_flops") is None:
-            row["roofline_us"] = None
-            row["roofline_utilization"] = None
-            continue
-        f32 = row.get("hlo_f32_flops", 0.0)
-        int_flops = max(row["hlo_flops"] - f32, 0.0)
-        compute_s = (f32 / r["peak_f32_flops"]
-                     + int_flops / r["peak_int32_flops"])
-        row["roofline_us"] = max(
-            compute_s, row["hlo_hbm_bytes"] / r["peak_hbm_bytes_per_s"]
-        ) * 1e6
-        row["roofline_utilization"] = (
-            row["roofline_us"] / (row["seconds"] * 1e6)
-        )
-    best = max((row for row in r["rows"] if row["lane"] != "interpret"),
-               key=lambda row: row["speedup_vs_interpret"])
-    r["compiled_speedup"] = best["speedup_vs_interpret"]
-    r["best_arm"] = best["arm"]
-    with open(path, "w") as f:
-        json.dump(r, f, indent=2)
-        f.write("\n")
-    print(os.path.basename(path), "->",
-          f"best={r['best_arm']} {r['compiled_speedup']:.2f}x")
-
-
 def reanalyze_cse(path: str = BENCH_CSE) -> None:
-    """Recompute BENCH_cse.json's derived reduction/ratio columns from
-    its stored raw totals (same formulas as bank_cse.run)."""
+    """Recompute BENCH_cse.json's derived reduction columns from its
+    stored raw totals (same formulas as bank_cse.run)."""
     if not os.path.exists(path):
         return
-    from benchmarks.bank_cse import derive_sweep, derive_throughput
+    from benchmarks.bank_cse import derive_sweep
 
     r = json.load(open(path))
     s = r["sweep"]
@@ -112,24 +67,13 @@ def reanalyze_cse(path: str = BENCH_CSE) -> None:
         s["total_pulses_parent"], s["total_pulses_optimized"],
         s["mean_cycles_parent"], s["mean_cycles_optimized"],
     ))
-    tp = r["throughput"]
-    for row in tp["rows"]:
-        row["samples_per_s_per_filter"] = (
-            (tp["n_samples"] - tp["taps"] + 1) / row["seconds"]
-        )
-        row["ratio_vs_baseline"] = (
-            tp["rows"][0]["seconds"] / row["seconds"]
-        )
-    tp.update(derive_throughput(tp["rows"]))
     with open(path, "w") as f:
         json.dump(r, f, indent=2)
         f.write("\n")
     print(os.path.basename(path), "->",
-          f"adds_reduction={100 * s['adds_reduction']:.1f}% "
-          f"throughput_ratio={tp['throughput_ratio']:.2f}x")
+          f"adds_reduction={100 * s['adds_reduction']:.1f}%")
 
 
 if __name__ == "__main__":
     reanalyze_dryrun()
-    reanalyze_compiled()
     reanalyze_cse()

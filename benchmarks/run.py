@@ -102,37 +102,17 @@ def bench_kernel_bank() -> None:
          f"vs_per_filter={r['speedup']:.2f}x")
 
 
-def bench_bank_compiled() -> None:
-    """Compiled-lane bank kernel vs interpret, with roofline utilization
-    (full grid + BENCH_compiled.json: benchmarks/bank_compiled.py)."""
-    from benchmarks import bank_compiled
-
-    result = bank_compiled.run(n_samples=4096, repeats=2, verbose=False)
-    best = next(r for r in result["rows"] if r["arm"] == result["best_arm"])
-    util = best["roofline_utilization"]
-    derived = (f"lane={result['lane']};best={result['best_arm']};"
-               f"vs_interpret={result['compiled_speedup']:.2f}x")
-    if util is not None:
-        derived += f";roofline_util={util:.2f}"
-    _row("bank_compiled", best["seconds"] * 1e6, derived)
-
-
 def bench_bank_cse() -> None:
     """Cross-filter CSE pass: adds-per-filter reduction on the sweep
-    grid + autotuned B=256 throughput vs the unoptimized baseline
-    (full grid + BENCH_cse.json: benchmarks/bank_cse.py)."""
+    grid, timed by the pass's mining (full grid + BENCH_cse.json:
+    benchmarks/bank_cse.py)."""
     from benchmarks import bank_cse
 
-    result = bank_cse.run(n_div=10, n_samples=4096, repeats=2,
-                          verbose=False)
-    sweep, tp = result["sweep"], result["throughput"]
-    auto = next(r for r in tp["rows"] if r["arm"] == "cse-auto")
+    sweep = bank_cse.run(n_div=10, verbose=False)["sweep"]
     derived = (f"adds_reduction={100 * sweep['adds_reduction']:.1f}%;"
                f"cycle_reduction={100 * sweep['cycle_reduction']:.1f}%;"
-               f"n_shared={sweep['n_shared']};"
-               f"auto={tp['auto_cse'] or 'n/a'};"
-               f"throughput_ratio={tp['throughput_ratio']:.2f}x")
-    _row("bank_cse", auto["seconds"] * 1e6, derived)
+               f"n_shared={sweep['n_shared']}")
+    _row("bank_cse", sweep["mine_seconds"] * 1e6, derived)
 
 
 def bench_kernel_pulse_matmul() -> None:
@@ -191,7 +171,6 @@ def main() -> None:
     bench_table4_machine()
     bench_kernel_blmac_fir()
     bench_kernel_bank()
-    bench_bank_compiled()
     bench_bank_cse()
     bench_kernel_pulse_matmul()
     bench_roofline_summary()

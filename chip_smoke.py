@@ -84,7 +84,7 @@ def stream_phase(taps: int, rng) -> "object":
     eng = FilterBankEngine(program, channels=n_chan)
     p = eng.dispatch_plan
     interpret = resolve_interpret(eng.interpret)
-    print(f"[stream] plan mode={p.mode} lane={p.lane} merge={p.merge} "
+    print(f"[stream] plan mode={p.mode} merge={p.merge} "
           f"bank_tile={p.bank_tile} tile={p.tile} "
           f"pallas_interpret={interpret} tile_groups="
           f"{len(eng.bank_schedule.groups)}", flush=True)

@@ -10,7 +10,7 @@ artifact::
                           ▼
                     BlmacProgram  ──  save() / load()  (npz + JSON header)
         quantized taps · packed CSD trits · occupancy signatures
-        memoized superlayer schedules · partitions · cost estimates
+        memoized superlayer schedules · partitions · cycle counts
                           │  lower(program, backend=...)
         ┌───────────┬─────┴─────┬───────────┬─────────────┐
         ▼           ▼           ▼           ▼             ▼
@@ -21,8 +21,8 @@ artifact::
 Public surface:
 
   * `compile_bank` / `compile_packed` — content-addressed compilation,
-  * `BlmacProgram` — the artifact (schedules, partitions, cycle and
-    latency predictions all memoized on it),
+  * `BlmacProgram` — the artifact (schedules, partitions and cycle
+    predictions all memoized on it),
   * `lower` — executables for the five backends,
   * `cse_pass` / `OptimizedProgram` — the cross-filter CSE optimizing
     pass: shared partial-sum rows mined across the bank, bit-exact on
@@ -30,6 +30,8 @@ Public surface:
     ``docs/architecture.md`` "Optimization passes"),
   * `plan_bank_schedule` / `BankSchedule` / `superlayer_schedule` — the
     pack-time scheduler (moved here from ``kernels/blmac_fir.py``),
+  * `bank_plan` / `BankDispatchPlan` — the one rule that picks a bank's
+    kernel geometry (tile, bank tile, merge),
   * `cache_stats` / `clear_caches` — one observability point for every
     compile-pipeline cache,
   * `TailSnapshot` — overlap-save stream state frozen as an artifact,
@@ -37,8 +39,7 @@ Public surface:
     sharded engine's bit-exact fault recovery).
 
 `repro.filters.FilterBankEngine`, `ShardedFilterBankEngine`,
-`repro.serving.AsyncBankServer` and both autotuners are thin clients of
-this package.
+and `repro.serving.AsyncBankServer` are thin clients of this package.
 """
 from .cache import cache_stats, clear_caches
 from .lowering import BACKENDS, Lowered, lower
@@ -46,16 +47,18 @@ from .optimize import OptimizedProgram, cse_pass
 from .program import (BlmacProgram, CompileSpec, PROGRAM_FORMAT_VERSION,
                       ProgramFormatError, compile_bank, compile_packed,
                       pack_bank_trits)
-from .schedule import (BankSchedule, MERGE_DEFAULT, TileGroup,
-                       default_bank_tile, plan_bank_schedule,
-                       superlayer_schedule)
+from .schedule import (BankDispatchPlan, BankSchedule, DEFAULT_TILE,
+                       MERGE_DEFAULT, TileGroup, bank_plan, default_bank_tile,
+                       plan_bank_schedule, superlayer_schedule)
 from .state import STATE_FORMAT_VERSION, SnapshotFormatError, TailSnapshot
 
 __all__ = [
     "BACKENDS",
+    "BankDispatchPlan",
     "BankSchedule",
     "BlmacProgram",
     "CompileSpec",
+    "DEFAULT_TILE",
     "Lowered",
     "MERGE_DEFAULT",
     "OptimizedProgram",
@@ -65,6 +68,7 @@ __all__ = [
     "SnapshotFormatError",
     "TailSnapshot",
     "TileGroup",
+    "bank_plan",
     "cache_stats",
     "clear_caches",
     "compile_bank",

@@ -1,8 +1,6 @@
 """Bounded, instrumented caches for the compile pipeline.
 
-One content-addressed program cache replaces the four scattered LRUs that
-PRs 1–4 grew (`specialized_program`, `_bank_call`, the two autotuner
-caches): a `BlmacProgram` is compiled at most once per distinct bank
+One content-addressed program cache: a `BlmacProgram` is compiled at most once per distinct bank
 content, and every downstream cache keys on the program's digest instead
 of re-hashing (or worse, re-deriving) the bank.
 
@@ -11,7 +9,7 @@ every cache in the pipeline plus event counters for the expensive
 recomputations the refactor is meant to eliminate (CSD packings,
 schedule plans, machine-cycle derivations).  `tests/test_compiler.py`
 asserts through it that CSD/occupancy is computed exactly once when one
-bank is shared by the engine, the autotuner and the cycle predictor.
+bank is shared by the engines, the plan and the cycle predictor.
 """
 from __future__ import annotations
 
@@ -84,10 +82,8 @@ class ProgramCache:
 PROGRAM_CACHE = ProgramCache()
 
 # hit/miss stats for caches that live OUTSIDE this module but belong to
-# the one pipeline (the autotuners key on program digests; their cache
-# object stays in kernels/runtime.py to keep that module a leaf)
+# the one pipeline
 STATS: "dict[str, CacheStat]" = {
-    "autotune": CacheStat(),
     # the optimized-program memo (compiler/optimize.py `_CSE_MEMO`):
     # hits are cse_pass calls answered without re-mining
     "cse": CacheStat(),
@@ -109,7 +105,6 @@ def cache_stats() -> dict:
 
         {
           "program":     {"hits": ..., "misses": ..., "size": ...},
-          "autotune":    {"hits": ..., "misses": ..., "size": ...},
           "cse":         {"hits": ..., "misses": ..., "size": ...},
           "specialized": {"hits": ..., "misses": ..., "size": ...},
           "bank_call":   {"size": ...},          # jit cache: size only
@@ -123,7 +118,6 @@ def cache_stats() -> dict:
     # the submodule, NOT the same-named function re-exported by the
     # kernels package (`import ... as` would resolve the shadowing attr)
     _bf = importlib.import_module("repro.kernels.blmac_fir")
-    _rt = importlib.import_module("repro.kernels.runtime")
     _opt = importlib.import_module("repro.compiler.optimize")
 
     out: dict = {
@@ -131,11 +125,6 @@ def cache_stats() -> dict:
             "hits": PROGRAM_CACHE.stat.hits,
             "misses": PROGRAM_CACHE.stat.misses,
             "size": len(PROGRAM_CACHE),
-        },
-        "autotune": {
-            "hits": STATS["autotune"].hits,
-            "misses": STATS["autotune"].misses,
-            "size": len(_rt._AUTOTUNE_CACHE),
         },
         "cse": {
             "hits": STATS["cse"].hits,
@@ -160,12 +149,9 @@ def clear_caches() -> None:
     bounded).  The `_bank_call` jit cache is cleared too.
     """
     _bf = importlib.import_module("repro.kernels.blmac_fir")
-    _rt = importlib.import_module("repro.kernels.runtime")
     _opt = importlib.import_module("repro.compiler.optimize")
 
     PROGRAM_CACHE.clear()
-    _rt._AUTOTUNE_CACHE.clear()
-    STATS["autotune"].reset()
     _opt._CSE_MEMO.clear()
     STATS["cse"].reset()
     _bf.specialized_program.cache_clear()

@@ -12,11 +12,8 @@ is ``(C, T)`` or ``(T,)`` integer samples) for:
   * ``"specialized"`` — per-filter pulse-baked Pallas programs
     (`specialized_program` LRU) from ``program.pulse_schedules()``. int32.
   * ``"scheduled"``   — the sparsity-scheduled bank kernel on
-    ``program.packed`` with the memoized ``program.schedule()``. int32.
-    Takes a ``lane`` argument selecting the execution lane: ``None``
-    (legacy pallas_call + ``interpret``), ``"interpret"``, ``"mosaic"``
-    (TPU), ``"triton"`` (GPU) or ``"xla"`` — the fused CPU-compiled
-    lowering (`repro.kernels.blmac_fir._bank_call_xla`).
+    ``program.packed``, at the geometry `bank_plan` gives (the engines'
+    own): Mosaic on a TPU, the Pallas interpreter elsewhere. int32.
   * ``"vmachine"``    — the vectorized §4 machine simulator programmed
     with the bank; the executable exposes ``.vmachine`` and ``.fits``
     (weight-memory verdicts). int64.
@@ -42,6 +39,7 @@ from __future__ import annotations
 import numpy as np
 
 from .program import BlmacProgram
+from .schedule import DEFAULT_TILE, bank_plan
 
 __all__ = ["BACKENDS", "Lowered", "lower"]
 
@@ -95,7 +93,6 @@ def lower(
     interpret: bool | None = None,
     machine_spec=None,
     mesh=None,
-    lane: str | None = None,
 ) -> Lowered:
     """Lower ``program`` to an executable for ``backend`` (see module doc).
 
@@ -110,15 +107,12 @@ def lower(
         Configure the sharded engine (the other backends infer C from
         the input).
     tile, bank_tile, merge
-        Pin kernel geometry (None = defaults / memoized heuristics).
+        Pin kernel geometry (None = `bank_plan`'s).
     interpret : bool | None
         Pallas interpret override for the kernel backends.
     machine_spec : repro.core.MachineSpec | None
         The vmachine's spec (default: the paper's parameters at this
         tap count).
-    lane : str | None
-        Execution lane for the ``"scheduled"`` backend (see module doc);
-        ignored by the others.
 
     Returns
     -------
@@ -142,8 +136,8 @@ def lower(
     >>> prog = compile_bank(bank)
     >>> x = np.arange(30, dtype=np.int64)
     >>> y_oracle = lower(prog, "oracle")(x)
-    >>> y_xla = lower(prog, "scheduled", lane="xla", interpret=True)(x)
-    >>> bool((y_oracle == y_xla).all())          # bit-exact across lanes
+    >>> y_kernel = lower(prog, "scheduled", interpret=True)(x)
+    >>> bool((y_oracle == y_kernel).all())       # bit-exact across backends
     True
     """
     if not isinstance(program, BlmacProgram):
@@ -169,7 +163,7 @@ def lower(
 
         pulses = program.pulse_schedules()
         taps = program.taps
-        tile = tile or 1024
+        tile = tile or DEFAULT_TILE
 
         def run_specialized(x):
             xi = jnp.asarray(_as_channels(x), jnp.int32)
@@ -192,14 +186,13 @@ def lower(
     if backend == "scheduled":
         from ..kernels.blmac_fir import blmac_fir_bank
 
-        sched = program.schedule(bank_tile, merge)
-        tile = tile or 1024
+        plan, sched = bank_plan(program, tile, bank_tile, merge)
 
         def run_scheduled(x):
             return np.asarray(blmac_fir_bank(
-                _as_channels(x), program.packed, program.taps, tile,
+                _as_channels(x), program.packed, program.taps, plan.tile,
                 interpret=interpret, schedule=sched, fast_path=False,
-                lane=lane, combine=combine, n_real=n_real,
+                combine=combine, n_real=n_real,
             ))
 
         return Lowered(run_scheduled, backend, program, schedule=sched)

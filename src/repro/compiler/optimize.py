@@ -61,7 +61,7 @@ from .program import (BlmacProgram, CompileSpec, ProgramFormatError,
 __all__ = ["OptimizedProgram", "cse_pass", "CSE_MEMO_MAX"]
 
 # the memo holds whole optimized programs (augmented packed banks), so it
-# is bounded like the autotune cache; an evicted entry just re-mines
+# is bounded; an evicted entry just re-mines
 CSE_MEMO_MAX = 16
 _CSE_MEMO: dict = {}
 
@@ -76,8 +76,7 @@ class OptimizedProgram(BlmacProgram):
 
     The base-class arrays describe the AUGMENTED bank — ``n_real``
     reduced real rows followed by ``n_shared`` virtual 2-pulse rows — so
-    every `BlmacProgram` consumer (schedules, kernels, cost model,
-    simulators) executes it unchanged; consumers then apply ``combine``
+    every `BlmacProgram` consumer (schedules, kernels, simulators) executes it unchanged; consumers then apply ``combine``
     (one add + shift per use, as a small GEMM) to fold the shared rows
     back into the real outputs.  Bit-exact vs. the parent on every
     backend lane (`tests/differential.cse_check`).
@@ -202,33 +201,6 @@ class OptimizedProgram(BlmacProgram):
             spec = MachineSpec(taps=self.taps,
                                coeff_bits=self.n_layers + 1)
         return super().machine_cycles(spec)[self.n_real:]
-
-    # -- cost-model reads ----------------------------------------------------
-
-    def predict_scheduled_us(self, channels, n_tiles, tile,
-                             bank_tile=None, merge=None, cal=None) -> float:
-        """Augmented-schedule latency plus the combine-stage price — the
-        number the autotuner compares against the parent's own plan to
-        *decline* the pass when sharing loses on a dense-GEMM lane."""
-        from ..core.costmodel import predict_combine_us
-
-        base = super().predict_scheduled_us(
-            channels, n_tiles, tile, bank_tile, merge, cal=cal
-        )
-        return base + predict_combine_us(
-            self.n_real, self.n_shared, channels, n_tiles, tile, cal=cal
-        )
-
-    def predict_specialized_us(self, channels, n_tiles, cal=None) -> float:
-        from ..core.costmodel import predict_combine_us
-
-        base = super().predict_specialized_us(channels, n_tiles, cal=cal)
-        # the specialized path still pays the combine GEMM per dispatch;
-        # tile size only enters via the signal length, folded into
-        # n_tiles by the caller's framing, so price one unit tile
-        return base + predict_combine_us(
-            self.n_real, self.n_shared, channels, n_tiles, 1, cal=cal
-        )
 
     # -- row-structure hooks that do not survive the combine -----------------
 

@@ -15,9 +15,7 @@ computes it exactly once and every backend reads it off the artifact:
   * memoized superlayer schedules (`plan_bank_schedule`) per
     ``(bank_tile, merge)``,
   * memoized §4 machine cycle predictions per `MachineSpec`,
-  * memoized bank partitions (the sharded engine's plan hook),
-  * cost-model estimates (`predict_{specialized,scheduled}_us` read
-    their inputs off the program instead of re-unpacking trits).
+  * memoized bank partitions (the sharded engine's plan hook).
 
 Programs are content-addressed (`ProgramCache`): compiling the same bank
 twice — from coefficients or from an identical packed operand — is a
@@ -140,12 +138,8 @@ def _memo_put(memo: dict, key, value, cap: int) -> None:
         del memo[next(iter(memo))]
 
 
-# per-program memo bounds: schedules/subprograms embed packed-bank copies
-# (the quantity the old bounded autotune cache deliberately limited), so
-# cap them instead of growing forever.  The schedule cap must cover the
-# autotuner's full sweep width (2 bank-tile candidates × 3 interpret
-# merge candidates + 2 × 3 compiled merge candidates = 12 geometries)
-# or repeated sweeps thrash the memo.
+# per-program memo bounds: schedules/subprograms embed packed-bank copies,
+# so cap them instead of growing forever.
 SCHEDULE_MEMO_MAX = 16
 SUBPROGRAM_MEMO_MAX = 32
 
@@ -154,7 +148,7 @@ class BlmacProgram:
     """One compiled BLMAC filter bank — the artifact every backend executes.
 
     Read-only by contract (the arrays are flagged unwritable; programs are
-    shared across engines, autotuners and caches).  Construct via
+    shared across engines, plans and caches).  Construct via
     `compile_bank` / `compile_packed` / `load`, never directly.
 
     Attributes
@@ -215,11 +209,6 @@ class BlmacProgram:
     # -- derived views -------------------------------------------------------
 
     @property
-    def mean_pulses(self) -> float:
-        """Bank-average BLMAC pulses per filter (the cost model's knob)."""
-        return float(self.pulse_counts.mean()) if self.n_filters else 0.0
-
-    @property
     def out_filters(self) -> int:
         """Filters this program serves — equals ``n_filters`` here;
         an `OptimizedProgram` serves fewer than its row count (the
@@ -275,7 +264,7 @@ class BlmacProgram:
     ) -> BankSchedule:
         """The memoized superlayer schedule for one kernel geometry.
 
-        Engine construction, the autotuner grid sweep and benchmarks all
+        Engine construction (through `bank_plan`) and benchmarks all
         land here — one `plan_bank_schedule` per distinct
         ``(bank_tile, merge)`` per program, however many clients ask.
         """
@@ -337,7 +326,7 @@ class BlmacProgram:
 
     def partition(self, n_shards: int):
         """Memoized occupancy-balanced `BankPartition` over ``n_shards``
-        (the sharded engine's and mesh autotuner's shared plan hook).
+        (the hook `repro.distributed.sharding.sharded_plan` plans with).
 
         Parameters
         ----------
@@ -378,8 +367,8 @@ class BlmacProgram:
     def select(self, rows) -> "BlmacProgram":
         """The subprogram serving ``rows`` (original filter indices, in
         order) — array slices of this program, no recompilation.  Memoized
-        here AND registered content-addressed, so the sharded autotuner
-        and the sharded engine asking for the same shard get one object.
+        here AND registered content-addressed, so every sharded plan and
+        engine asking for the same shard gets one object.
 
         Parameters
         ----------
@@ -433,54 +422,6 @@ class BlmacProgram:
             PROGRAM_CACHE.put(sub, pkey, qkey)
         _memo_put(self._subprograms, memo, sub, SUBPROGRAM_MEMO_MAX)
         return sub
-
-    # -- cost-model reads ----------------------------------------------------
-
-    def predict_specialized_us(
-        self, channels: int, n_tiles: int, cal=None
-    ) -> float:
-        """Modelled per-dispatch latency of the per-filter specialized
-        loop — `repro.core.costmodel.predict_specialized_us` with every
-        bank-derived input read off the program.  ``cal`` optionally
-        selects a per-lane `BackendCalibration` constant set."""
-        from ..core.costmodel import predict_specialized_us
-
-        return predict_specialized_us(
-            self.n_filters, channels, n_tiles, self.taps,
-            self.mean_pulses, self.n_layers, cal=cal,
-        )
-
-    def predict_scheduled_us(
-        self,
-        channels: int,
-        n_tiles: int,
-        tile: int,
-        bank_tile: int | None = None,
-        merge: int | None = None,
-        cal=None,
-    ) -> float:
-        """Modelled per-dispatch latency of the scheduled bank path for
-        one geometry, costed on the memoized schedule.  ``cal``
-        optionally selects a per-lane `BackendCalibration` constant
-        set (default: the interpret reference constants).  The exact
-        schedule also decides ``f32_safe`` — whether EVERY superlayer's
-        digit bound admits the xla lane's exact-f32 contraction
-        (`repro.kernels.blmac_fir.f32_dot_safe`), which prices MACs at
-        the lane's f32 GEMM rate."""
-        from ..core.costmodel import predict_scheduled_us
-        from ..kernels.blmac_fir import f32_dot_safe
-
-        sched = self.schedule(bank_tile, merge)
-        m_pad = self.n_words * TRITS_PER_WORD
-        f32_safe = all(
-            f32_dot_safe(m_pad, parts)
-            for g in sched.groups
-            for _, parts in g.schedule
-        )
-        return predict_scheduled_us(
-            channels, n_tiles, tile, m_pad,
-            sched.group_summaries(), cal=cal, f32_safe=f32_safe,
-        )
 
     # -- serialization -------------------------------------------------------
 
@@ -661,7 +602,7 @@ def compile_bank(coeffs, spec: CompileSpec | None = None) -> BlmacProgram:
     one-program/five-backends pipeline.
 
     Content-addressed: the same bank compiles once per process (then per
-    `save` file across processes) — every engine, autotuner and
+    `save` file across processes) — every engine and
     predictor shares the artifact and its memoized schedules, partitions
     and cycle predictions.
 

@@ -5,8 +5,13 @@ occupancy-sorted filter permutation plus per-tile-group static *superlayer*
 programs that `repro.kernels.blmac_fir._fir_kernel_bank` executes verbatim.
 It is pure numpy planning (no jax), which is why it lives in the compiler
 package: `BlmacProgram.schedule()` memoizes its output per
-``(bank_tile, merge)`` so the engine, the autotuner and any benchmark
-asking for the same geometry share ONE plan.
+``(bank_tile, merge)`` so the engine and any benchmark asking for the
+same geometry share ONE plan.
+
+`bank_plan` is the one rule that picks a bank's kernel geometry: tile
+`DEFAULT_TILE`, bank tile `default_bank_tile`, merge `MERGE_DEFAULT`,
+each overridable.  The sharded half (`repro.distributed.sharding.
+sharded_plan`) applies it to every shard.
 
 Moved here from ``kernels/blmac_fir.py`` in the one-program refactor; the
 kernel module re-exports every name for backward compatibility.
@@ -28,21 +33,21 @@ __all__ = [
     "superlayer_schedule",
     "plan_bank_schedule",
     "default_bank_tile",
+    "DEFAULT_TILE",
+    "BankDispatchPlan",
+    "bank_plan",
 ]
 
 MAX_BANK_TILE = 256  # acc VMEM at tile=1024: 256×1024×4 B = 1 MiB
 
-# CSD layers fused per superlayer matmul (see plan_bank_schedule): the
-# measured optimum on the reference machine; 1 recovers the paper-pure
-# one-matmul-per-bit-layer kernel.  8 merged layers bound the superlayer
-# digit by 2**8 - 1, which keeps the whole contraction inside the exact
-# float32 window (`repro.kernels.blmac_fir.f32_dot_safe`: m_pad * bound
-# * 2**8 <= 2**24, satisfied for folded windows up to ~257 taps-half) —
-# so the compiled lanes run it on the fast f32 GEMM units bit-exactly,
-# the effect the compiled-merge autotuner sweep re-measures per plan.
-# It is also the widest merge the Pallas bank kernel's bf16 MXU
-# contraction takes exactly (`repro.kernels.blmac_fir.bf16_dot_safe`).
+# CSD layers fused per superlayer matmul (see plan_bank_schedule); 1
+# recovers the paper-pure one-matmul-per-bit-layer kernel.  8 merged
+# layers bound the superlayer digit by 2**8 - 1: the widest merge the
+# bank kernel's bf16 MXU contraction takes exactly
+# (`repro.kernels.blmac_fir.bf16_dot_safe`).
 MERGE_DEFAULT = 8
+# output samples per bank-kernel grid step
+DEFAULT_TILE = 256
 
 
 def _pad_to(n: int, m: int) -> int:
@@ -133,19 +138,6 @@ class BankSchedule:
         the quantity the dense kernel fixed at n_layers per tile."""
         return sum(len(g.schedule) for g in self.groups)
 
-    def group_summaries(self) -> "list[tuple[int, int, int, int]]":
-        """One ``(n_bank_tiles, bank_tile, n_superlayers, n_sel_layers)``
-        tuple per tile group — the shape `predict_scheduled_us` costs."""
-        return [
-            (
-                g.packed.shape[0] // self.tile_size,
-                self.tile_size,
-                len(g.schedule),
-                len(g.sel_layers),
-            )
-            for g in self.groups
-        ]
-
 
 def plan_bank_schedule(
     packed: np.ndarray,
@@ -226,3 +218,43 @@ def plan_bank_schedule(
         groups=tuple(groups),
         n_filters=n_filters,
     )
+
+
+@dataclass(frozen=True)
+class BankDispatchPlan:
+    """How a bank runs: the scheduled bank kernel at ``tile`` output
+    samples per grid step, ``bank_tile`` filters per bank tile and
+    ``merge`` CSD layers per superlayer matmul.  ``cse`` reads
+    ``"optimized"`` for a CSE `OptimizedProgram` (its shared-row layout
+    runs, folded by the combine matrix) and ``""`` otherwise."""
+
+    mode: str
+    tile: int
+    bank_tile: int
+    merge: int
+    cse: str = ""
+
+
+def bank_plan(program, tile: int | None = None, bank_tile: int | None = None,
+              merge: int | None = None):
+    """The plan and memoized `BankSchedule` of ``program`` (a
+    `BlmacProgram`): tile `DEFAULT_TILE`, bank tile
+    `default_bank_tile(n_filters)` and merge `MERGE_DEFAULT`, unless
+    ``tile``, ``bank_tile`` or ``merge`` pins one.  Every engine, the
+    sharded plan and `lower` take their kernel geometry from here.
+
+    >>> import numpy as np
+    >>> from repro.compiler import bank_plan, compile_bank
+    >>> bank = np.zeros((3, 15), np.int64)
+    >>> bank[:, 7] = [64, 96, 160]
+    >>> plan, schedule = bank_plan(compile_bank(bank))
+    >>> plan.mode, plan.tile, plan.bank_tile, plan.merge
+    ('scheduled', 256, 8, 8)
+    """
+    schedule = program.schedule(bank_tile, merge)
+    plan = BankDispatchPlan(
+        "scheduled", DEFAULT_TILE if tile is None else int(tile),
+        schedule.tile_size, schedule.merge,
+        cse="" if program.combine is None else "optimized",
+    )
+    return plan, schedule

@@ -4,15 +4,10 @@ filter with a BLMAC, with the symmetric pre-add optimization of Eq. 3.
     tot = N/2                              (pre-adds of symmetric samples)
         + Σ_{j<N/2+1} ntrits[|w_j|]        (BLMAC pulses)
 
-plus the comparison baselines the paper uses.
+plus the comparison baselines the paper uses, and the session server's
+admission model (`predict_session_step_us`).
 """
 from __future__ import annotations
-
-import json
-import os
-import platform
-import time
-from dataclasses import asdict, dataclass
 
 import numpy as np
 
@@ -27,17 +22,6 @@ __all__ = [
     "classical_equivalent_adds",
     "machine_cycles",
     "machine_cycles_batch",
-    "BackendCalibration",
-    "calibration_path",
-    "get_calibration",
-    "ensure_calibration",
-    "calibrate_backend",
-    "BankDispatchPlan",
-    "ShardedBankPlan",
-    "predict_specialized_us",
-    "predict_scheduled_us",
-    "predict_sharded_us",
-    "predict_recovery_us",
     "predict_session_step_us",
 ]
 
@@ -88,518 +72,6 @@ def machine_cycles(
 
 
 # ---------------------------------------------------------------------------
-# bank-dispatch cost model (the autotuner's objective function)
-# ---------------------------------------------------------------------------
-#
-# Coarse per-dispatch latency predictions for the FIR serving paths, in
-# microseconds.  Since the compiled-lowering work the constants are a
-# PER-BACKEND-LANE calibration table (`BackendCalibration`): each
-# execution lane — Pallas interpret, the CPU-compiled XLA path, TPU
-# Mosaic, GPU Triton — carries its own set, fitted by
-# `calibrate_backend()` at first compiled-sweep use and persisted next
-# to the program-cache root (`calibration_path()`), so the autotuner
-# ranks candidates with numbers measured on THIS machine instead of a
-# reference container's.  The module-level constants below are the
-# "interpret" lane's reference values (the machine the original
-# BENCH_fir.json was recorded on) and double as the fallback when no
-# fitted table exists; on other hardware the absolute numbers are wrong
-# but the *rankings* the autotuner needs (specialized for narrow banks,
-# wide-merge scheduled tiles for wide banks) are driven by the same
-# op-count asymmetries.  Accuracy is ±30% on the calibration grid —
-# good enough to pick a dispatch, not to replace measurement.
-
-SPEC_CALL_US = 140.0  # per specialized-program dispatch (B=1 pallas_call)
-SPEC_OP_US = 0.014  # per pulse/fold/shift op, per signal tile
-PALLAS_CALL_US = 500.0  # per scheduled-bank pallas_call dispatch
-STEP_US = 300.0  # per grid step: frame gather + interpret plumbing
-MAC_US = 7e-5  # per int32 multiply-accumulate in a superlayer matmul
-UNPACK_US = 2e-3  # per packed trit unpacked, per grid step
-
-
-@dataclass(frozen=True)
-class BackendCalibration:
-    """Per-lane cost-model constants (all microseconds).
-
-    ``lane`` names the execution path the constants describe:
-    ``"interpret"`` (Pallas interpreter), ``"xla"`` (the CPU-compiled
-    XLA lowering), ``"mosaic"`` (TPU) or ``"triton"`` (GPU).
-    ``source`` records provenance: ``"reference"`` (shipped defaults)
-    or ``"fitted"`` (measured on this host by `calibrate_backend`,
-    ``cpu_model`` stamps which one).
-    """
-
-    lane: str
-    spec_call_us: float  # per specialized-program dispatch
-    spec_op_us: float  # per pulse/fold/shift op, per signal tile
-    call_us: float  # per scheduled-bank kernel/jit dispatch
-    step_us: float  # per grid step: frame gather + plumbing
-    mac_us: float  # per int32 MAC in a superlayer contraction
-    unpack_us: float  # per packed trit unpacked, per grid step
-    # per MAC when the contraction runs on the f32 GEMM units — the xla
-    # lane's exact-f32 superlayer dot (see `_bank_call_xla`): CPU XLA
-    # vectorizes float GEMMs ~an order of magnitude harder than int32
-    # loops.  0.0 = lane has no separate f32 path (falls back to mac_us).
-    mac_f32_us: float = 0.0
-    source: str = "reference"
-    cpu_model: str = ""
-
-
-# Reference calibrations per lane.  The "interpret" row IS the historic
-# constant set; the compiled rows are order-of-magnitude priors that a
-# `calibrate_backend()` fit replaces at first use — they only need to
-# keep compiled candidates comparable amongst themselves until then.
-REFERENCE_CALIBRATIONS: "dict[str, BackendCalibration]" = {
-    "interpret": BackendCalibration(
-        "interpret", SPEC_CALL_US, SPEC_OP_US, PALLAS_CALL_US, STEP_US,
-        MAC_US, UNPACK_US,
-    ),
-    "xla": BackendCalibration(
-        "xla", spec_call_us=60.0, spec_op_us=1e-3, call_us=80.0,
-        step_us=8.0, mac_us=1.5e-4, unpack_us=4e-5, mac_f32_us=2e-5,
-    ),
-    "mosaic": BackendCalibration(
-        "mosaic", spec_call_us=30.0, spec_op_us=2e-4, call_us=40.0,
-        step_us=2.0, mac_us=2e-8, unpack_us=1e-6,
-    ),
-    "triton": BackendCalibration(
-        "triton", spec_call_us=30.0, spec_op_us=2e-4, call_us=40.0,
-        step_us=2.0, mac_us=5e-8, unpack_us=2e-6,
-    ),
-}
-
-
-def calibration_path() -> str:
-    """Where the fitted per-lane table persists: ``calibration.json``
-    under ``$REPRO_CACHE_DIR`` (default ``~/.cache/repro-blmac`` — the
-    same cache root serving processes use for saved programs), so a
-    process calibrates once per machine, not once per run.  CI caches
-    this file keyed on the runner's CPU model."""
-    root = os.environ.get(
-        "REPRO_CACHE_DIR",
-        os.path.join(os.path.expanduser("~"), ".cache", "repro-blmac"),
-    )
-    return os.path.join(root, "calibration.json")
-
-
-def _cpu_model() -> str:
-    try:
-        with open("/proc/cpuinfo") as f:
-            for line in f:
-                if line.startswith("model name"):
-                    return line.split(":", 1)[1].strip()
-    except OSError:
-        pass
-    return platform.processor() or platform.machine()
-
-
-def _load_table() -> dict:
-    try:
-        with open(calibration_path()) as f:
-            return json.load(f)
-    except (OSError, ValueError):
-        return {}
-
-
-def get_calibration(lane: str) -> BackendCalibration:
-    """The active `BackendCalibration` for ``lane``: the fitted entry
-    from `calibration_path()` when one exists for this CPU model, else
-    the reference defaults.  Pure read — never runs probes; use
-    `ensure_calibration` on paths allowed to measure."""
-    entry = _load_table().get(lane)
-    if entry and entry.get("cpu_model") == _cpu_model():
-        try:
-            return BackendCalibration(**entry)
-        except TypeError:  # older/foreign file layout: fall through
-            pass
-    if lane not in REFERENCE_CALIBRATIONS:
-        raise ValueError(
-            f"unknown lane {lane!r}; expected one of "
-            f"{sorted(REFERENCE_CALIBRATIONS)}"
-        )
-    return REFERENCE_CALIBRATIONS[lane]
-
-
-def ensure_calibration(lane: str) -> BackendCalibration:
-    """`get_calibration`, but fit-at-first-use: when no fitted entry for
-    this host exists yet, run `calibrate_backend` (seconds of probe
-    measurements), persist it, and return the fit.  Any probe failure
-    falls back to the reference constants — calibration is a ranking
-    aid, never a hard dependency."""
-    cal = get_calibration(lane)
-    if cal.source == "fitted":
-        return cal
-    try:
-        return calibrate_backend(lane)
-    except Exception:
-        return cal
-
-
-def calibrate_backend(lane: str, repeats: int = 3) -> BackendCalibration:
-    """Fit the ``lane``'s cost-model constants on THIS machine and
-    persist them to `calibration_path()`.
-
-    Probes (µs-scale, a few seconds total):
-
-    * dispatch overhead — wall time of a warm tiny dispatch on the lane
-      (a jitted no-op-sized program for ``"xla"``, a 1-step scheduled
-      kernel for the Pallas lanes),
-    * MAC rate — a warm ``(128, 64) @ (64, 65536)`` int32 contraction,
-      the superlayer matmul's shape family,
-    * unpack rate — the shift/mask trit decode over a packed operand,
-    * step overhead — a framed gather per grid step.
-
-    The specialized-path constants are scaled from the dispatch probe
-    (per-pulse work shares the lane's op rate).  Lanes other than
-    ``"xla"`` and ``"interpret"`` reuse the probe harness only where the
-    backend is actually present; fitting a TPU lane on a CPU host
-    raises.
-    """
-    import jax
-    import jax.numpy as jnp
-
-    if lane not in REFERENCE_CALIBRATIONS:
-        raise ValueError(f"unknown lane {lane!r}")
-    if lane == "mosaic" and jax.default_backend() != "tpu":
-        raise RuntimeError("cannot fit the mosaic lane without a TPU")
-    if lane == "triton" and jax.default_backend() != "gpu":
-        raise RuntimeError("cannot fit the triton lane without a GPU")
-    ref = REFERENCE_CALIBRATIONS[lane]
-
-    def best(fn, *args):
-        fn(*args)  # warm: compile + cache
-        t = float("inf")
-        for _ in range(repeats):
-            t0 = time.perf_counter()
-            fn(*args)
-            t = min(t, time.perf_counter() - t0)
-        return t * 1e6  # µs
-
-    if lane in ("xla", "mosaic", "triton"):
-        # dispatch: the smallest useful jitted program on the lane
-        tiny = jnp.zeros((8, 8), jnp.int32)
-        call_us = best(lambda a: jax.jit(jnp.sum)(a).block_until_ready(), tiny)
-        # MAC rate: one superlayer-family contraction
-        b, m, n = 128, 64, 65536
-        d = jnp.ones((b, m), jnp.int32)
-        u = jnp.ones((m, n), jnp.int32)
-        dot = jax.jit(
-            lambda d, u: jnp.dot(d, u, preferred_element_type=jnp.int32)
-        )
-        mac_us = max(
-            best(lambda d, u: dot(d, u).block_until_ready(), d, u) - call_us,
-            1e-3,
-        ) / (b * m * n)
-        # f32 GEMM rate: the same contraction on the float units (the
-        # exact-f32 superlayer dot of the xla lane)
-        df, uf = d.astype(jnp.float32), u.astype(jnp.float32)
-        dotf = jax.jit(jnp.dot)
-        mac_f32_us = max(
-            best(lambda d, u: dotf(d, u).block_until_ready(), df, uf)
-            - call_us,
-            1e-3,
-        ) / (b * m * n)
-        # unpack rate: shift/mask decode of a packed operand
-        words = jnp.ones((b, 16, m // 16), jnp.int32)
-        shifts = 2 * jnp.arange(16, dtype=jnp.int32)
-
-        def unpack(w):
-            codes = (w[..., None] >> shifts) & 3
-            return (
-                (codes == 1).astype(jnp.int32)
-                - (codes == 3).astype(jnp.int32)
-            ).sum()
-
-        unpack_us = max(
-            best(lambda w: jax.jit(unpack)(w).block_until_ready(), words)
-            - call_us,
-            1e-3,
-        ) / (b * 16 * m)
-        # step overhead: a framed gather, the per-grid-step fixed cost
-        frame = jnp.arange(4096, dtype=jnp.int32)
-        idx = jnp.arange(64)[:, None] + jnp.arange(512)[None, :]
-        gather = jax.jit(lambda f: f[idx].sum())
-        step_us = max(
-            best(lambda f: gather(f).block_until_ready(), frame) - call_us,
-            0.5,
-        )
-        cal = BackendCalibration(
-            lane=lane,
-            spec_call_us=call_us,
-            spec_op_us=max(mac_us * 512, 1e-5),  # per vector op per tile
-            call_us=call_us,
-            step_us=step_us,
-            mac_us=mac_us,
-            unpack_us=unpack_us,
-            mac_f32_us=mac_f32_us,
-            source="fitted",
-            cpu_model=_cpu_model(),
-        )
-    else:  # "interpret": fit the dominant dispatch/step terms via a
-        # real (tiny) interpreted kernel; keep reference per-op rates
-        from ..kernels.blmac_fir import blmac_fir_bank
-        from ..compiler.program import pack_bank_trits
-
-        q = np.zeros((2, 15), np.int64)
-        q[:, 7] = [64, 96]
-        packed = pack_bank_trits(q)
-        x = jnp.asarray(
-            np.random.default_rng(0).integers(-128, 128, 600), jnp.int32
-        )
-
-        def run(x):
-            blmac_fir_bank(
-                x, packed, 15, tile=512, interpret=True, fast_path=False
-            ).block_until_ready()
-
-        one = best(run, x)  # ~1 call + 2 steps of pure overhead
-        call_us = max(one * 0.4, 50.0)
-        cal = BackendCalibration(
-            lane=lane,
-            spec_call_us=call_us * ref.spec_call_us / ref.call_us,
-            spec_op_us=ref.spec_op_us,
-            call_us=call_us,
-            step_us=max((one - call_us) / 2, 10.0),
-            mac_us=ref.mac_us,
-            unpack_us=ref.unpack_us,
-            source="fitted",
-            cpu_model=_cpu_model(),
-        )
-
-    table = _load_table()
-    table[lane] = asdict(cal)
-    path = calibration_path()
-    os.makedirs(os.path.dirname(path), exist_ok=True)
-    tmp = path + ".tmp"
-    with open(tmp, "w") as f:
-        json.dump(table, f, indent=1)
-    os.replace(tmp, path)
-    return cal
-
-
-@dataclass(frozen=True)
-class BankDispatchPlan:
-    """Autotuner verdict: how to run a (B, taps) bank over C channels.
-
-    ``mode`` is ``"specialized"`` (per-filter pulse-baked programs) or
-    ``"scheduled"`` (occupancy-grouped bank tiles).  ``lane`` is the
-    execution lane the plan was costed for (``"interpret"`` — the
-    historic default — or a compiled lane: ``"xla"``, ``"mosaic"``,
-    ``"triton"``).  ``merge`` is the CSD-layers-per-superlayer fusion
-    factor of the scheduled kernel (1 = paper-pure one matmul per bit
-    layer); ``predicted_us`` is the modelled per-dispatch latency the
-    plan won with.
-    """
-
-    mode: str
-    tile: int
-    bank_tile: int
-    merge: int
-    predicted_us: float
-    lane: str = "interpret"
-    # CSE verdict for plans tuned on an `OptimizedProgram`: "" (plain
-    # program), "optimized" (the shared-row layout won), or "declined"
-    # (the parent's own best plan was cheaper once the combine stage was
-    # priced in — the engine then executes the parent, bit-identically)
-    cse: str = ""
-
-
-def predict_specialized_us(
-    n_filters: int,
-    channels: int,
-    n_tiles: int,
-    taps: int,
-    mean_pulses: float,
-    n_layers: int = 16,
-    cal: BackendCalibration | None = None,
-) -> float:
-    """Modelled latency of the per-filter specialized-program loop: one
-    dispatch per (filter, channel), each executing ~(folds + pulses +
-    layer shifts) vector ops per signal tile.  ``cal`` selects the
-    lane's constants (default: the "interpret" reference set)."""
-    c = cal or REFERENCE_CALIBRATIONS["interpret"]
-    ops = taps // 2 + mean_pulses + n_layers
-    return n_filters * channels * (
-        c.spec_call_us + n_tiles * ops * c.spec_op_us
-    )
-
-
-def predict_scheduled_us(
-    channels: int,
-    n_tiles: int,
-    tile: int,
-    m_pad: int,
-    groups: "list[tuple[int, int, int, int]]",
-    cal: BackendCalibration | None = None,
-    f32_safe: bool = False,
-) -> float:
-    """Modelled latency of the scheduled bank path.
-
-    ``groups`` summarizes a `BankSchedule`: one ``(n_bank_tiles,
-    bank_tile, n_superlayers, n_sel_layers)`` tuple per tile group.  Cost
-    per grid step = fixed step overhead + one matmul per superlayer +
-    the unpack of the tile's selected trit layers.  ``cal`` selects the
-    lane's constants (default: the "interpret" reference set) — the
-    SAME formula ranks compiled-lane candidates, only the constants
-    change.  ``f32_safe`` marks schedules whose superlayer digit bound
-    admits the exact-f32 contraction (see `_bank_call_xla`): MACs are
-    then priced at the lane's ``mac_f32_us`` GEMM rate when it has one.
-    """
-    c = cal or REFERENCE_CALIBRATIONS["interpret"]
-    mac = (c.mac_f32_us or c.mac_us) if f32_safe else c.mac_us
-    total = 0.0
-    for n_bank_tiles, bank_tile, n_super, n_sel in groups:
-        if n_sel == 0:
-            continue  # zero-fill group: no kernel dispatched
-        step = (
-            c.step_us
-            + n_super * bank_tile * m_pad * tile * mac
-            + n_sel * bank_tile * m_pad * c.unpack_us
-        )
-        total += c.call_us + n_bank_tiles * channels * n_tiles * step
-    return total
-
-
-def predict_combine_us(
-    n_real: int,
-    n_shared: int,
-    channels: int,
-    n_tiles: int,
-    tile: int,
-    cal: BackendCalibration | None = None,
-) -> float:
-    """Modelled latency of the CSE combine stage: one extra dispatch
-    applying the ``(n_real, n_shared)`` reuse matrix to the shared-row
-    outputs — a small GEMM plus the elementwise add back into the real
-    rows.  Priced at the lane's *int32* MAC rate even when the schedule
-    itself is f32-safe: combined outputs reach the full int32 range, so
-    the exact-f32 trick never applies to this contraction.  Zero when
-    the program has no shared rows."""
-    if n_shared == 0:
-        return 0.0
-    c = cal or REFERENCE_CALIBRATIONS["interpret"]
-    signal = channels * n_tiles * tile
-    return c.call_us + n_real * (n_shared + 1) * signal * c.mac_us
-
-
-# ---------------------------------------------------------------------------
-# mesh-aware sharded-bank cost model
-# ---------------------------------------------------------------------------
-#
-# The sharded engine replicates per-shard bank programs over a (bank, data)
-# device mesh the way the paper replicates 110-LUT machines.  Its latency
-# model is CRITICAL-PATH over TWO resources: the slowest shard's device
-# time (shards run concurrently on disjoint devices), and the HOST's
-# total dispatch time (one python thread feeds the whole mesh, so
-# per-shard dispatch work is serial and SUMS across shards — a
-# specialized-mode shard costs one host dispatch per filter, which is
-# why a mesh full of specialized shards is host-bound while the same
-# shards as single-group scheduled programs are not).  Plus the fixed
-# costs sharding adds: one queue hop per shard, and (for time-sharded
-# single-channel streams) one halo ppermute per push.  The same fitted
-# single-device constants feed the per-shard terms, so "does sharding
-# pay at all?" is answered by the SAME model that ranks single-device
-# modes: a narrow bank where dispatch overhead dominates comes back
-# with n_bank_shards == 1 (the autotuner declines).
-
-SHARD_DISPATCH_US = 250.0  # per bank-shard program dispatch, per push
-HALO_EXCHANGE_US = 180.0  # per time-shard halo ppermute, per push
-
-
-@dataclass(frozen=True)
-class ShardedBankPlan:
-    """Mesh-aware autotuner verdict for one bank on one (bank, data) mesh.
-
-    ``n_bank_shards`` of 1 means the autotuner DECLINED to shard the
-    filter axis (single-device program wins).  ``data_mode`` is how the
-    second mesh axis is used: ``"none"`` (axis size 1), ``"channels"``
-    (C divisible over the axis, no communication) or ``"time"`` (signal
-    chunks with an overlap-save halo exchange).  ``shard_plans`` holds
-    one single-device `BankDispatchPlan` per bank shard — per-shard
-    mode/tile picks, exactly what the issue's heterogeneous-occupancy
-    banks need.  ``predicted_us`` is the modelled critical path.
-    """
-
-    n_bank_shards: int
-    n_data: int
-    data_mode: str
-    shard_plans: tuple
-    predicted_us: float
-    # CSE verdict when tuned on an `OptimizedProgram` — same convention
-    # as `BankDispatchPlan.cse` ("", "optimized", or "declined")
-    cse: str = ""
-
-    @property
-    def sharded(self) -> bool:
-        return self.n_bank_shards > 1 or self.n_data > 1
-
-
-def predict_sharded_us(
-    shard_us,
-    n_data: int = 1,
-    data_mode: str = "none",
-    host_us=None,
-) -> float:
-    """Critical-path latency of a sharded dispatch.
-
-    ``shard_us`` are per-shard single-device predictions (each shard's
-    slice of the data axis already folded in by the caller); shards run
-    concurrently, so their DEVICE contribution is the max.  ``host_us``
-    are per-shard host dispatch costs; the host is one serial resource,
-    so they SUM — whichever resource saturates first is the critical
-    path.  Per-shard queue overhead and the time-shard halo exchange
-    are added on top."""
-    shard_us = list(shard_us)
-    if not shard_us:
-        raise ValueError("predict_sharded_us needs at least one shard")
-    n_shards = len(shard_us)
-    us = max(shard_us)
-    if host_us is not None:
-        us = max(us, float(sum(host_us)))
-    us += n_shards * SHARD_DISPATCH_US
-    if data_mode == "time" and n_data > 1:
-        us += HALO_EXCHANGE_US * n_data
-    return us
-
-
-# ---------------------------------------------------------------------------
-# recovery cost model (fault-tolerant re-partition target choice)
-# ---------------------------------------------------------------------------
-#
-# When `ShardedFilterBankEngine` loses a shard it must pick the shard
-# count of the re-partitioned survivor mesh.  The choice trades a
-# ONE-TIME bill (compiling the candidate's per-shard schedules, and
-# replaying the in-flight chunks through the new mesh) against the
-# candidate's STEADY-STATE per-push latency over however long the
-# recovered mesh is expected to serve.  The constants are coarse, in
-# the same fitted-on-the-reference-container spirit as the dispatch
-# constants above: they only need to rank candidates (e.g. "7 fresh
-# shard schedules + slightly better steady state" vs "4 likely-memoized
-# shards"), not predict wall time.
-
-RECOVERY_REPLAN_US = 2500.0  # per fresh shard subprogram: select + schedule
-REPLAY_US_PER_SAMPLE = 0.5  # per in-flight output sample replayed
-RECOVERY_HORIZON_PUSHES = 50.0  # pushes the recovered mesh amortizes over
-
-
-def predict_recovery_us(
-    steady_us: float,
-    n_replanned_shards: int,
-    replay_samples: int,
-) -> float:
-    """Modelled total cost of adopting one recovery target: the re-plan
-    bill for its ``n_replanned_shards`` shard schedules, the replay of
-    ``replay_samples`` in-flight output samples, and its steady-state
-    per-push latency (``steady_us``, from `predict_sharded_us`) over
-    the amortization horizon.  Lower is better; used by
-    `ShardedFilterBankEngine` to choose the re-partition shard count."""
-    return (
-        n_replanned_shards * RECOVERY_REPLAN_US
-        + replay_samples * REPLAY_US_PER_SAMPLE
-        + RECOVERY_HORIZON_PUSHES * float(steady_us)
-    )
-
-
-# ---------------------------------------------------------------------------
 # multi-tenant session-step cost model (admission control)
 # ---------------------------------------------------------------------------
 #
@@ -610,10 +82,11 @@ def predict_recovery_us(
 # real tenant (idle lanes are zero-padded), so a round costs the full
 # dispatch plus n_slots lane-fills regardless of occupancy — which is
 # exactly the asymmetry admission control needs: adding a session is
-# nearly free until it spills a new round.  Same fitted-on-the-reference-
-# container spirit as the constants above: ranks "admit vs reject", does
-# not predict wall time.
+# nearly free until it spills a new round.  The constants are guesses
+# made on a CPU, never measured on the chip: they rank "admit vs
+# reject", they do not predict wall time.
 
+SESSION_DISPATCH_US = 800.0  # per bank dispatch round
 SESSION_LANE_US = 45.0  # per channel lane staged + sliced, per round
 JOURNAL_APPEND_US = 15.0  # per chunk/pull WAL record framed + written
 JOURNAL_SYNC_US = 400.0  # per group-commit fsync at the end of a step
@@ -628,15 +101,12 @@ def predict_session_step_us(
     """Modelled latency of one session-server batching step with
     ``n_active`` sessions packed into ``n_slots`` shared lanes:
     ceil(n_active / n_slots) rounds, each a full ``dispatch_us`` bank
-    dispatch (from `predict_specialized_us` / `predict_scheduled_us`)
-    plus the per-lane staging cost of every slot in the round, plus the
-    step's flat write-ahead-journal bill (``journal_us``, built by the
-    server from `JOURNAL_APPEND_US` / `JOURNAL_SYNC_US` when a journal
-    is attached).  ``dispatch_us`` is the CURRENT engine plan's
-    prediction — on a sharded engine that plan is rebuilt by every
-    fault recovery, so admission is automatically priced against the
-    degraded mesh.  The server admits a session only while the
-    predicted step stays inside its latency budget."""
+    dispatch (the server passes `SESSION_DISPATCH_US`) plus the
+    per-lane staging cost of every slot in the round, plus the step's
+    flat write-ahead-journal bill (``journal_us``, built by the server
+    from `JOURNAL_APPEND_US` / `JOURNAL_SYNC_US` when a journal is
+    attached).  The server admits a session only while the predicted
+    step stays inside its latency budget."""
     if n_slots < 1:
         raise ValueError("n_slots must be >= 1")
     if n_active <= 0:

@@ -84,8 +84,7 @@ def layer_occupancy(digits: np.ndarray) -> np.ndarray:
 
 
 def layer_pulse_counts(digits: np.ndarray) -> np.ndarray:
-    """(…, M, L) CSD digits → int64 (…, L) pulses per bit layer (the
-    autotuner's per-layer work predictor)."""
+    """(…, M, L) CSD digits → int64 (…, L) pulses per bit layer."""
     return np.count_nonzero(np.asarray(digits), axis=-2).astype(np.int64)
 
 

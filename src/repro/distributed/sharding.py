@@ -16,8 +16,10 @@ FIR bank partitioning (the BLMAC serving side):
         dense shard does not straggle the mesh (the paper scales by
         replicating 110-LUT machines; we scale by replicating per-shard
         bank programs)
-  DATA  channels (or, for single-channel streams, signal time chunks
-        with an overlap-save halo exchange) over the `data` mesh axis
+  DATA  channels, or signal time chunks with an overlap-save halo
+        exchange, over the `data` mesh axis, when asked for
+`sharded_plan` applies these rules, and `repro.compiler.bank_plan` to
+each shard.
 """
 from __future__ import annotations
 
@@ -300,3 +302,88 @@ def partition_bank(
     inv[np.concatenate(assign)] = np.arange(n_filters)
     shard_cost = np.array([cost[a].sum() for a in assign])
     return BankPartition(assign=assign, inv=inv, cost=shard_cost)
+
+
+DATA_MODES = ("none", "channels", "time")
+
+
+@dataclass(frozen=True)
+class ShardedBankPlan:
+    """How a bank runs on a (bank, data) mesh.
+
+    ``n_bank_shards`` bank shards, one per mesh row; ``data_mode`` says
+    how the second mesh axis is used: ``"none"`` (one device per shard),
+    ``"channels"`` (C split over the axis, no communication) or
+    ``"time"`` (signal chunks with an overlap-save halo exchange), over
+    ``n_data`` devices.  ``shard_plans`` holds each shard's
+    `repro.compiler.BankDispatchPlan`.
+    """
+
+    n_bank_shards: int
+    n_data: int
+    data_mode: str
+    shard_plans: tuple
+
+    @property
+    def sharded(self) -> bool:
+        return self.n_bank_shards > 1 or self.n_data > 1
+
+
+def sharded_plan(
+    program,
+    mesh_shape: "tuple[int, int]",
+    channels: int = 1,
+    n_bank_shards: int | None = None,
+    data_mode: str | None = None,
+    tile: int | None = None,
+    merge: int | None = None,
+):
+    """Plan a `repro.compiler.BlmacProgram` over an ``(n_bank, n_data)``
+    mesh → ``(ShardedBankPlan, BankPartition, schedules)``.
+
+    The bank is cut into ``n_bank_shards`` shards (default: every bank
+    row of the mesh), at most one per filter and per bank row, by the
+    program's memoized `partition`.  The data axis is used only when
+    ``data_mode`` names ``"channels"`` (needs C divisible by the axis)
+    or ``"time"``; the default is ``"none"``, and a mode the mesh cannot
+    give raises ValueError.  Each shard runs `repro.compiler.bank_plan`
+    of its memoized `select` subprogram, at ``tile`` and ``merge`` when
+    given.
+
+    >>> import numpy as np
+    >>> from repro.compiler import compile_bank
+    >>> bank = np.zeros((6, 15), np.int64)
+    >>> bank[:, 7] = np.arange(1, 7) * 32
+    >>> plan, part, _ = sharded_plan(compile_bank(bank), (4, 1))
+    >>> plan.n_bank_shards, plan.data_mode, part.n_shards
+    (4, 'none', 4)
+    """
+    from ..compiler import bank_plan
+
+    n_bank, n_data = int(mesh_shape[0]), int(mesh_shape[1])
+    mode = "none" if data_mode is None else data_mode
+    if mode not in DATA_MODES:
+        raise ValueError(f"unknown data mode {mode!r}; expected one of "
+                         f"{DATA_MODES}")
+    if mode != "none" and (
+        n_data < 2 or (mode == "channels" and channels % n_data)
+    ):
+        raise ValueError(
+            f"data mode {mode!r} is not available on a ({n_bank}, "
+            f"{n_data}) mesh with {channels} channel(s)"
+        )
+    shards = n_bank if n_bank_shards is None else int(n_bank_shards)
+    shards = max(1, min(shards, n_bank, program.n_filters))
+    part = program.partition(shards)
+    plans, schedules = [], []
+    for rows in part.assign:
+        plan, schedule = bank_plan(program.select(rows), tile, merge=merge)
+        plans.append(plan)
+        schedules.append(schedule)
+    plan = ShardedBankPlan(
+        n_bank_shards=shards,
+        n_data=1 if mode == "none" else n_data,
+        data_mode=mode,
+        shard_plans=tuple(plans),
+    )
+    return plan, part, tuple(schedules)

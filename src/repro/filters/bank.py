@@ -7,26 +7,17 @@ the output samples that became computable — carrying the ``taps − 1``
 sample tail between chunks (classical overlap-save) so consecutive pushes
 produce one gapless output stream per (filter, channel) pair.
 
-Mode selection mirrors the hardware trade-off:
+The engine runs one path: the scheduled bank kernel at the geometry
+`repro.compiler.bank_plan` gives (tile, bank tile and merge, each
+overridable).  Filters are sorted into occupancy-homogeneous bank tiles
+at construction time (`plan_bank_schedule`), each tile group is one
+`pallas_call` iterating ONLY its populated superlayers, and the packed
+uint32 trit operands stay resident on the device across pushes.
 
-  * ``"specialized"`` — per-filter pulse-baked programs from the LRU
-    program cache; wins for narrow banks where per-call overhead is
-    amortized and the add count is exactly the pulse count.
-  * ``"packed"``      — the scheduled bank path: filters sorted into
-    occupancy-homogeneous bank tiles at construction time
-    (`plan_bank_schedule`), each tile group one `pallas_call` iterating
-    ONLY its populated superlayers, packed uint32 trit operands resident
-    on device across pushes.
-  * ``"auto"``        — the default: `autotune_bank_dispatch` runs both
-    candidates (and the scheduled tile/merge grid) through the
-    calibrated cost model in `repro.core.costmodel` and keeps the
-    winner's plan — no threshold guessing.
-
-Since the one-program refactor the engine is a thin client of
-`repro.compiler`: construction compiles (or is handed) ONE
-`BlmacProgram` and reads everything off it — the packed trit operands,
-the memoized superlayer schedule, the per-filter pulse schedules of
-specialized mode, and the §4 cycle predictions.  Two engines built on
+The engine is a thin client of `repro.compiler`: construction compiles
+(or is handed) ONE `BlmacProgram` and reads everything off it — the
+packed trit operands, the memoized superlayer schedule and the §4 cycle
+predictions.  Two engines built on
 the same bank share one program (content-addressed), and an engine built
 from a `BlmacProgram.load()`ed file starts without recompiling anything.
 
@@ -40,7 +31,7 @@ int32.  A 16-bit stream (int16 input) is split on the host into two
 kernels, and combined on the device into exact int64 outputs
 (`repro.kernels.blmac_fir.plane_combine`).
 
-Bit-exactness: all modes agree with `repro.filters.fir_bit_layers_batch`
+Bit-exactness: every geometry agrees with `repro.filters.fir_bit_layers_batch`
 to the last bit on integer inputs (property-tested in `tests/test_bank.py`
 and `tests/differential.py`).
 """
@@ -51,15 +42,12 @@ import dataclasses
 import numpy as np
 import jax.numpy as jnp
 
-from ..kernels.runtime import DEFAULT_TILE, span
+from ..kernels.runtime import span
 
-# Legacy crossover (filters below → specialized) — superseded by the
-# autotuner for mode="auto"; kept because external callers used it to
-# pre-decide a forced mode.
-SPECIALIZE_THRESHOLD = 8
+__all__ = ["FilterBankEngine", "PushStats"]
 
-__all__ = ["FilterBankEngine", "PushStats", "SPECIALIZE_THRESHOLD",
-           "DEFAULT_TILE"]
+# the values ``mode`` accepts: one path, under two names
+MODES = ("auto", "packed")
 
 
 @dataclasses.dataclass
@@ -140,41 +128,29 @@ class FilterBankEngine:
         prebuilt / `load()`ed program skips compilation entirely.  A
         CSE-`OptimizedProgram` serves its PARENT's filters: the engine
         runs the shared-row layout and folds the combine matrix inside
-        `_apply` (``mode="auto"`` lets the autotuner *decline* the
-        optimized layout — ``dispatch_plan.cse`` records the verdict).
+        `_apply`.
     channels : int
         Number of independent input channels C (all filtered by every filter).
     tile : int | None
-        Output samples per kernel grid step (lane-parallel width).
-        ``None`` lets the autotuner pick (falls back to ``DEFAULT_TILE``
-        for forced modes).
-    mode : {"auto", "packed", "scheduled", "specialized"}
-        ``"scheduled"`` is an alias for ``"packed"``.
+        Output samples per kernel grid step (None = `DEFAULT_TILE`).
+    mode : {"auto", "packed"}
+        Both name the one path; accepted for callers that pass it.
     bank_tile : int | None
-        Filters per bank tile of the scheduled kernel (None = heuristic).
+        Filters per bank tile (None = `default_bank_tile`).
     merge : int | None
-        CSD layers fused per superlayer matmul (None = kernel default;
+        CSD layers fused per superlayer matmul (None = `MERGE_DEFAULT`;
         1 = paper-pure one matmul per bit layer).
     interpret : bool | None
         Pallas interpret override; None = backend default.
     chunk_hint : int
-        Expected samples per push, the autotuner's amortization knob
-        (streaming chunks are short; batch jobs long).
-    compiled : bool | str
-        Opt the ``"auto"`` sweep into the compiled execution lanes
-        (``True`` = this host's `default_lane`, or a lane name);
-        the engine then executes whatever lane the winning plan names.
-        Default ``False`` keeps the historic interpret-only behaviour.
-    lane : str | None
-        Pin the execution lane for a forced (non-auto) packed mode —
-        e.g. ``"xla"`` runs the schedule through the fused compiled
-        lowering.  ``None`` = the legacy pallas_call + ``interpret``.
+        Ignored; accepted for callers that pass it.
 
     Raises
     ------
     ValueError
-        Unknown ``mode``, ``channels < 1``, or non-type-I/overflowing
-        coefficients (via `compile_bank`'s §2.1 bound check).
+        A ``mode`` other than ``"auto"``/``"packed"``, ``channels < 1``,
+        or non-type-I/overflowing coefficients (via `compile_bank`'s
+        §2.1 bound check).
 
     Examples
     --------
@@ -201,11 +177,8 @@ class FilterBankEngine:
         interpret: bool | None = None,
         merge: int | None = None,
         chunk_hint: int = 2048,
-        compiled: "bool | str" = False,
-        lane: str | None = None,
     ):
-        from ..compiler import BlmacProgram, MERGE_DEFAULT, compile_bank
-        from ..kernels.runtime import autotune_bank_dispatch
+        from ..compiler import BlmacProgram, bank_plan, compile_bank
 
         if isinstance(qbank, BlmacProgram):
             program = qbank
@@ -221,40 +194,13 @@ class FilterBankEngine:
             )
         if channels < 1:
             raise ValueError("channels must be >= 1")
-        if mode == "scheduled":
-            mode = "packed"
-        if mode not in ("auto", "packed", "specialized"):
-            raise ValueError(f"unknown mode {mode!r}")
+        if mode not in MODES:
+            raise ValueError(f"unknown mode {mode!r}; expected one of {MODES}")
         self.taps = program.taps
         self.channels = int(channels)
         self.interpret = interpret
-        self.dispatch_plan = None
-        self.lane = lane
-        schedule = None
-        if mode == "auto":
-            self.dispatch_plan, schedule = autotune_bank_dispatch(
-                program, channels=self.channels, tile=tile,
-                chunk_hint=chunk_hint, interpret=interpret,
-                compiled=compiled,
-            )
-            if self.dispatch_plan.cse == "declined":
-                # a CSE-optimized program whose shared-row layout the
-                # cost model rejects here: the plan (and schedule) are
-                # the PARENT's — execute it, bit-identical outputs
-                program = program.parent
-            mode = (
-                "specialized"
-                if self.dispatch_plan.mode == "specialized"
-                else "packed"
-            )
-            if self.lane is None and self.dispatch_plan.lane != "interpret":
-                self.lane = self.dispatch_plan.lane
-            if tile is None:
-                tile = self.dispatch_plan.tile
-            if bank_tile is None and schedule is not None:
-                bank_tile = schedule.tile_size
-            if merge is None and schedule is not None:
-                merge = schedule.merge
+        self.dispatch_plan, schedule = bank_plan(program, tile, bank_tile,
+                                                 merge)
         self.program = program
         # external face: a CSE-optimized program still SERVES the
         # parent's filters — qbank/n_filters describe the combined
@@ -265,33 +211,16 @@ class FilterBankEngine:
             else program.effective_qbank()
         )
         self.n_filters = program.out_filters
-        self.tile = int(tile) if tile is not None else DEFAULT_TILE
-        self.mode = mode
-        self.merge = merge if merge is not None else MERGE_DEFAULT
-        if mode == "packed":
-            # the program memoizes one plan per (bank_tile, merge) — the
-            # autotuned schedule and an explicit-override re-plan resolve
-            # through the same memo; upload each tile group's packed
-            # operand ONCE so push() feeds device-resident operands
-            # instead of re-staging the bank every chunk
-            if (
-                schedule is None
-                or (bank_tile is not None and bank_tile != schedule.tile_size)
-                or schedule.merge != self.merge
-            ):
-                schedule = program.schedule(bank_tile, self.merge)
-            self.bank_schedule = schedule
-            self.bank_tile = schedule.tile_size
-            self._group_ops = [
-                jnp.asarray(g.packed.view(np.int32)) if g.sel_layers else None
-                for g in schedule.groups
-            ]
-            self._schedules = None
-        else:
-            self.bank_schedule = None
-            self.bank_tile = bank_tile
-            self._group_ops = None
-            self._schedules = program.pulse_schedules()
+        self.tile = self.dispatch_plan.tile
+        self.bank_tile = self.dispatch_plan.bank_tile
+        self.merge = self.dispatch_plan.merge
+        # upload each tile group's packed operand ONCE so push() feeds
+        # device-resident operands instead of re-staging the bank
+        self.bank_schedule = schedule
+        self._group_ops = [
+            jnp.asarray(g.packed.view(np.int32)) if g.sel_layers else None
+            for g in schedule.groups
+        ]
         # overlap-save state: the last taps-1 samples of every channel,
         # and the stream's sample width (None until the first push)
         self._tail = np.zeros((channels, 0), np.int32)
@@ -471,7 +400,7 @@ class FilterBankEngine:
     def _apply(self, buf: np.ndarray, wide: bool = False) -> np.ndarray:
         """The bank's outputs on the (C, n) int32 buffer: int32, or for
         ``wide`` (16-bit samples) int64 through the two 8-bit planes."""
-        from ..kernels.blmac_fir import (bank_schedule_apply, blmac_fir_specialized,
+        from ..kernels.blmac_fir import (bank_schedule_apply,
                                          frame_signal_batch, plane_combine)
         from ..kernels.runtime import resolve_interpret
 
@@ -491,54 +420,31 @@ class FilterBankEngine:
                 buf = np.pad(buf, ((0, 0), (0, n_pad - n)))
             x = jnp.asarray(buf, jnp.int32)
         lanes = buf.shape[0]  # channels, or the 2C planes of a wide push
-        if self.mode == "packed":
-            interpret = resolve_interpret(self.interpret)
-            with span("dispatch"):
-                frames, _ = frame_signal_batch(x, self.taps, self.tile)
-                y = bank_schedule_apply(
-                    frames,
-                    self.bank_schedule,
-                    self.taps,
-                    self.tile,
-                    interpret,
-                    device_groups=self._group_ops,
-                    lane=self.lane,
-                    combine=self._combine,
-                    n_real=(self.n_filters if self._combine is not None
-                            else None),
-                )  # (B, lanes, n_tiles * tile), caller order restored + combined
-                if wide:
-                    with span("plane_combine"):
-                        y = plane_combine(y, n_out, interpret)
-                else:
-                    y = y[:, :, :n_out]
-            rows = sum(g.packed.shape[0] for g in self.bank_schedule.groups)
-            self.stats.outputs_computed += rows * lanes * cols
-            with span("wait"):
-                y.block_until_ready()
-            with span("readback"):
-                out = np.asarray(y)
-            self.stats.bytes_read_back += out.nbytes
-            # a wide push's words, less the padding of their last block
-            return out[:, :, :2 * n_out].view(np.int64) if wide else out
-        out = np.empty((len(self._schedules), lanes, n_out), np.int32)
-        # each filter × channel is read back before the next dispatch,
-        # so every readback sits inside the loop's dispatch span
+        interpret = resolve_interpret(self.interpret)
         with span("dispatch"):
-            for b, pulses in enumerate(self._schedules):
-                for c in range(lanes):
-                    yc = blmac_fir_specialized(
-                        x[c], pulses, self.taps, self.tile, self.interpret
-                    )
-                    with span("readback"):
-                        out[b, c] = np.asarray(yc)[:n_out]
-                    self.stats.bytes_read_back += yc.nbytes
-        self.stats.outputs_computed += out.shape[0] * lanes * cols
-        if self._combine is not None:
-            from ..compiler.lowering import _host_combine_i32
-
-            out = _host_combine_i32(out, self._combine, self.n_filters)
-        if wide:
-            c = self.channels
-            return (out[:, c:].astype(np.int64) << 8) + out[:, :c]
-        return out
+            frames, _ = frame_signal_batch(x, self.taps, self.tile)
+            y = bank_schedule_apply(
+                frames,
+                self.bank_schedule,
+                self.taps,
+                self.tile,
+                interpret,
+                device_groups=self._group_ops,
+                combine=self._combine,
+                n_real=(self.n_filters if self._combine is not None
+                        else None),
+            )  # (B, lanes, n_tiles * tile), caller order restored + combined
+            if wide:
+                with span("plane_combine"):
+                    y = plane_combine(y, n_out, interpret)
+            else:
+                y = y[:, :, :n_out]
+        rows = sum(g.packed.shape[0] for g in self.bank_schedule.groups)
+        self.stats.outputs_computed += rows * lanes * cols
+        with span("wait"):
+            y.block_until_ready()
+        with span("readback"):
+            out = np.asarray(y)
+        self.stats.bytes_read_back += out.nbytes
+        # a wide push's words, less the padding of their last block
+        return out[:, :, :2 * n_out].view(np.int64) if wide else out

@@ -16,11 +16,9 @@ a two-axis device mesh:
     halo exchange (`repro.distributed.collectives.halo_exchange_left`,
     one `ppermute` of ``taps − 1`` samples per push) inside `shard_map`.
 
-Whether sharding pays at all is the mesh-aware autotuner's call
-(`repro.kernels.runtime.autotune_sharded_dispatch`): the unsharded plan
-competes in the same critical-path sweep, and a narrow bank or a short
-chunk comes back with ``n_bank_shards == 1`` — the engine then degrades
-to the single-device scheduled path bit-for-bit.
+The plan is `repro.distributed.sharding.sharded_plan`: one bank shard
+per mesh row (or the caller's count), the data axis only when the caller
+names a ``data_mode``, and `repro.compiler.bank_plan` on every shard.
 
 Output reassembly is gather-free: per-shard outputs land on their own
 devices, every shard's host copy starts at dispatch, and the host
@@ -37,8 +35,8 @@ makes the chunk deterministically replayable on ANY backend of the same
 program.  When a shard is detected dead (a raised `ShardLost`, or the
 `ShardHealth` watchdog timeout), the engine removes that mesh row,
 re-partitions the bank over the survivors via the program's memoized
-`partition`/`select` slices (recovery shard count chosen by
-`repro.core.costmodel.predict_recovery_us`), and replays every
+`partition`/`select` slices (one shard per surviving mesh row, or the
+caller's count), and replays every
 in-flight chunk from its snapshot — so the resumed stream is bit-exact
 with an uninterrupted run.  When the mesh degrades to a single device
 the engine falls back to the plain `FilterBankEngine` lowering of the
@@ -67,8 +65,9 @@ from ..distributed.faultbank import (FaultStats, PendingInvalidated,
                                      ShardCorruption, ShardError, ShardHealth,
                                      ShardLost, ShardTimeout,
                                      TransientShardError)
-from ..distributed.sharding import (DATA_AXIS, BankPartition, bank_mesh,
-                                    mesh_bank_shape)
+from ..distributed.sharding import (DATA_AXIS, BankPartition,
+                                    ShardedBankPlan, bank_mesh,
+                                    mesh_bank_shape, sharded_plan)
 from ..kernels.runtime import span
 from .bank import PushStats, check_8bit, padded_columns
 
@@ -192,28 +191,22 @@ class ShardedFilterBankEngine:
         Quantized odd symmetric (type-I) coefficients, one row per filter
         — compiled once via `compile_bank` (content-addressed); passing a
         prebuilt / `load()`ed program warm-starts without recompiling.
-        Shard subprograms are the program's memoized `select()` slices,
-        shared with the mesh autotuner.
+        Shard subprograms are the program's memoized `select()` slices.
     channels : int
         Independent input channels C (all filtered by every filter).
     mesh : jax.sharding.Mesh | None
         A mesh with a ``bank`` axis and optionally a ``data`` axis
         (see `repro.distributed.sharding.bank_mesh`).  ``None`` builds a
         (n_devices, 1) mesh over every visible device.  A 1×1 mesh is
-        valid and degrades to the single-device scheduled engine.
+        valid: one shard on one device.
     n_bank_shards : int | None
-        Force the filter-shard count (clamped to the mesh's bank axis);
-        ``None`` lets the mesh-aware autotuner pick — including picking
-        1 when sharding does not pay.
+        The filter-shard count (clamped to the mesh's bank axis and the
+        bank size); ``None`` = one shard per bank row.
     data_mode : {"none", "channels", "time"} | None
-        Force how the data axis is used; ``None`` lets the autotuner
-        pick — including leaving the axis idle when the halo/split
-        overhead loses to a single device per shard.
-    tile, merge, chunk_hint, interpret, compiled
-        As `repro.filters.FilterBankEngine`; per-shard tiles/modes are
-        autotuned per shard unless ``tile`` pins them.  ``compiled``
-        opts every per-shard sweep into the compiled execution lanes;
-        each shard then runs the lane its winning plan names.
+        How the data axis is used; ``None`` = ``"none"``, the axis idle.
+    tile, merge, chunk_hint, interpret
+        As `repro.filters.FilterBankEngine` (``chunk_hint`` is ignored);
+        ``tile`` and ``merge`` apply to every shard.
     fault_injector : repro.distributed.faultbank.FaultInjector | None
         Deterministic chaos hooks (tests/benchmarks only): consulted on
         every shard dispatch and materialize.
@@ -240,7 +233,6 @@ class ShardedFilterBankEngine:
         merge: int | None = None,
         chunk_hint: int = 2048,
         interpret: bool | None = None,
-        compiled: "bool | str" = False,
         fault_injector=None,
         shard_timeout: float | None = None,
         integrity_check: bool = False,
@@ -276,9 +268,7 @@ class ShardedFilterBankEngine:
         self._force_data = data_mode
         self._tile_arg = tile
         self._merge_arg = merge
-        self._chunk_hint = chunk_hint
         self._interpret_arg = interpret
-        self._compiled_arg = compiled
         self.injector = fault_injector
         self.shard_timeout = shard_timeout
         self.integrity_check = bool(integrity_check)
@@ -299,51 +289,21 @@ class ShardedFilterBankEngine:
 
     # -- construction helpers ----------------------------------------------
 
-    def _configure(self, mesh: Mesh, force_shards: int | None = None) -> None:
-        """(Re)build the mesh-dependent half of the engine: autotuned
-        plan, partition, per-shard dispatch closures, chunk quantum and
-        the `ShardHealth` watchdog.  Called at construction and again by
+    def _configure(self, mesh: Mesh) -> None:
+        """(Re)build the mesh-dependent half of the engine: plan,
+        partition, per-shard dispatch closures, chunk quantum and the
+        `ShardHealth` watchdog.  Called at construction and again by
         `_recover` with the surviving-device mesh."""
-        from ..kernels.runtime import autotune_sharded_dispatch
-
         n_bank, n_data = mesh_bank_shape(mesh)
         if n_bank * n_data != mesh.size:
             raise ValueError(
                 f"mesh must be ({'bank'}, {'data'})-shaped, got {mesh.shape}"
             )
-        force = force_shards if force_shards is not None else self._force_bank
-        if force is not None:
-            force = max(1, min(int(force), n_bank, self.n_filters))
-        self.plan, self.partition, schedules = autotune_sharded_dispatch(
-            self.program, channels=self.channels, mesh_shape=(n_bank, n_data),
-            tile=self._tile_arg, chunk_hint=self._chunk_hint,
-            interpret=self._interpret_arg,
-            force_shards=force, force_data=self._force_data,
-            compiled=self._compiled_arg,
+        self.plan, self.partition, schedules = sharded_plan(
+            self.program, (n_bank, n_data), channels=self.channels,
+            n_bank_shards=self._force_bank, data_mode=self._force_data,
+            tile=self._tile_arg, merge=self._merge_arg,
         )
-        if self._merge_arg is not None:
-            # re-plan only the scheduled shards whose merge differs,
-            # KEEPING each shard's autotuned bank tile, and stamp the
-            # override into the shard plans; the re-plan goes through the
-            # shard subprogram's schedule memo, and predicted_us
-            # intentionally keeps the autotuner's estimate for ITS
-            # schedules — the cost model is not re-run for a forced merge
-            import dataclasses
-
-            merge = self._merge_arg
-            schedules = tuple(
-                self.program.select(rows).schedule(sched.tile_size, merge)
-                if sched is not None and sched.merge != merge else sched
-                for rows, sched in zip(self.partition.assign, schedules)
-            )
-            self.plan = dataclasses.replace(
-                self.plan,
-                shard_plans=tuple(
-                    dataclasses.replace(p, merge=merge)
-                    if p.mode == "scheduled" else p
-                    for p in self.plan.shard_plans
-                ),
-            )
         self.mesh = mesh
         self.n_bank_shards = self.plan.n_bank_shards
         self.n_data = self.plan.n_data
@@ -364,18 +324,12 @@ class ShardedFilterBankEngine:
         self._shards = []
         # rows each shard's kernels produce, tile-group padding included
         self._shard_rows = [
-            len(rows) if sched is None
-            else sum(g.packed.shape[0] for g in sched.groups)
-            for rows, sched in zip(self.partition.assign, schedules)
+            sum(g.packed.shape[0] for g in sched.groups)
+            for sched in schedules
         ]
-        for s, (rows, plan) in enumerate(
-            zip(self.partition.assign, self.plan.shard_plans)
-        ):
+        for s, plan in enumerate(self.plan.shard_plans):
             self._shards.append(
-                self._build_shard(
-                    self.program.select(rows),  # the autotuner's subprogram
-                    plan, schedules[s], devices[s % n_bank],
-                )
+                self._build_shard(plan, schedules[s], devices[s % n_bank])
             )
         self.health = ShardHealth(
             len(self._shards), timeout=self.shard_timeout,
@@ -385,30 +339,19 @@ class ShardedFilterBankEngine:
     def _configure_degraded(self, device) -> None:
         """Last-resort recovery target: one surviving device.  The SAME
         `BlmacProgram` is lowered through the plain single-device
-        `FilterBankEngine` (its autotuned packed/specialized path), and
-        the shard list collapses to one host-side closure.  ``device``
-        is the survivor: the engine's operands and every dispatch are
-        placed on it, never on the default device (which may be the
-        one that was lost)."""
-        from ..core.costmodel import BankDispatchPlan, ShardedBankPlan
+        `FilterBankEngine`, and the shard list collapses to one
+        host-side closure.  ``device`` is the survivor: the engine's
+        operands and every dispatch are placed on it, never on the
+        default device (which may be the one that was lost)."""
         from .bank import FilterBankEngine
 
         with jax.default_device(device):
             plain = FilterBankEngine(
                 self.program, channels=self.channels, tile=self._tile_arg,
-                merge=self._merge_arg, chunk_hint=self._chunk_hint,
-                interpret=self._interpret_arg, compiled=self._compiled_arg,
+                merge=self._merge_arg, interpret=self._interpret_arg,
             )
         self._plain = plain
-        plan1 = plain.dispatch_plan
-        if plan1 is None:
-            plan1 = BankDispatchPlan(
-                mode=plain.mode, tile=plain.tile,
-                bank_tile=plain.bank_tile or 0, merge=plain.merge,
-                predicted_us=float("nan"),
-            )
-        self.plan = ShardedBankPlan(1, 1, "none", (plan1,),
-                                    plan1.predicted_us)
+        self.plan = ShardedBankPlan(1, 1, "none", (plain.dispatch_plan,))
         self.n_bank_shards, self.n_data, self.data_mode = 1, 1, "none"
         b = self.n_filters
         self.partition = BankPartition(
@@ -431,35 +374,11 @@ class ShardedFilterBankEngine:
         )
         self.fault.degraded_since = time.perf_counter()
 
-    def _build_shard(self, subprogram, plan, schedule, dev_row):
+    def _build_shard(self, plan, schedule, dev_row):
         """One bank shard = (dispatch closure, device row).  Returns a
         callable ``fn(buf_np, n) -> device output`` where ``buf_np`` is
-        the padded (C, n_pad) int32 buffer and ``n`` the valid length.
-        ``subprogram`` is the shard's `BlmacProgram` slice — its pulse
-        schedules and packed operands are the memoized artifacts the
-        autotuner already costed."""
-        if plan.mode == "specialized":  # n_data == 1 by construction
-            pulses = subprogram.pulse_schedules()
-            dev = dev_row[0]
-
-            def run_specialized(buf, n):
-                from ..kernels.blmac_fir import blmac_fir_specialized
-
-                x = jax.device_put(jnp.asarray(buf, jnp.int32), dev)
-                chans = [x[c] for c in range(self.channels)]
-                return [
-                    [
-                        blmac_fir_specialized(
-                            xc, p, self.taps, plan.tile, self.interpret
-                        )
-                        for xc in chans
-                    ]
-                    for p in pulses
-                ]
-
-            return run_specialized, 0
-
-        fn = self._make_scheduled_fn(schedule, plan.tile, lane=plan.lane)
+        the padded (C, n_pad) int32 buffer and ``n`` the valid length."""
+        fn = self._make_scheduled_fn(schedule, plan.tile)
         if self.n_data == 1:
             dev = dev_row[0]
             ops = tuple(
@@ -514,20 +433,17 @@ class ShardedFilterBankEngine:
 
         return run_mapped, offset
 
-    def _make_scheduled_fn(self, schedule, tile, lane=None):
+    def _make_scheduled_fn(self, schedule, tile):
         """Jitted scheduled-bank program for one shard: frame, then the
         shared `bank_schedule_apply` group loop (zeros for empty groups,
         one `_bank_call` per tile group, shard-order restoration).  The
         schedule is static (closed over); jit caches per input shape ×
         device.  ``ops`` carries only the NON-empty groups' operands
         (shard_map in_specs must match real arrays), re-slotted to the
-        full per-group list here.  ``lane`` is the shard plan's execution
-        lane ("interpret" → the legacy pallas_call + interpret flag)."""
+        full per-group list here."""
         from ..kernels.blmac_fir import bank_schedule_apply, frame_signal_batch
 
         taps, interpret = self.taps, self.interpret
-        if lane == "interpret":
-            lane = None  # legacy path: honour the interpret flag
         has_layers = [bool(g.sel_layers) for g in schedule.groups]
 
         @jax.jit
@@ -537,7 +453,7 @@ class ShardedFilterBankEngine:
             full = [next(it) if h else None for h in has_layers]
             return bank_schedule_apply(
                 frames, schedule, taps, tile, interpret,
-                device_groups=full, lane=lane,
+                device_groups=full,
             )
 
         return fn
@@ -814,15 +730,6 @@ class ShardedFilterBankEngine:
         def read():
             if inj is not None:
                 inj.on_materialize(s, p.chunk_idx)
-            if isinstance(y, list):  # specialized shard: per-filter arrays
-                rows = [
-                    np.stack([np.asarray(a)[:n_out] for a in chans])
-                    for chans in y
-                ]
-                self.stats.bytes_read_back += sum(
-                    a.nbytes for chans in y for a in chans
-                )
-                return np.stack(rows)
             host = np.asarray(y)
             if isinstance(y, jax.Array):  # not the degraded engine's
                 self.stats.bytes_read_back += host.nbytes
@@ -892,8 +799,9 @@ class ShardedFilterBankEngine:
 
     def _recover(self, err: ShardLost) -> None:
         """Handle a detected shard loss: drop the dead mesh row,
-        re-partition the bank over the survivors (recovery shard count
-        chosen by modelled cost), rebuild the dispatch closures, and
+        re-partition the bank over the survivors (one shard per
+        surviving row, or the caller's count), rebuild the dispatch
+        closures, and
         replay every in-flight chunk from its tail snapshot.  Raises
         `ShardLost` when no surviving device remains."""
         with span("recover"):
@@ -922,44 +830,10 @@ class ShardedFilterBankEngine:
         else:
             devices = [d for row in rows
                        for d in np.asarray(row).reshape(-1)]
-            target = self._choose_recovery_shards(n_bank, n_data)
-            self._configure(bank_mesh(n_bank, n_data, devices=devices),
-                            force_shards=target)
+            self._configure(bank_mesh(n_bank, n_data, devices=devices))
         self._replay_inflight()
         self.fault.recoveries += 1
         self.fault.last_recovery_s = time.perf_counter() - t0
-
-    def _choose_recovery_shards(self, n_bank: int, n_data: int) -> int:
-        """Pick the recovery target's bank-shard count by modelled cost
-        (`repro.core.costmodel.predict_recovery_us`): each candidate
-        pays for its fresh per-shard schedules and the in-flight replay,
-        then its steady-state latency over the amortization horizon.
-        Candidates are the full surviving row count and the power of two
-        below it (partitions the program has likely already memoized).
-        A caller-forced shard count short-circuits the sweep."""
-        from ..core.costmodel import predict_recovery_us
-        from ..kernels.runtime import autotune_sharded_dispatch
-
-        if self._force_bank is not None:
-            return max(1, min(int(self._force_bank), n_bank, self.n_filters))
-        replay = sum(p.n_out for p in self._inflight)
-        pow2 = 1
-        while pow2 * 2 <= n_bank:
-            pow2 *= 2
-        best, best_us = None, float("inf")
-        for cand in sorted({min(n_bank, self.n_filters),
-                            min(pow2, self.n_filters)}):
-            plan, _, schedules = autotune_sharded_dispatch(
-                self.program, channels=self.channels,
-                mesh_shape=(n_bank, n_data), tile=self._tile_arg,
-                chunk_hint=self._chunk_hint, interpret=self._interpret_arg,
-                force_shards=cand, force_data=self._force_data,
-            )
-            n_scheduled = sum(1 for sc in schedules if sc is not None)
-            us = predict_recovery_us(plan.predicted_us, n_scheduled, replay)
-            if us < best_us:
-                best, best_us = cand, us
-        return best
 
     def _replay_inflight(self) -> None:
         """Re-dispatch every unresolved chunk through the recovered
@@ -1037,15 +911,14 @@ class ShardedFilterBankEngine:
         return times
 
     def describe(self) -> str:
-        """One line for logs: mesh, shard modes, balance, predicted cost."""
+        """One line for logs: mesh, shard modes and balance."""
         modes = ",".join(p.mode[:4] for p in self.plan.shard_plans)
         degraded = " DEGRADED" if self._plain is not None else ""
         return (
             f"sharded-bank B={self.n_filters} C={self.channels} "
             f"mesh=({self.n_bank_shards}x{self.n_data}){degraded} "
             f"data={self.data_mode} modes=[{modes}] "
-            f"imbalance={self.partition.imbalance:.2f} "
-            f"predicted={self.plan.predicted_us:.0f}us"
+            f"imbalance={self.partition.imbalance:.2f}"
         )
 
 

@@ -7,7 +7,6 @@
                     integer matmul per populated superlayer (the
                     1.98M-filter sweep path); B=1 fast-paths to the
                     specialized program
-  autotune_bank_dispatch — cost-model dispatch planner for the above
   blmac_matmul    — CSD-P pulse-code quantized matmul (serving-side weight
                     decompression; attacks the decode memory roofline)
 """
@@ -21,7 +20,6 @@ from .ops import (
 )
 from .blmac_fir import (BankSchedule, pack_bank_trits, plan_bank_schedule,
                         superlayer_schedule)
-from .runtime import autotune_bank_dispatch
 from . import ref
 
 __all__ = [
@@ -31,7 +29,6 @@ __all__ = [
     "pack_bank_trits",
     "plan_bank_schedule",
     "superlayer_schedule",
-    "autotune_bank_dispatch",
     "default_interpret",
     "pulse_dequantize",
     "pulse_matmul_op",

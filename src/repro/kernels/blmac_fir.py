@@ -355,40 +355,6 @@ def _bank_call(
     )(frames.reshape(n_chan * n_tiles, 1, frame_len), packed)
 
 
-# ---------------------------------------------------------------------------
-# compiled lowering lanes
-# ---------------------------------------------------------------------------
-#
-# The scheduled kernel above runs on four execution lanes:
-#
-#   "interpret" — the Pallas interpreter (the CPU test lane; the historic
-#       CI target every BENCH_fir number was recorded on),
-#   "mosaic"    — pallas_call compiled for TPU: the same kernel body,
-#   "triton"    — pallas_call compiled for GPU,
-#   "xla"       — the SAME superlayer schedule lowered as a plain jitted
-#       XLA program (no Pallas): the always-available compiled CI target,
-#       since the Pallas interpreter is the only Pallas mode a CPU host
-#       can run.
-#
-# The XLA lane keeps the two properties that make the Pallas kernel fast:
-# the packed trit words are the *operand* (the 2-bit→{-1,0,+1} decode
-# happens inside the jitted program, so XLA fuses it into the dot's LHS
-# and trits never round-trip through memory as unpacked int8), and each
-# populated superlayer is ONE integer contraction — here against the
-# window matrix of EVERY (channel, signal-tile) grid cell at once,
-# ``(B_pad, M) @ (M, C·n_tiles·tile)``, which is exactly the
-# wide-matmul-unit regime where the compiled autotuner sweep
-# re-evaluates the merge heuristic: superlayers whose digit bound stays
-# below the f32 mantissa limit run bit-exactly on the float GEMM units
-# (`f32_dot_safe`), which caps the winning merge near the f32-safe span
-# instead of "fuse everything".
-# The cost is materializing that im2col-style window matrix
-# (``m_pad × signal`` int32, ~`m_pad`× the signal bytes) instead of one
-# (M, tile) block per grid step — the right trade below VMEM-scale
-# signals, and the reason the Pallas lanes keep the blocked layout.
-
-LANES = ("interpret", "mosaic", "triton", "xla")
-
 # float32 mantissa: integers of magnitude < 2**24 are exactly
 # representable, and sums/products that stay under the bound are exact
 F32_EXACT_BOUND = 1 << 24
@@ -403,10 +369,7 @@ def f32_dot_safe(m_pad: int, parts) -> bool:
     and the superlayer digit is bounded by its trit shifts,
     ``|d_j| <= sum(2**rel)``.  When ``m_pad * bound(d) * 2**8 < 2**24``
     every partial sum of the dot is an integer below the f32 mantissa
-    limit, so running it on the float GEMM units is bit-exact — and on
-    CPU XLA those units are ~an order of magnitude faster than the int32
-    matmul loop (the wide-matmul-unit effect the compiled merge
-    heuristic re-evaluates; see `repro.core.costmodel`).
+    limit, so an f32-accumulating contraction is bit-exact.
     """
     bound = sum(1 << rel for _, rel in parts)
     return m_pad * bound * 256 <= F32_EXACT_BOUND
@@ -436,102 +399,6 @@ def bf16_dot_safe(m_pad: int, parts) -> bool:
     return bound <= BF16_EXACT_INT and f32_dot_safe(m_pad, parts)
 
 
-def _lane_interpret(lane: str, interpret: bool) -> bool:
-    """Pallas ``interpret`` flag for a lane (the "xla" lane never reaches
-    a pallas_call)."""
-    if lane == "interpret":
-        return True
-    if lane in ("mosaic", "triton", "xla"):
-        return False
-    raise ValueError(f"unknown lane {lane!r}; expected one of {LANES}")
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("taps", "schedule", "tail_shift", "tile", "n_real"),
-)
-@jax.named_scope("blmac/bank_xla")
-def _bank_call_xla(
-    frames: jnp.ndarray,  # (C, n_tiles, frame_len) int32
-    packed: jnp.ndarray,  # (B_pad, n_sel, n_words) int32, selected layers
-    taps: int,
-    schedule: tuple,
-    tail_shift: int,
-    tile: int,
-    combine: jnp.ndarray | None = None,  # (n_real, n_shared) int32
-    n_real: int | None = None,
-) -> jnp.ndarray:
-    """The scheduled bank computation as ONE fused XLA program — same
-    schedule semantics as `_fir_kernel_bank`, same (B_pad, C, n_tiles,
-    tile) result, bit-exact.
-
-    ``combine`` (CSE-optimized programs, `repro.compiler.optimize`) adds
-    a second small GEMM to the fused program: rows past ``n_real`` are
-    shared partial-sum rows, folded back as ``y[:n_real] + combine @
-    y[n_real:]`` — int32 ring arithmetic, so the result equals the
-    parent program's output bit-for-bit even if a shared row wraps."""
-    n_chan, n_tiles, frame_len = frames.shape
-    b_pad, n_sel, n_words = packed.shape
-    m_pad = n_words * TRITS_PER_WORD
-    half = taps // 2
-    # window matrix for EVERY grid cell at once: row j of cell (c, s)
-    # holds the symmetric fold u_j[t] = x[t+j] + x[t+taps-1-j]
-    j = jnp.arange(m_pad, dtype=jnp.int32)[:, None]
-    t = jnp.arange(tile, dtype=jnp.int32)[None, :]
-    fwd = frames[..., jnp.minimum(j + t, frame_len - 1)]
-    rev = frames[..., jnp.clip(taps - 1 - j + t, 0, frame_len - 1)]
-    u = jnp.where(j < half, fwd + rev, jnp.where(j == half, fwd, 0))
-    # (C, n_tiles, m_pad, tile) → (m_pad, C·n_tiles·tile): the RHS every
-    # superlayer contraction shares
-    u = jnp.moveaxis(u, 2, 0).reshape(m_pad, n_chan * n_tiles * tile)
-
-    shifts = 2 * jnp.arange(TRITS_PER_WORD, dtype=jnp.int32)
-
-    def trit_layer(sel_idx):
-        # fused unpack: packed words are the operand; the 2-bit decode is
-        # part of the jitted program, feeding the dot LHS directly
-        codes = (packed[:, sel_idx, :, None] >> shifts) & 3
-        d = (codes == 1).astype(jnp.int32) - (codes == 3).astype(jnp.int32)
-        return d.reshape(b_pad, m_pad)
-
-    # superlayers whose digit bound admits the exact-f32 contraction run
-    # on the float GEMM units (see `f32_dot_safe`); the window matrix is
-    # converted once (|u_j| <= 2**8: exact)
-    u_f32 = (
-        u.astype(jnp.float32)
-        if any(f32_dot_safe(m_pad, parts) for _, parts in schedule)
-        else None
-    )
-    acc = jnp.zeros((b_pad, u.shape[1]), jnp.int32)
-    for shift_in, parts in schedule:  # MSB → LSB over populated superlayers
-        if shift_in:
-            acc = acc << shift_in
-        d = None
-        for sel_idx, rel in parts:
-            dl = trit_layer(sel_idx)
-            if rel:
-                dl = dl << rel
-            d = dl if d is None else d + dl
-        if f32_dot_safe(m_pad, parts):
-            # every partial sum is an integer < 2**24: the f32 dot is
-            # bit-exact, and the f32->s32 convert of exact integers is too.
-            # HIGHEST keeps a TPU from rounding the operands to bf16
-            y = jnp.dot(
-                d.astype(jnp.float32), u_f32,
-                precision=jax.lax.Precision.HIGHEST,
-            ).astype(jnp.int32)
-        else:
-            y = jnp.dot(d, u, preferred_element_type=jnp.int32)
-        acc = acc + y
-    if tail_shift:
-        acc = acc << tail_shift
-    if combine is not None:
-        acc = acc[:n_real] + jnp.dot(
-            combine, acc[n_real:], preferred_element_type=jnp.int32
-        )
-    return acc.reshape(acc.shape[0], n_chan, n_tiles, tile)
-
-
 def pulses_from_packed(packed_row: np.ndarray, taps: int):
     """(n_layers, n_words) packed trits → MSB-first static pulse tuple
     (the `specialized_program` input) — the small-bank fast path's bridge
@@ -558,7 +425,6 @@ def blmac_fir_bank(
     merge: int = MERGE_DEFAULT,
     schedule: BankSchedule | None = None,
     fast_path: bool = True,
-    lane: str | None = None,
     combine: np.ndarray | None = None,
     n_real: int | None = None,
 ) -> jnp.ndarray:
@@ -575,9 +441,7 @@ def blmac_fir_bank(
     baseline in PR 1 purely in framing/padding overhead; now it costs
     exactly its pulse count.  Pass a precomputed ``schedule`` (from
     `plan_bank_schedule`) to skip planning on the hot path — the
-    `FilterBankEngine` does this once at construction.  ``lane``
-    selects the execution lane (see `LANES`; compiled lanes skip the
-    fast path — specialized programs are an interpret-era optimization).
+    `FilterBankEngine` does this once at construction.
     ``combine``/``n_real`` execute a CSE-optimized shared-row bank (see
     `bank_schedule_apply`); the result then has ``n_real`` rows.
     """
@@ -594,7 +458,6 @@ def blmac_fir_bank(
         and schedule is None
         and combine is None
         and n_filters <= FAST_PATH_MAX
-        and lane in (None, "interpret")
     ):
         xi = x.astype(jnp.int32)
         n_out = xi.shape[-1] - taps + 1
@@ -616,7 +479,7 @@ def blmac_fir_bank(
     if schedule is None:
         schedule = plan_bank_schedule(packed, bank_tile, merge)
     frames, n_out = frame_signal_batch(x.astype(jnp.int32), taps, tile)
-    y = bank_schedule_apply(frames, schedule, taps, tile, interpret, lane=lane,
+    y = bank_schedule_apply(frames, schedule, taps, tile, interpret,
                             combine=combine, n_real=n_real)
     # one combined slice: separate [:, :, :n_out] then [:, 0, :] would copy
     # the full (B, C, signal) buffer twice on the host
@@ -630,7 +493,6 @@ def bank_schedule_apply(
     tile: int,
     interpret: bool,
     device_groups: list | None = None,
-    lane: str | None = None,
     combine: jnp.ndarray | None = None,
     n_real: int | None = None,
 ) -> jnp.ndarray:
@@ -639,44 +501,16 @@ def bank_schedule_apply(
 
     ``device_groups`` optionally supplies pre-uploaded packed operands
     (one per group, int32 view) so streaming callers don't re-stage the
-    bank every chunk.  ``lane`` selects the execution lane (see `LANES`);
-    None keeps the legacy behaviour — a pallas_call honouring the
-    ``interpret`` flag — while ``"xla"`` routes to the fused compiled
-    lowering `_bank_call_xla` (bit-exact against every other lane).
+    bank every chunk.  Each group is one `_bank_call`: Mosaic on a TPU,
+    the Pallas interpreter under ``interpret``.
 
     ``combine``/``n_real`` execute a CSE-optimized program's shared-row
     layout (`repro.compiler.optimize`): rows past ``n_real`` are shared
     partial sums, folded back after reassembly as one small int32 GEMM
-    plus an add — on the single-group xla path the GEMM fuses into the
-    lowered program itself.  The result then has ``n_real`` rows."""
+    plus an add.  The result then has ``n_real`` rows."""
     n_chan, n_tiles, _ = frames.shape
     if combine is not None:
         combine = jnp.asarray(np.asarray(combine, np.int32))
-    if lane is not None and lane != "xla":
-        interpret = _lane_interpret(lane, interpret)
-    if len(schedule.groups) == 1 and lane == "xla":
-        # Single tile group (the common autotuned shape): fold the
-        # caller-order restore into the dot's LHS instead of gathering
-        # the (B, C, signal) result — permuting the tiny packed operand's
-        # rows permutes the output rows for free, where `y[inv]` is a
-        # full-output-size copy (~6 ms of the ~40 ms xla arm at the
-        # BENCH_compiled geometry).  Pad rows drop out with the same
-        # indexing.  Pallas lanes keep the gather: their grid needs the
-        # padded, occupancy-sorted row layout.
-        g = schedule.groups[0]
-        if not g.sel_layers:
-            rows = len(schedule.inv) if combine is None else n_real
-            return jnp.zeros((rows, n_chan, n_tiles * tile), jnp.int32)
-        op = (
-            device_groups[0]
-            if device_groups is not None
-            else jnp.asarray(g.packed.view(np.int32))
-        )[schedule.inv]
-        y = _bank_call_xla(
-            frames, op, taps, g.schedule, g.tail_shift, tile,
-            combine=combine, n_real=n_real,
-        )
-        return y.reshape(y.shape[0], n_chan, -1)
     parts = []
     for gi, g in enumerate(schedule.groups):
         rows = g.packed.shape[0]
@@ -691,15 +525,10 @@ def bank_schedule_apply(
             else jnp.asarray(g.packed.view(np.int32))
         )
         with span("group", group=gi):
-            if lane == "xla":
-                y = _bank_call_xla(
-                    frames, op, taps, g.schedule, g.tail_shift, tile
-                )
-            else:
-                y = _bank_call(
-                    frames, op, taps, g.schedule, g.tail_shift, tile,
-                    schedule.tile_size, interpret,
-                )  # (rows, C, n_tiles, tile)
+            y = _bank_call(
+                frames, op, taps, g.schedule, g.tail_shift, tile,
+                schedule.tile_size, interpret,
+            )  # (rows, C·n_tiles·tile)
         parts.append(y.reshape(rows, n_chan, -1))
     y = jnp.concatenate(parts, axis=0) if len(parts) > 1 else parts[0]
     y = y[schedule.inv]  # drop pad rows, restore caller's filter order
@@ -777,7 +606,7 @@ def plane_combine(y: jnp.ndarray, n_out: int, interpret: bool) -> jnp.ndarray:
 def _combine_shared(y: jnp.ndarray, combine: jnp.ndarray, n_real: int):
     """Fold shared partial-sum rows (``y[n_real:]``) back into their
     consumers: one (n_real, n_shared) int32 GEMM plus an add.  Exact in
-    the mod-2**32 ring on every lane; the combined values are the parent
+    the mod-2**32 ring; the combined values are the parent
     program's outputs, which fit int32 by the pack-time §2.1 bound."""
     return y[:n_real] + jnp.tensordot(
         combine, y[n_real:], axes=1, preferred_element_type=jnp.int32

@@ -69,13 +69,11 @@ def serve_sessions(args) -> None:
             program,
             channels=args.slots,
             n_bank_shards=args.bank_shards,
-            chunk_hint=args.chunk,
         )
         print(f"[serve] sessions × shards: {engine.describe()}")
     server = BankSessionServer(
         program,
         n_slots=args.slots,
-        chunk_hint=args.chunk,
         auto_step=False,
         engine=engine,
         journal=args.journal_path or None,
@@ -201,9 +199,7 @@ def serve_fir_bank(args) -> None:
         if args.program_path:
             program.save(args.program_path)
             print(f"[serve] saved compiled program to {args.program_path}")
-    engine = ShardedFilterBankEngine(
-        program, channels=args.channels, chunk_hint=args.chunk
-    )
+    engine = ShardedFilterBankEngine(program, channels=args.channels)
     print(f"[serve] {engine.describe()}")
     server = AsyncBankServer(engine, depth=args.depth)
     rng = np.random.default_rng(0)

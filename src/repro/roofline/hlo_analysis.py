@@ -55,8 +55,7 @@ _ZERO_COST = {
 # Ops the TPU backend fuses into neighbours (CPU HLO leaves them top-level,
 # which would overstate HBM traffic ~3-5×).  Excluding them makes hbm_bytes
 # a *fusion-optimistic* model — the methodology caveat documented in
-# docs/benchmarks.md (roofline utilization columns of BENCH_compiled.json
-# inherit it).
+# docs/benchmarks.md.
 _FUSED_ON_TPU = {
     "add", "subtract", "multiply", "divide", "maximum", "minimum", "abs",
     "negate", "exponential", "exponential-minus-one", "log", "log-plus-one",

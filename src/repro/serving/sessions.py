@@ -48,11 +48,9 @@ concurrent user streams over one `BlmacProgram`:
     fault isolation: only the sessions packed into the failed dispatch
     round ride the replay (no other session's output is reordered or
     dropped), transient shard errors get a bounded in-step retry, and
-    `fault_stats()` attributes faults per session.  Admission control
-    reads the ENGINE'S LIVE PLAN, which every recovery rebuilds, so
-    after a shard loss the server prices steps against the degraded
-    mesh (and `serve_stats()['degraded']` flips once the engine has
-    fallen back to the 1×1 plain lowering).
+    `fault_stats()` attributes faults per session.  Every recovery
+    re-checks the step budget, and `serve_stats()['degraded']` flips
+    once the engine has fallen back to the 1×1 plain lowering.
   * **Durability.**  Attach a `repro.serving.journal.SessionJournal`
     (``journal=`` path) and every state transition — session registry,
     pushed chunks, delivered-sample watermarks, cadenced quiescent-point
@@ -217,7 +215,8 @@ class BankSessionServer:
         error propagates to the `step()` caller.
     mode, tile, interpret, chunk_hint
         Forwarded to the shared `FilterBankEngine` (ignored when
-        ``engine`` is injected).
+        ``engine`` is injected); it accepts ``mode`` ``"auto"`` or
+        ``"packed"`` (one path) and ignores ``chunk_hint``.
     """
 
     def __init__(
@@ -321,22 +320,11 @@ class BankSessionServer:
     # -- admission / eviction -----------------------------------------------
 
     def _dispatch_us(self) -> float:
-        """Per-round dispatch latency estimate feeding admission control.
-        Reads the engine's LIVE plan first — on a sharded engine that is
-        `ShardedBankPlan`, rebuilt by every fault recovery, so admission
-        automatically re-prices against a degraded mesh (the 1×1
-        fallback's plan may carry a NaN prediction, which falls through
-        to the coarse fixed-overhead floor)."""
-        from ..core.costmodel import PALLAS_CALL_US, STEP_US
+        """Per-round dispatch latency estimate feeding admission control:
+        the fixed `repro.core.costmodel.SESSION_DISPATCH_US`."""
+        from ..core.costmodel import SESSION_DISPATCH_US
 
-        plan = getattr(self.engine, "plan", None)  # sharded: live mesh plan
-        if plan is None:
-            plan = getattr(self.engine, "dispatch_plan", None)
-        if plan is not None:
-            us = float(plan.predicted_us)
-            if np.isfinite(us):
-                return us
-        return PALLAS_CALL_US + STEP_US
+        return SESSION_DISPATCH_US
 
     def _degraded(self) -> bool:
         """True once the (sharded) engine has fallen back to the 1×1
@@ -365,9 +353,8 @@ class BankSessionServer:
 
     def predicted_step_us(self, extra_sessions: int = 0) -> float:
         """Modelled latency of one batching step with the current active
-        population plus ``extra_sessions`` hypothetical admissions,
-        priced against the engine's CURRENT (possibly degraded) plan and
-        the journal's per-step overhead."""
+        population plus ``extra_sessions`` hypothetical admissions: each
+        round at `_dispatch_us`, plus the journal's per-step overhead."""
         from ..core.costmodel import predict_session_step_us
 
         n = self._active() + extra_sessions
@@ -393,11 +380,9 @@ class BankSessionServer:
         return True
 
     def _shed_to_budget(self) -> int:
-        """Post-recovery load shedding: after the engine re-plans onto a
-        smaller (or degraded) mesh, the SAME active population may no
-        longer fit the step budget — park idle LRU sessions until the
-        predicted step fits again (or nothing idle remains).  Returns
-        the number of sessions parked."""
+        """Post-recovery load shedding: park idle LRU sessions until the
+        predicted step fits the budget again (or nothing idle remains).
+        Returns the number of sessions parked."""
         shed = 0
         if self.step_budget_us is None:
             return shed
@@ -808,8 +793,8 @@ class BankSessionServer:
         bounded retry (the call is stateless, so a retry is a clean
         re-dispatch), any detection the engine's recovery machinery
         handled DURING the call is attributed to exactly the sessions in
-        this round, and a recovery re-plan immediately re-prices the
-        budget (shedding idle load if the degraded mesh no longer fits).
+        this round, and a recovery re-checks the budget (shedding idle
+        load if the step no longer fits).
         Per-tenant isolation is structural: sessions outside ``batch``
         have no samples in ``buf``, so neither the fault nor the replay
         can touch their streams."""

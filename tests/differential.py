@@ -243,7 +243,7 @@ def five_way_check(
         "FilterBankEngine cycle prediction != vmachine"
 
     # -- leg 5: device-sharded engine over a (bank, data) mesh ---------------
-    # occupancy-balanced partition, per-shard autotuned programs, halo
+    # occupancy-balanced partition, per-shard programs, halo
     # exchange when the mesh carries a data axis, and the gather-free
     # caller-order reassembly — checked on whatever mesh the session has
     sharded = lower(program, "sharded", mesh=mesh, interpret=interpret)
@@ -320,17 +320,17 @@ def cse_check(
     """CSE leg of the harness: optimize a compiled bank with
     `repro.compiler.cse_pass` and assert the optimized program is
     bit-exact against the PARENT's oracle on every backend —
-    weight-level (``effective_qbank``), scheduled (interpret AND the
-    fused xla lane with its in-kernel combine GEMM), specialized (small
-    banks), vmachine (widened-spec augmented rows + exact int64 fold),
-    sharded (augmented rows across the mesh, host fold after the
-    gather), and both `FilterBankEngine` modes — ``mode="auto"`` also
-    exercising the autotuner's optimize-vs-decline verdict.
+    weight-level (``effective_qbank``), scheduled (the bank kernel and
+    its combine GEMM), specialized (small banks), vmachine
+    (widened-spec augmented rows + exact int64 fold), sharded (augmented
+    rows across the mesh, host fold after the gather), and
+    `FilterBankEngine` at a pinned tile and at its own plan, whose
+    ``cse`` reads ``"optimized"``.
 
     Also asserts the pass's accounting: the optimized program never
     increases total pulses or §3.3 adds, and its §4 cycle prediction
     equals its augmented bank's cycles plus one per combine use.
-    Returns a small report dict (counts, adds, the auto verdict).
+    Returns a small report dict (counts, adds, the plan's ``cse``).
     """
     from repro.compiler import cse_pass
 
@@ -375,7 +375,6 @@ def cse_check(
     for leg, kw in (
         ("oracle", {}),
         ("scheduled", dict(tile=tile, interpret=interpret)),
-        ("scheduled", dict(tile=tile, interpret=interpret, lane="xla")),
         ("vmachine", {}),
         ("sharded", dict(mesh=mesh, interpret=interpret)),
     ):
@@ -398,7 +397,7 @@ def cse_check(
     assert np.array_equal(np.asarray(y, np.int64), oracle), \
         "cse: packed FilterBankEngine != parent oracle"
     auto = FilterBankEngine(opt, channels=1, mode="auto", interpret=interpret)
-    assert auto.dispatch_plan.cse in ("optimized", "declined")
+    assert auto.dispatch_plan.cse == "optimized" and auto.program is opt
     y = auto.push(x)[:, 0, :]
     assert np.array_equal(np.asarray(y, np.int64), oracle), \
         "cse: auto FilterBankEngine != parent oracle"

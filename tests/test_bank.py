@@ -119,12 +119,13 @@ def test_specialized_program_cache_hits():
 # streaming overlap-save engine
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("mode", ["packed", "specialized"])
-def test_engine_stream_equals_one_shot(mode):
+# merge 1 is the paper's one matmul per bit layer, merge 8 the chip's
+@pytest.mark.parametrize("merge", [1, 8])
+def test_engine_stream_equals_one_shot(merge):
     q = _qbank(10, 31)
     rng = np.random.default_rng(8)
     x = rng.integers(-128, 128, (2, 2100))
-    eng = FilterBankEngine(q, channels=2, tile=256, mode=mode)
+    eng = FilterBankEngine(q, channels=2, tile=256, merge=merge)
     cuts = [0, 13, 30, 31, 600, 601, 1500, 2100]
     outs = [eng.push(x[:, a:b]) for a, b in zip(cuts, cuts[1:])]
     y = np.concatenate(outs, axis=2)
@@ -146,23 +147,22 @@ def test_engine_priming_returns_empty():
 
 
 def test_engine_auto_mode_selection():
-    """auto = cost-model dispatch: narrow banks go per-filter specialized,
-    banks past the compile-budget cap always go to the scheduled path."""
-    from repro.kernels.runtime import SPECIALIZE_BANK_MAX
+    """Every bank, narrow or wide, runs the one plan: the scheduled
+    kernel at tile 256, bank tile `default_bank_tile(B)` and merge 8.
+    ``mode`` names that path as "auto" or "packed"; nothing else."""
+    from repro.compiler import bank_plan
 
-    small = FilterBankEngine(_qbank(2, 15))
-    assert small.mode == "specialized"
-    assert small.dispatch_plan is not None
-    assert small.dispatch_plan.predicted_us > 0
-    wide = FilterBankEngine(_qbank(SPECIALIZE_BANK_MAX + 1, 15))
-    assert wide.mode == "packed"
-    assert wide.dispatch_plan.mode == "scheduled"
-    assert wide.bank_tile == wide.bank_schedule.tile_size
-    # forced modes bypass the autotuner entirely
-    forced = FilterBankEngine(_qbank(2, 15), mode="packed")
-    assert forced.mode == "packed" and forced.dispatch_plan is None
-    alias = FilterBankEngine(_qbank(2, 15), mode="scheduled")
-    assert alias.mode == "packed"
+    for n in (2, 33):
+        eng = FilterBankEngine(_qbank(n, 15))
+        plan = eng.dispatch_plan
+        assert (plan.mode, plan.tile, plan.merge) == ("scheduled", 256, 8)
+        assert plan.bank_tile == eng.bank_tile == eng.bank_schedule.tile_size
+        assert plan == bank_plan(eng.program)[0]
+        packed = FilterBankEngine(_qbank(n, 15), mode="packed")
+        assert packed.dispatch_plan == plan
+    for mode in ("scheduled", "specialized"):
+        with pytest.raises(ValueError, match="unknown mode"):
+            FilterBankEngine(_qbank(2, 15), mode=mode)
 
 
 def test_engine_reset_and_taps1():
@@ -186,14 +186,14 @@ def test_engine_rejects_bad_input():
         eng.push(np.zeros((3, 10)))
 
 
-@pytest.mark.parametrize("mode", ["packed", "specialized"])
-def test_engine_sub_tap_chunks_after_priming(mode):
+@pytest.mark.parametrize("merge", [1, 8])
+def test_engine_sub_tap_chunks_after_priming(merge):
     """Chunks shorter than taps-1 — including single samples — after the
     engine is already primed must each yield exactly chunk-sized output."""
     q = _qbank(3, 15)
     rng = np.random.default_rng(20)
     x = rng.integers(-128, 128, (1, 60))
-    eng = FilterBankEngine(q, channels=1, tile=128, mode=mode)
+    eng = FilterBankEngine(q, channels=1, tile=128, merge=merge)
     eng.push(x[:, :14])  # exactly taps-1: still priming
     assert eng.pending == 14
     outs = [eng.push(x[:, i : i + 1]) for i in range(14, 60)]  # 1 at a time
@@ -226,14 +226,14 @@ def test_engine_empty_chunk_while_priming():
     assert eng.pending == 5
 
 
-@pytest.mark.parametrize("mode", ["packed", "specialized"])
-def test_engine_final_chunk_not_tile_multiple(mode):
+@pytest.mark.parametrize("merge", [1, 8])
+def test_engine_final_chunk_not_tile_multiple(merge):
     """A final chunk that leaves the padded buffer off the tile grid: the
     windows reaching into the zero padding must be dropped, not returned."""
     q = _qbank(4, 31)
     rng = np.random.default_rng(22)
     x = rng.integers(-128, 128, (1, 777))  # 777 = 6*128 + 9, taps 31
-    eng = FilterBankEngine(q, channels=1, tile=128, mode=mode)
+    eng = FilterBankEngine(q, channels=1, tile=128, merge=merge)
     y = np.concatenate(
         [eng.push(x[:, :512]), eng.push(x[:, 512:])], axis=2
     )

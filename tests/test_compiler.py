@@ -113,14 +113,14 @@ def test_select_subprogram_slices_and_memoizes():
 
 
 # ---------------------------------------------------------------------------
-# exactly-once compilation across engine + autotuner + cycle predictor
+# exactly-once compilation across engine + bank plan + cycle predictor
 # ---------------------------------------------------------------------------
 
 def test_csd_computed_exactly_once_across_clients():
     """The regression the refactor exists for: one bank used by the
-    engine, the autotuner and the cycle predictor costs ONE CSD/packing
+    engine, the bank plan and the cycle predictor costs ONE CSD/packing
     pass and ONE program compile, however many clients touch it."""
-    from repro.kernels.runtime import autotune_bank_dispatch
+    from repro.compiler import bank_plan
 
     q = _qbank(n=12, taps=31, seed=3)
     clear_caches()
@@ -130,13 +130,13 @@ def test_csd_computed_exactly_once_across_clients():
     assert c1["counters"]["csd_packings"] == 1
     plans_after_build = c1["counters"]["schedule_plans"]
 
-    # a second engine, a direct autotuner call and the cycle predictor
+    # a second engine, a direct bank_plan call and the cycle predictor
     # all resolve through the same artifact: no new compiles, packings
     # or schedule plans
     eng2 = FilterBankEngine(q, mode="auto", chunk_hint=1024)
     assert eng2.program is eng.program
-    plan, _ = autotune_bank_dispatch(eng.program, chunk_hint=1024)
-    assert plan == eng.dispatch_plan or eng.dispatch_plan is None
+    plan, sched = bank_plan(eng.program)
+    assert plan == eng.dispatch_plan and sched is eng.bank_schedule
     cycles = eng.predicted_machine_cycles()
     assert np.array_equal(eng2.predicted_machine_cycles(), cycles)
     c2 = cache_stats()
@@ -145,7 +145,6 @@ def test_csd_computed_exactly_once_across_clients():
     assert c2["counters"]["schedule_plans"] == plans_after_build
     assert c2["counters"]["machine_cycle_computes"] == 1
     assert c2["program"]["hits"] >= 1
-    assert c2["autotune"]["hits"] >= 1
     assert np.array_equal(
         cycles, machine_cycles_batch(q, 16, 0, False)
     )
@@ -153,7 +152,7 @@ def test_csd_computed_exactly_once_across_clients():
 
 def test_cache_stats_reports_every_domain():
     stats = cache_stats()
-    for domain in ("program", "autotune", "specialized"):
+    for domain in ("program", "cse", "specialized"):
         assert {"hits", "misses", "size"} <= set(stats[domain])
     assert "size" in stats["bank_call"]
     assert isinstance(stats["counters"], dict)
@@ -161,13 +160,17 @@ def test_cache_stats_reports_every_domain():
 
 def test_caches_are_bounded():
     from repro.compiler.cache import PROGRAM_CACHE
-    from repro.kernels.runtime import _AUTOTUNE_CACHE, _AUTOTUNE_CACHE_MAX
+    from repro.compiler.program import SCHEDULE_MEMO_MAX
 
     clear_caches()
     for seed in range(40):
         compile_bank(_qbank(n=2, taps=15, seed=seed, lim=500))
     assert len(PROGRAM_CACHE) <= PROGRAM_CACHE.max_entries
-    assert len(_AUTOTUNE_CACHE) <= _AUTOTUNE_CACHE_MAX
+    # per-program schedule memo: one entry per geometry, bounded
+    prog = compile_bank(_qbank(n=2, taps=15, seed=0, lim=500))
+    for bank_tile in range(8, 8 * (SCHEDULE_MEMO_MAX + 4), 8):
+        prog.schedule(bank_tile)
+    assert len(prog._schedules) == SCHEDULE_MEMO_MAX
 
 
 # ---------------------------------------------------------------------------
@@ -178,8 +181,6 @@ def test_save_load_roundtrip_identical(tmp_path):
     q = adversarial_bank(taps=31)
     prog = compile_bank(q)
     sched = prog.schedule()
-    est_spec = prog.predict_specialized_us(1, 4)
-    est_sched = prog.predict_scheduled_us(1, 4, 512)
     x = np.random.default_rng(0).integers(-128, 128, 31 + 64)
     y = lower(prog, "scheduled", tile=128)(x)
 
@@ -205,9 +206,8 @@ def test_save_load_roundtrip_identical(tmp_path):
         assert g1.tail_shift == g2.tail_shift
         assert g1.sel_layers == g2.sel_layers
         assert np.array_equal(g1.packed, g2.packed)
-    # identical cost estimates
-    assert loaded.predict_specialized_us(1, 4) == est_spec
-    assert loaded.predict_scheduled_us(1, 4, 512) == est_sched
+    # identical §4 cycle counts
+    assert np.array_equal(loaded.machine_cycles(), prog.machine_cycles())
     # bit-exact outputs after reload
     y2 = lower(loaded, "scheduled", tile=128)(x)
     assert np.array_equal(y, y2)

@@ -29,6 +29,8 @@ DOC_FILES = [ROOT / "README.md", *sorted((ROOT / "docs").glob("*.md"))]
 DOCTEST_MODULES = [
     "repro.compiler.program",
     "repro.compiler.lowering",
+    "repro.compiler.schedule",
+    "repro.distributed.sharding",
     "repro.filters.bank",
 ]
 

@@ -126,16 +126,18 @@ def test_bank_kernel_exact_at_bf16_bound(samples):
     else:
         x = np.where(np.arange(n) % 2, 127, -128)[None, :]
     y = blmac_fir_bank(jnp.asarray(x), packed, taps, tile=256,
-                       merge=BF16_MERGE_MAX, fast_path=False, lane="interpret")
+                       merge=BF16_MERGE_MAX, fast_path=False, interpret=True)
     w = np.full((2, taps), 255, np.int64)
     w[1] = -255
     assert np.array_equal(np.asarray(y), fir_bit_layers_batch(x, w))
 
 
-@pytest.mark.parametrize("lane", ["mosaic", "interpret"])
-def test_bank_kernel_raises_beyond_bf16_bound(lane):
+@pytest.mark.parametrize("interpret", [
+    pytest.param(False, id="mosaic"), pytest.param(True, id="interpret")])
+def test_bank_kernel_raises_beyond_bf16_bound(interpret):
     """A superlayer of nine merged layers (digit 511) is not bf16-exact:
-    the Pallas bank kernel refuses it instead of rounding."""
+    the Pallas bank kernel refuses it, compiled or interpreted, instead
+    of rounding."""
     from repro.kernels.blmac_fir import blmac_fir_bank
 
     taps = 31
@@ -143,7 +145,7 @@ def test_bank_kernel_raises_beyond_bf16_bound(lane):
     x = jnp.zeros((1, 200), jnp.int32)
     with pytest.raises(ValueError, match="bf16"):
         blmac_fir_bank(x, packed, taps, tile=128, merge=9, fast_path=False,
-                       lane=lane)
+                       interpret=interpret)
 
 
 def _lowered_texts():
@@ -151,8 +153,8 @@ def _lowered_texts():
     kernel entry point, lowered on the CPU at a small shape."""
     from repro.compiler import compile_bank
     from repro.filters import spread_lowpass_qbank
-    from repro.kernels.blmac_fir import (_bank_call, _bank_call_xla,
-                                         _combine_shared, frame_signal_batch,
+    from repro.kernels.blmac_fir import (_bank_call, _combine_shared,
+                                         frame_signal_batch, plane_combine,
                                          specialized_program)
 
     prog = compile_bank(spread_lowpass_qbank(8, 31))
@@ -163,14 +165,14 @@ def _lowered_texts():
     bank = _bank_call.lower(frames, op, taps=31, schedule=g.schedule,
                             tail_shift=g.tail_shift, tile=128, bank_tile=8,
                             interpret=True)
-    xla = _bank_call_xla.lower(frames, op, taps=31, schedule=g.schedule,
-                               tail_shift=g.tail_shift, tile=128)
+    planes = plane_combine.lower(jnp.zeros((8, 2, 256), jnp.int32), 200,
+                                 True)
     spec = specialized_program(prog.pulse_schedules()[0], 31, 128,
                                True).lower(jnp.zeros(512, jnp.int32))
     comb = _combine_shared.lower(jnp.zeros((6, 1, 128), jnp.int32),
                                  jnp.ones((4, 2), jnp.int32), n_real=4)
     return [("blmac/bank_kernel", "blmac_bank_kernel", bank),
-            ("blmac/bank_xla", None, xla),
+            ("blmac/plane_combine", "blmac_plane_combine", planes),
             ("blmac/specialized", "blmac_specialized", spec),
             ("blmac/combine", None, comb)]
 

@@ -3,14 +3,14 @@
 The contract under test, in order of importance:
 
   1. **Bit-exactness** — an optimized program produces the parent's
-     outputs on every backend lane (the `cse_check` differential leg:
-     oracle, scheduled interpret + fused xla combine GEMM, specialized,
-     vmachine, sharded, both engine modes).
+     outputs on every backend (the `cse_check` differential leg:
+     oracle, scheduled + combine GEMM, specialized, vmachine, sharded,
+     the engine).
   2. **Accounting** — total pulses and §3.3 adds never increase; §4
      cycles price one extra cycle per combine use.
   3. **Caching** — the pass is content-addressed on ``(parent.key,
      "cse", level)``, memoized (mines exactly once across the engine,
-     the autotuner and the cycle predictor), and survives save/load
+     the plans and the cycle predictor), and survives save/load
      with tamper detection.
 """
 import numpy as np
@@ -85,7 +85,7 @@ def test_cse_row_structure_hooks_point_to_bank():
 
 
 # ---------------------------------------------------------------------------
-# differential bit-exactness (every backend lane)
+# differential bit-exactness (every backend)
 # ---------------------------------------------------------------------------
 
 
@@ -93,7 +93,7 @@ def test_cse_bit_exact_random_bank():
     report = cse_check(random_type1_bank(6, 31, seed=1), interpret=True)
     assert report["n_shared"] > 0
     assert report["adds_optimized"] <= report["adds_parent"]
-    assert report["auto_cse"] in ("optimized", "declined")
+    assert report["auto_cse"] == "optimized"
 
 
 def test_cse_bit_exact_sweep_bank():
@@ -115,12 +115,11 @@ def test_cse_engine_decline_executes_parent():
     parent = compile_bank(random_type1_bank(10, 31, seed=7))
     opt = cse_pass(parent)
     eng = FilterBankEngine(opt, channels=1, mode="auto", interpret=True)
-    assert eng.dispatch_plan.cse in ("optimized", "declined")
-    if eng.dispatch_plan.cse == "declined":
-        assert eng.program is parent
-        assert eng.n_filters == parent.n_filters
-    else:
-        assert eng.program is opt
+    # the one plan runs the optimized layout; it serves the parent's
+    # filters, bit-identically
+    assert eng.dispatch_plan.cse == "optimized"
+    assert eng.program is opt
+    assert eng.n_filters == parent.n_filters
     rng = np.random.default_rng(0)
     x = rng.integers(-128, 128, size=(1, 600), dtype=np.int32)
     assert np.array_equal(
@@ -134,8 +133,8 @@ def test_cse_engine_decline_executes_parent():
 
 
 def test_cse_runs_exactly_once_across_clients():
-    from repro.kernels.runtime import (autotune_bank_dispatch,
-                                       autotune_sharded_dispatch)
+    from repro.compiler import bank_plan
+    from repro.distributed import sharded_plan
 
     q = random_type1_bank(8, 31, seed=11)
     clear_caches()
@@ -145,11 +144,11 @@ def test_cse_runs_exactly_once_across_clients():
     assert c1["counters"]["cse_passes"] == 1
     assert c1["cse"]["misses"] == 1 and c1["cse"]["size"] == 1
 
-    # engine construction, both autotuners and the cycle predictor all
+    # engine construction, both plans and the cycle predictor all
     # consume the SAME optimized artifact: no re-mining anywhere
     eng = FilterBankEngine(opt, channels=1, mode="auto", interpret=True)
-    autotune_bank_dispatch(opt, chunk_hint=2048)
-    autotune_sharded_dispatch(opt, mesh_shape=(2, 1), interpret=True)
+    bank_plan(opt)
+    sharded_plan(opt.bank, (2, 1))
     cycles = opt.machine_cycles()
     assert cycles.shape == (opt.n_real,)
     assert cse_pass(parent) is opt
@@ -157,7 +156,7 @@ def test_cse_runs_exactly_once_across_clients():
     c2 = cache_stats()
     assert c2["counters"]["cse_passes"] == 1
     assert c2["cse"]["hits"] >= 1
-    assert eng.dispatch_plan.cse in ("optimized", "declined")
+    assert eng.dispatch_plan.cse == "optimized"
 
 
 def test_cse_memo_is_bounded():

@@ -63,19 +63,6 @@ def test_priming_push_counts_a_push_and_no_work(program):
                                 "wide_pushes": 0}
 
 
-def test_specialized_engine_counts_per_filter_programs():
-    q = spread_lowpass_qbank(3, TAPS)
-    eng = FilterBankEngine(q, channels=C, mode="specialized", tile=128)
-    y = eng.push(_chunks(1, 300)[0])
-    # 300 samples pad to 384: each filter × channel program frames 354
-    # outputs in 3 tiles and returns them all
-    assert y.shape == (3, C, 270)
-    assert eng.push_stats() == {
-        "pushes": 1, "outputs_delivered": 3 * C * 270,
-        "outputs_computed": 3 * C * 384,
-        "bytes_read_back": 3 * C * 354 * 4, "wide_pushes": 0}
-
-
 def test_sharded_engine_on_one_device_counts_like_the_plain_engine(program):
     eng = ShardedFilterBankEngine(program, channels=C, tile=128,
                                   chunk_hint=256)

@@ -57,79 +57,3 @@ def test_dus_counts_slice_not_buffer():
     c = analyze_hlo(jax.jit(f, donate_argnums=0).lower(buf, upd).compile().as_text())
     # traffic must be ~2x the update slice, nowhere near the 8 MiB buffer
     assert c.hbm_bytes <= 4 * 512 * 4 * 2 + 1024, c.hbm_bytes
-
-
-# ---------------------------------------------------------------------------
-# the scheduled bank kernel's compiled (xla-lane) HLO
-# ---------------------------------------------------------------------------
-
-import numpy as np
-
-from repro.core import po2_quantize_batch
-from repro.filters import design_bank
-from repro.kernels import pack_bank_trits, plan_bank_schedule
-from repro.kernels.blmac_fir import (TRITS_PER_WORD, _bank_call_xla,
-                                     frame_signal_batch)
-
-
-def _compiled_group(merge, taps=31, tile=512, n=32, chunk=2048):
-    cuts = 0.05 + 0.9 * (np.arange(n) + 0.5) / n
-    q, _ = po2_quantize_batch(
-        design_bank(taps, [("lowpass", float(c)) for c in cuts]), 16
-    )
-    sched = plan_bank_schedule(pack_bank_trits(q), None, merge)
-    assert len(sched.groups) == 1
-    g = sched.groups[0]
-    frames, _ = frame_signal_batch(jnp.zeros((1, chunk), jnp.int32), taps, tile)
-    op = jnp.asarray(g.packed.view(np.int32))
-    hlo = _bank_call_xla.lower(
-        frames, op, taps=taps, schedule=g.schedule, tail_shift=g.tail_shift,
-        tile=tile,
-    ).compile().as_text()
-    return g, frames, op, tile, analyze_hlo(hlo)
-
-
-def test_bank_xla_hlo_dot_flops_exact():
-    """One superlayer (merge=16 fully fuses a 16-bit bank) → exactly one
-    (B_pad, M) @ (M, C·n_tiles·tile) contraction's worth of FLOPs."""
-    g, frames, op, tile, c = _compiled_group(merge=16)
-    assert len(g.schedule) == 1
-    b_pad, _, n_words = op.shape
-    m_pad = n_words * TRITS_PER_WORD
-    s = frames.shape[0] * frames.shape[1] * tile
-    assert c.flops == 2.0 * b_pad * m_pad * s * len(g.schedule), c.flops
-
-
-def test_bank_xla_hlo_flops_scale_with_superlayer_count():
-    """merge=8 splits the same bank into two superlayers: twice the
-    contractions, twice the dot FLOPs — the schedule→HLO relation the
-    compiled cost model relies on."""
-    g16, _, _, _, c16 = _compiled_group(merge=16)
-    g8, _, _, _, c8 = _compiled_group(merge=8)
-    assert len(g16.schedule) == 1 and len(g8.schedule) == 2
-    assert c8.flops == 2 * c16.flops, (c8.flops, c16.flops)
-
-
-def test_bank_xla_hlo_unpack_is_fused():
-    """The fused-unpack property at the HLO level: the packed trit words
-    are the program operand (2 bits/trit) and the shift/mask decode lands
-    inside fusions, so HBM traffic stays near the window matrix + output
-    — nowhere near what per-superlayer unpacked-trit round-trips would
-    add on top."""
-    g, frames, op, tile, c = _compiled_group(merge=16)
-    b_pad = op.shape[0]
-    m_pad = op.shape[2] * TRITS_PER_WORD
-    s = frames.shape[0] * frames.shape[1] * tile
-    window = m_pad * s * 4  # the im2col-style u matrix, int32
-    out = b_pad * s * 4
-    # the dot reads packed-derived LHS + window, writes the accumulator
-    assert c.hbm_by_op.get("dot", 0) >= window + out
-    # fusion-optimistic total stays within a small multiple of the
-    # unavoidable traffic (window + out + frames + packed operand)
-    floor = window + out + frames.size * 4 + op.size * 4
-    assert c.hbm_bytes <= 4 * floor, (c.hbm_bytes, floor)
-    # no unpacked-int8 trit tensor ever becomes a top-level buffer: that
-    # would add ≥ b_pad · m_pad · n_tiles round trips via some elementwise
-    # op, and every decode op XLA emits here is in the fused set
-    assert "shift-right-arithmetic" not in c.hbm_by_op
-    assert "and" not in c.hbm_by_op

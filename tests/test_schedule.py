@@ -2,7 +2,7 @@
 
 Oracle-vs-kernel equality on the banks a schedule can get wrong (all-zero
 rows, single pulses at the extreme layers, mixed occupancy in hostile
-order), schedule-compilation unit tests, the autotuned dispatch, and the
+order), schedule-compilation unit tests, the bank plan, and the
 pack-time int32 bound.
 """
 import numpy as np
@@ -14,8 +14,9 @@ from repro.core import (assert_int32_bound, layer_occupancy,
                         po2_quantize_batch)
 from repro.core.csd import csd_digits
 from repro.filters import FilterBankEngine, design_bank, fir_bit_layers_batch
-from repro.kernels import (autotune_bank_dispatch, pack_bank_trits,
-                           plan_bank_schedule, superlayer_schedule)
+from repro.compiler import bank_plan, compile_bank
+from repro.kernels import (pack_bank_trits, plan_bank_schedule,
+                           superlayer_schedule)
 from repro.kernels.blmac_fir import blmac_fir_bank  # packed-operand entry
 
 from differential import adversarial_bank, four_way_check
@@ -173,26 +174,27 @@ def test_fast_path_matches_bank_path():
 
 
 # ---------------------------------------------------------------------------
-# autotuner + engine dispatch
+# bank plan + engine dispatch
 # ---------------------------------------------------------------------------
 
 def test_autotuner_scales_with_bank_width():
-    def bank(n, taps=63):
+    """The plan's bank tile tracks the bank's width (whole bank in one
+    tile up to 256 filters, then tiles that keep the padding small);
+    tile and merge stay fixed, and the schedule is the program's memo."""
+    def program(n, taps=63):
         cuts = 0.05 + 0.9 * (np.arange(n) + 0.5) / n
         q, _ = po2_quantize_batch(
             design_bank(taps, [("lowpass", float(c)) for c in cuts]), 16
         )
-        return pack_bank_trits(q)
+        return compile_bank(q)
 
-    plan1, sched1 = autotune_bank_dispatch(bank(1), 63)
-    assert plan1.mode == "specialized" and sched1 is None
-    plan256, sched256 = autotune_bank_dispatch(bank(256), 63, chunk_hint=8192)
-    assert plan256.mode == "scheduled"
-    assert plan256.merge > 1  # superlayer fusion beats per-bit-layer matmuls
-    assert sched256 is not None and sched256.tile_size == plan256.bank_tile
-    # repeat dispatch is an LRU hit returning the identical plan object
-    again, _ = autotune_bank_dispatch(bank(256), 63, chunk_hint=8192)
-    assert again is plan256
+    for n, bank_tile in ((1, 8), (17, 24), (256, 256), (300, 152)):
+        prog = program(n)
+        plan, sched = bank_plan(prog)
+        assert (plan.mode, plan.tile, plan.merge) == ("scheduled", 256, 8)
+        assert plan.bank_tile == sched.tile_size == bank_tile
+        again, same = bank_plan(prog)  # the program's memo: no re-plan
+        assert again == plan and same is sched is prog.schedule()
 
 
 def test_engine_scheduled_streaming_on_adversarial_bank():

@@ -1,5 +1,5 @@
 """Device-sharded filter-bank engine: partition balance, caller-order
-restoration, single-device degradation, mesh-aware autotuning, and the
+restoration, single-device degradation, the sharded plan, and the
 multi-device paths (per-shard programs, halo exchange, channel sharding)
 in a forced-8-device subprocess."""
 import json
@@ -7,11 +7,11 @@ import json
 import numpy as np
 import pytest
 
-from repro.distributed import bank_mesh, partition_bank
+from repro.compiler import bank_plan, compile_bank
+from repro.distributed import bank_mesh, partition_bank, sharded_plan
 from repro.filters import (FilterBankEngine, ShardedFilterBankEngine,
                            fir_bit_layers_batch, spread_lowpass_qbank)
 from repro.kernels.blmac_fir import pack_bank_trits
-from repro.kernels.runtime import autotune_sharded_dispatch
 from tests._subproc import run_py
 from tests.differential import adversarial_bank, five_way_check
 
@@ -75,50 +75,61 @@ def test_partition_clamps_shards_to_bank():
 
 
 # ---------------------------------------------------------------------------
-# mesh-aware autotuning (planning is device-free)
+# the sharded plan (device-free)
 # ---------------------------------------------------------------------------
 
 
 def test_autotuner_shards_wide_bank_and_declines_narrow():
-    wide = pack_bank_trits(_qbank(256, taps=63))
-    plan, part, schedules = autotune_sharded_dispatch(
-        wide, 63, channels=1, mesh_shape=(8, 1), chunk_hint=8192
-    )
-    assert plan.n_bank_shards > 1, "256-filter bank should shard on 8 devices"
+    """The bank axis takes one shard per mesh row; a bank narrower than
+    the mesh takes one shard per filter, and a one-filter bank does not
+    shard at all."""
+    wide = compile_bank(_qbank(256, taps=63))
+    plan, part, schedules = sharded_plan(wide, (8, 1))
+    assert plan.n_bank_shards == 8, "256-filter bank should shard on 8 rows"
     assert len(schedules) == plan.n_bank_shards == part.n_shards
-    # a tiny bank on the same mesh: per-shard dispatch overhead swamps
-    # the work and the autotuner must decline to shard the filter axis
-    narrow = pack_bank_trits(_qbank(2, taps=31))
-    plan2, _, _ = autotune_sharded_dispatch(
-        narrow, 31, channels=1, mesh_shape=(8, 1), chunk_hint=512
-    )
-    assert plan2.n_bank_shards == 1
-    assert not plan2.sharded
+    two, _, _ = sharded_plan(compile_bank(_qbank(2, taps=31)), (8, 1))
+    assert two.n_bank_shards == 2
+    plan1, _, _ = sharded_plan(compile_bank(_qbank(1, taps=31)), (8, 1))
+    assert plan1.n_bank_shards == 1
+    assert not plan1.sharded
 
 
 def test_autotuner_can_decline_the_data_axis():
-    packed = pack_bank_trits(_qbank(4, taps=31))
-    # short chunks on a (1, 2) mesh: the halo exchange + split overhead
-    # loses to one device per shard, so the sweep leaves the axis idle
-    plan, _, _ = autotune_sharded_dispatch(
-        packed, 31, channels=1, mesh_shape=(1, 2), chunk_hint=256
-    )
+    prog = compile_bank(_qbank(4, taps=31))
+    # unasked, the data axis stays idle: one device per shard
+    plan, _, _ = sharded_plan(prog, (1, 2))
     assert plan.n_data == 1 and plan.data_mode == "none"
-    # forcing an unavailable mode is an error, not a silent fallback
+    assert not plan.sharded
+    # asking for an unavailable mode is an error, not a silent fallback
     with pytest.raises(ValueError):
-        autotune_sharded_dispatch(
-            packed, 31, channels=3, mesh_shape=(1, 2), chunk_hint=256,
-            force_data="channels",
-        )
+        sharded_plan(prog, (1, 2), channels=3, data_mode="channels")
+    with pytest.raises(ValueError):
+        sharded_plan(prog, (2, 1), data_mode="time")
 
 
 def test_forced_shard_count_is_respected():
-    packed = pack_bank_trits(_qbank(16, taps=31))
-    plan, part, _ = autotune_sharded_dispatch(
-        packed, 31, channels=1, mesh_shape=(8, 1), chunk_hint=2048,
-        force_shards=4,
-    )
+    prog = compile_bank(_qbank(16, taps=31))
+    plan, part, _ = sharded_plan(prog, (8, 1), n_bank_shards=4)
     assert plan.n_bank_shards == 4 and part.n_shards == 4
+    # clamped to the mesh's bank rows
+    plan, _, _ = sharded_plan(prog, (2, 1), n_bank_shards=4)
+    assert plan.n_bank_shards == 2
+
+
+def test_sharded_compiled_planning_and_degraded_engine():
+    """A mesh of one device runs one shard of the one plan: the shard's
+    plan is `bank_plan` of the whole program, and the stream is
+    bit-exact."""
+    q = _qbank(64, taps=63)
+    eng = ShardedFilterBankEngine(q, mesh=bank_mesh(1, 1))
+    assert (eng.n_bank_shards, eng.n_data) == (1, 1)
+    assert eng.plan.shard_plans == (bank_plan(eng.program)[0],)
+    assert "predicted" not in eng.describe()
+    rng = np.random.default_rng(8)
+    x = rng.integers(-128, 128, (1, 600))
+    y = eng.push(x)
+    expect = fir_bit_layers_batch(x, q)
+    assert np.array_equal(np.asarray(y, np.int64), expect)
 
 
 # ---------------------------------------------------------------------------
@@ -173,8 +184,7 @@ x = rng.integers(-128, 128, 4096)
 ref = fir_bit_layers_batch(x, q)[:, 0, :]
 
 # uneven B=13 over 4 bank shards x 2 time shards (halo exchange);
-# data_mode forced so the halo path is exercised even where the
-# autotuner would decline the data axis
+# the data axis is used only when data_mode names it
 eng = ShardedFilterBankEngine(q, mesh=bank_mesh(4, 2), n_bank_shards=4,
                               data_mode="time")
 assert eng.data_mode == "time" and eng.n_bank_shards == 4
@@ -523,8 +533,8 @@ from repro.filters import ShardedFilterBankEngine as E, spread_lowpass_qbank
 # one jitted program per shard schedule, shared by every engine, so the
 # loop compiles once
 make, shared = E._make_scheduled_fn, {}
-E._make_scheduled_fn = lambda self, sched, tile, lane=None: shared.setdefault(
-    (id(sched), tile, lane), make(self, sched, tile, lane))
+E._make_scheduled_fn = lambda self, sched, tile: shared.setdefault(
+    (id(sched), tile), make(self, sched, tile))
 q = spread_lowpass_qbank(16, 15)
 x = np.random.default_rng(0).integers(-128, 128, 300)
 before, most, overlapped = threading.active_count(), 0, 0

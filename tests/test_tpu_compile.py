@@ -26,9 +26,9 @@ from repro.configs.fir127 import CONFIG
 from repro.core import po2_quantize_batch
 from repro.distributed import halo_exchange_left
 from repro.filters import FilterBankEngine, spread_lowpass_qbank, sweep_bank
-from repro.kernels.blmac_fir import (_bank_call, _bank_call_xla,
-                                     bank_schedule_apply, frame_signal_batch,
-                                     plane_combine, specialized_program)
+from repro.kernels.blmac_fir import (_bank_call, bank_schedule_apply,
+                                     frame_signal_batch, plane_combine,
+                                     specialized_program)
 
 TAPS = CONFIG.taps
 CHUNK = 16384
@@ -72,8 +72,7 @@ def _steady_frames_shape(n_chan: int, tile: int):
 
 
 def _engine(qbank, channels):
-    """The engine's own plan (autotuned here on the CPU, as on the chip:
-    the default sweep is backend-independent)."""
+    """The engine's own plan (`bank_plan`, the same on every backend)."""
     return FilterBankEngine(compile_bank(qbank), channels=channels)
 
 
@@ -121,30 +120,12 @@ def test_plane_combine_kernel_compiles(n_out, one_chip):
 
 
 def test_specialized_program_compiles(one_chip):
-    """The pulse-baked single-filter kernel (banks of <= 32 filters and
-    the B = 1 fast path) compiles for one 127-tap filter."""
+    """The pulse-baked single-filter kernel (the B = 1 fast path and
+    the ``"specialized"`` lowering) compiles for one 127-tap filter."""
     prog = compile_bank(spread_lowpass_qbank(1, TAPS))
     run = specialized_program(prog.pulse_schedules()[0], TAPS, 512, False)
     x = jax.ShapeDtypeStruct((CHUNK,), jnp.int32, sharding=one_chip)
     assert "tpu_custom_call" in run.lower(x).compile().as_text()
-
-
-def test_xla_bank_call_compiles_within_device_memory(one_chip):
-    """The fused XLA lowering of the same schedule compiles, and its
-    window matrix and output fit one chip's 16 GB."""
-    eng = _engine(spread_lowpass_qbank(256, TAPS), 8)
-    g = eng.bank_schedule.groups[0]
-    frames = jax.ShapeDtypeStruct(
-        _steady_frames_shape(8, eng.tile), jnp.int32, sharding=one_chip
-    )
-    op = jax.ShapeDtypeStruct(g.packed.shape, jnp.int32, sharding=one_chip)
-    mem = _bank_call_xla.lower(
-        frames, op, taps=TAPS, schedule=g.schedule, tail_shift=g.tail_shift,
-        tile=eng.tile,
-    ).compile().memory_analysis()
-    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
-            + mem.temp_size_in_bytes)
-    assert 0 < used < 16 * 10**9
 
 
 def test_time_sharded_step_compiles_on_four_chips(topo):

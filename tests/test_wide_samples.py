@@ -77,8 +77,11 @@ def test_int8_valued_stream_keeps_the_int32_result(dtype):
 
 
 def test_specialized_mode_serves_int16_streams_exactly():
+    """A narrow bank (3 filters, one 8-row bank tile at merge 1, the
+    paper's one matmul per bit layer) serves a 16-bit stream exactly."""
     q = spread_lowpass_qbank(3, 31)
-    eng = FilterBankEngine(q, channels=2, mode="specialized", tile=128)
+    eng = FilterBankEngine(q, channels=2, tile=128, merge=1)
+    assert eng.bank_tile == 8
     y, x = _push_all(eng, _stream(2, [200, 100]))
     assert y.dtype == np.int64
     np.testing.assert_array_equal(y, _reference(q, x))
